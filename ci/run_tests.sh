@@ -791,14 +791,13 @@ run_nightly() {
     MXTPU_NIGHTLY=1 python -m pytest tests/test_large_array.py \
         tests/test_checkpoint_compat.py -q
     MXTPU_NIGHTLY=1 python -m pytest tests/test_dist.py -q -k seven
-    # the armed bench configurations (bf16 + on-device init + scan;
-    # remat sweep config) must execute end-to-end so a broken
-    # measurement path can't wait for a live chip window to surface;
-    # plus the full-size int8 proofs (inception @299, trained resnet
-    # accuracy) and the program analyses
+    # the measured train configuration (bench.build_train_step: bf16
+    # ResNet-50, per-step and scan) walks chip_smoke.py's train and mesh
+    # phases at a tiny size on the CPU, so a broken measurement path
+    # cannot wait for a chip call to surface; plus the full-size int8
+    # proofs (inception @299, trained resnet accuracy)
     MXTPU_NIGHTLY=1 python -m pytest \
-        tests/test_bench.py::test_bench_child_bf16_scan_executes \
-        tests/test_bench.py::test_bench_child_remat_executes \
+        tests/test_chip_smoke.py::test_train_and_mesh_phases \
         "tests/test_quantization_int8.py::test_quantize_net_inceptionv3_full_int8_nightly" \
         "tests/test_quantization_int8.py::test_quantized_trained_resnet_accuracy_within_2pct" \
         -q

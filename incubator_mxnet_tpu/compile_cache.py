@@ -1,7 +1,7 @@
 """Persistent, content-addressed executable cache + AOT compile path.
 
 ROADMAP item 4: a single resnet50 train step costs 81 s (fp32) / 111 s
-(bf16) of XLA compile time (MEASURED_r05, docs/PERF_ANALYSIS.md §1),
+(bf16) of XLA compile time (one v5e chip, 2026-08-01; docs/PERF_ANALYSIS.md §1),
 paid again on *every* process start — a fatal tax on preemption resume
 (PR 8), elastic re-admits (PR 6), and serving restarts. This module
 makes the second process skip XLA entirely:
@@ -56,12 +56,39 @@ from . import telemetry
 from .resilience import checkpoint as _ckpt
 from .telemetry import compilereg as _compilereg
 
-__all__ = ["CachedJit", "wrap", "enabled", "cache_dir", "entry_key",
+__all__ = ["jax_cache_dir", "enable_jax_cache",
+           "CachedJit", "wrap", "enabled", "cache_dir", "entry_key",
            "abstract_signature", "abstractify", "stats", "reset_stats",
            "clear",
            "HITS_TOTAL", "MISSES_TOTAL", "EVICTIONS_TOTAL", "SAVED_SECONDS"]
 
 logger = logging.getLogger(__name__)
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def jax_cache_dir():
+    """Directory of JAX's own persistent compilation cache: wherever the
+    environment's `JAX_COMPILATION_CACHE_DIR` points, else `.jax_cache`
+    in the checkout this package was imported from. Never a temporary
+    or per-process name — a cache that moves between runs cannot hit."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def enable_jax_cache():
+    """Turn on JAX's persistent compilation cache at `jax_cache_dir()`
+    and return the directory. Entry points (chip_smoke.py, bench.py, the
+    tools) call this once before their first compile; it is the only
+    place the repo chooses that directory, and it chooses nothing when
+    the environment already has."""
+    d = jax_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", d)
+    # the default (1 s) would leave every small program — the serving
+    # prefill buckets, each Pallas kernel — to recompile in every process
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return d
 
 HITS_TOTAL = "mxtpu_compile_cache_hits_total"
 _HITS_HELP = ("Executables served from the persistent compile cache "
@@ -78,7 +105,7 @@ _SAVED_HELP = ("Compile wall-clock skipped by cache hits: the stored "
                "cost, by fn.")
 
 # bump to invalidate every existing cache entry on a format change
-_SCHEMA = 1
+_SCHEMA = 2
 _SUFFIX = ".exe"
 
 _stats_lock = threading.Lock()
@@ -449,8 +476,14 @@ class CachedJit:
         try:
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
+            # the devices the program was compiled for, in assignment
+            # order: left to its default, deserialize_and_load binds the
+            # executable to EVERY device of the backend, and a one-device
+            # program then dies at call time on any multi-device host
+            by_id = {d.id: d for d in jax.devices()}
             compiled = deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["device_ids"]])
         except Exception:
             # stale flatbuffer, partial entry the manifest missed, ...
             st.evict(st.path(key), "corrupt", fn_name=self._name)
@@ -481,6 +514,9 @@ class CachedJit:
                 "schema": _SCHEMA, "salts": _salts(),
                 "payload": payload, "in_tree": in_tree,
                 "out_tree": out_tree, "fn": self._name,
+                "device_ids": [
+                    d.id for d in
+                    compiled.runtime_executable().local_devices()],
                 "graph_hash": ghash, "compile_s": compile_s,
                 "created": time.time(),
             }, fn_name=self._name)

@@ -29,12 +29,9 @@ def init_from_env():
         return True
     import jax
 
-    try:  # user may have initialized jax.distributed themselves
-        if jax.distributed.is_initialized():
-            _INITIALIZED = True
-            return True
-    except AttributeError:  # older jax without is_initialized
-        pass
+    if jax.distributed.is_initialized():  # the user did it themselves
+        _INITIALIZED = True
+        return True
     coord = _config.get("MXTPU_COORDINATOR")
     nproc = _config.get("MXTPU_NUM_PROCESSES")
     if not coord or nproc <= 1:

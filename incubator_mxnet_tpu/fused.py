@@ -101,7 +101,7 @@ class GluonTrainStep:
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, batch_axis=0, device=None,
-                 init_on_device=False, compute_dtype=None,
+                 compute_dtype=None,
                  shard_optimizer_states=False, remat=False,
                  remat_policy=None, shard_policy=None):
         self.net = net
@@ -109,18 +109,6 @@ class GluonTrainStep:
         self.opt = optimizer
         self.mesh = mesh
         self.device = device  # single target device (e.g. the TPU chip)
-        # regenerate parameter/state buffers ON the target device instead of
-        # shipping the host-initialized values over the wire: one tiny seed
-        # crosses instead of the full model (~100MB for ResNet-50). Values
-        # are fresh random draws with each param's host scale — identical
-        # program and throughput, different (valid) weights; meant for
-        # benchmarking remote-attached chips where bulk transfers are the
-        # least reliable link, not for resuming real training.
-        self.init_on_device = init_on_device
-        if init_on_device and mesh is not None:
-            raise ValueError(
-                "init_on_device supports the single-device path only; for a "
-                "mesh, params are placed by sharding annotations at build")
         # mixed precision the TPU way (the reference's multi-precision SGD,
         # ref: optimizer_op.cc mp_sgd_update): master params and optimizer
         # states stay float32; inside the step, floating params and inputs
@@ -234,15 +222,12 @@ class GluonTrainStep:
         ]
         self._params = [p.data()._data for p in self.param_objs]
         if self.device is not None and self.mesh is None:
-            if self.init_on_device:
-                self._params, self._states = self._materialize_on_device()
-            else:
-                # bulk host->device transfer of params/states (host init)
-                self._params = [jax.device_put(d, self.device)
-                                for d in self._params]
-                self._states = jax.tree_util.tree_map(
-                    lambda d: jax.device_put(d, self.device), self._states
-                )
+            # bulk host->device transfer of params/states (host init)
+            self._params = [jax.device_put(d, self.device)
+                            for d in self._params]
+            self._states = jax.tree_util.tree_map(
+                lambda d: jax.device_put(d, self.device), self._states
+            )
         mesh = self.mesh
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -334,56 +319,6 @@ class GluonTrainStep:
                     out_shardings=self._out_sh),
             donated=(0, 1))
         self._built = True
-
-    def _materialize_on_device(self):
-        """Regenerate param/state buffers on the target device.
-
-        One jitted program per group; only a seed crosses the wire. Each
-        parameter is redrawn as mean + std * normal with its host-init
-        moments (so BN gammas stay at 1.0 exactly, conv kernels keep their
-        Xavier scale); optimizer-state arrays are zeros except the rare
-        nonzero leaf, which is transferred as-is."""
-        import numpy as np
-
-        sharding = jax.sharding.SingleDeviceSharding(self.device)
-        specs = []
-        for d in self._params:
-            h = np.asarray(d, dtype=np.float32)
-            specs.append((tuple(d.shape), d.dtype,
-                          float(h.mean()), float(h.std())))
-
-        def gen(seed):
-            key = jax.random.PRNGKey(seed)
-            outs = []
-            for i, (shape, dtype, mean, std) in enumerate(specs):
-                k = jax.random.fold_in(key, i)
-                v = mean + jax.random.normal(k, shape, jnp.float32) * std
-                outs.append(v.astype(dtype))
-            return tuple(outs)
-
-        params = list(jax.jit(gen, out_shardings=sharding)(0))
-
-        leaves, treedef = jax.tree_util.tree_flatten(self._states)
-        resolved = {}
-        zero_specs, zero_idx = [], []
-        for i, leaf in enumerate(leaves):
-            if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-                if np.asarray(leaf).any():  # nonzero init state: ship it
-                    resolved[i] = jax.device_put(leaf, self.device)
-                else:
-                    zero_idx.append(i)
-                    zero_specs.append((tuple(leaf.shape), leaf.dtype))
-            else:
-                resolved[i] = leaf
-        if zero_idx:
-            zeros = jax.jit(
-                lambda: tuple(jnp.zeros(s, d) for s, d in zero_specs),
-                out_shardings=sharding)()
-            for j, i in enumerate(zero_idx):
-                resolved[i] = zeros[j]
-        states = jax.tree_util.tree_unflatten(
-            treedef, [resolved[i] for i in range(len(leaves))])
-        return params, states
 
     def shard_placements(self):
         """Per-parameter record of the optimizer-state placements the
@@ -808,8 +743,6 @@ class GluonTrainStep:
                 ca = self._step.aot_compile(*abstract).cost_analysis()
             else:
                 ca = self._step.lower(*abstract).compile().cost_analysis()
-            if isinstance(ca, list):  # older jax returns [dict]
-                ca = ca[0]
             res = {"flops": float(ca.get("flops", 0.0)),
                    "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
             if res and _telemetry.enabled():

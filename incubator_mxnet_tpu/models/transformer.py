@@ -371,14 +371,15 @@ def prefill(params, cache, prompt, cfg: TransformerConfig):
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_pages: int,
                         page_size: int):
-    """Per-layer paged K/V pool: (L, num_pages, page_size, H, Dh). No
-    position scalar — slot positions live with the caller (the engine),
-    one per decode slot. Page 0 is the null page by convention
+    """Per-layer paged K/V pool: (L, H, num_pages, page_size, Dh) —
+    head-major, the layout ops.pallas_kernels' paged kernels block per
+    head. No position scalar — slot positions live with the caller (the
+    engine), one per decode slot. Page 0 is the null page by convention
     (serving.pages.PageAllocator never hands it out): dead slots and
     padded prefill rows scatter their writes there."""
     H = cfg.n_heads
     Dh = cfg.d_model // H
-    shape = (cfg.n_layers, num_pages, page_size, H, Dh)
+    shape = (cfg.n_layers, H, num_pages, page_size, Dh)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -389,6 +390,16 @@ def _page_write_index(page_table, positions, page_size):
     page = jnp.take_along_axis(
         page_table, (positions // page_size)[:, None], axis=1)[:, 0]
     return page * page_size + positions % page_size
+
+
+def _pool_write(pool, write_idx, rows):
+    """Scatter token rows (N, H, Dh) into one layer's pool
+    (H, num_pages, page_size, Dh) at flat rows write_idx (N,), each
+    page * page_size + offset."""
+    H, num_pages, page_size, Dh = pool.shape
+    flat = pool.reshape(H, num_pages * page_size, Dh)
+    flat = flat.at[:, write_idx].set(rows.astype(pool.dtype).swapaxes(0, 1))
+    return flat.reshape(pool.shape)
 
 
 def decode_step_paged(params, paged, tokens, positions, page_table,
@@ -403,7 +414,7 @@ def decode_step_paged(params, paged, tokens, positions, page_table,
     the caller discards. Returns (logits (S, V), new_paged). Shapes are
     static in (S, P_max, pool) — every call is one XLA program."""
     S = tokens.shape[0]
-    num_pages, page_size = paged["k"].shape[1], paged["k"].shape[2]
+    page_size = paged["k"].shape[3]
     x = params["embed"][tokens] + params["pos"][positions]  # (S, d)
     n_valid = positions + 1
     write_idx = _page_write_index(page_table, positions, page_size)
@@ -411,16 +422,13 @@ def decode_step_paged(params, paged, tokens, positions, page_table,
     stacked = {k: params[k] for k in _stack_keys(params)}
 
     def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in  # (num_pages, page_size, H, Dh)
+        lp, k_pool, v_pool = layer_in  # (H, num_pages, page_size, Dh)
         h = _ln(x, lp["ln1_g"], lp["ln1_b"])
         q = (h @ lp["wq"]).reshape(S, cfg.n_heads, -1)
         k = (h @ lp["wk"]).reshape(S, cfg.n_heads, -1)
         v = (h @ lp["wv"]).reshape(S, cfg.n_heads, -1)
-        flat = (num_pages * page_size,) + k_pool.shape[2:]
-        k_pool = k_pool.reshape(flat).at[write_idx].set(
-            k.astype(k_pool.dtype)).reshape(k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[write_idx].set(
-            v.astype(v_pool.dtype)).reshape(v_pool.shape)
+        k_pool = _pool_write(k_pool, write_idx, k)
+        v_pool = _pool_write(v_pool, write_idx, v)
         from ..ops.pallas_kernels import paged_decode_attention
 
         a = paged_decode_attention(q, k_pool, v_pool, page_table, n_valid)
@@ -451,7 +459,7 @@ def prefill_paged(params, paged, prompts, true_lens, page_table,
     logits (S, V) at each row's LAST REAL token — the first sampled
     continuation token, matching prefill()'s x[:, -1] for full rows."""
     S, T_b = prompts.shape
-    num_pages, page_size = paged["k"].shape[1], paged["k"].shape[2]
+    page_size = paged["k"].shape[3]
     x = params["embed"][prompts] + params["pos"][:T_b][None]
     stacked = {k: params[k] for k in _stack_keys(params)}
 
@@ -469,13 +477,10 @@ def prefill_paged(params, paged, prompts, true_lens, page_table,
         q = _split_heads(h @ lp["wq"], cfg.n_heads)
         k = _split_heads(h @ lp["wk"], cfg.n_heads)
         v = _split_heads(h @ lp["wv"], cfg.n_heads)
-        flat = (num_pages * page_size,) + k_pool.shape[2:]
-        kw = k.reshape((S * T_b,) + k.shape[2:]).astype(k_pool.dtype)
-        vw = v.reshape((S * T_b,) + v.shape[2:]).astype(v_pool.dtype)
-        k_pool = k_pool.reshape(flat).at[write_idx].set(kw).reshape(
-            k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[write_idx].set(vw).reshape(
-            v_pool.shape)
+        k_pool = _pool_write(k_pool, write_idx,
+                             k.reshape((S * T_b,) + k.shape[2:]))
+        v_pool = _pool_write(v_pool, write_idx,
+                             v.reshape((S * T_b,) + v.shape[2:]))
         a = _dense_attention(q, k, v, causal=True)
         x = x + a.reshape(S, T_b, cfg.d_model) @ lp["wo"]
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
@@ -519,7 +524,7 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
     Returns (logits (S, Q, V), new_paged). Shapes are static in
     (S, Q, P_max, pool) — every call is one XLA program."""
     S, Q = tokens.shape
-    num_pages, page_size = paged["k"].shape[1], paged["k"].shape[2]
+    page_size = paged["k"].shape[3]
     j = jnp.arange(Q, dtype=jnp.int32)
     pos = start[:, None] + j[None, :]  # (S, Q) global positions
     cap = min(page_table.shape[1] * page_size, params["pos"].shape[0])
@@ -539,13 +544,10 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
         q = _split_heads(h @ lp["wq"], cfg.n_heads)  # (S, Q, H, Dh)
         k = _split_heads(h @ lp["wk"], cfg.n_heads)
         v = _split_heads(h @ lp["wv"], cfg.n_heads)
-        flat = (num_pages * page_size,) + k_pool.shape[2:]
-        kw = k.reshape((S * Q,) + k.shape[2:]).astype(k_pool.dtype)
-        vw = v.reshape((S * Q,) + v.shape[2:]).astype(v_pool.dtype)
-        k_pool = k_pool.reshape(flat).at[write_idx].set(kw).reshape(
-            k_pool.shape)
-        v_pool = v_pool.reshape(flat).at[write_idx].set(vw).reshape(
-            v_pool.shape)
+        k_pool = _pool_write(k_pool, write_idx,
+                             k.reshape((S * Q,) + k.shape[2:]))
+        v_pool = _pool_write(v_pool, write_idx,
+                             v.reshape((S * Q,) + v.shape[2:]))
         from ..ops.pallas_kernels import paged_decode_attention_wide
 
         a = paged_decode_attention_wide(q, k_pool, v_pool, page_table,

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "softmax_xent", "flash_decode",
            "dense_decode_attention", "paged_decode_attention",
@@ -402,11 +403,12 @@ def softmax_xent(logits, labels, block_b=8, interpret=None, vma=None):
         # inside a shard_map body the outputs must carry the same
         # varying-mesh-axes metadata as the traced inputs
         vma = tuple(getattr(jax.typeof(flat), "vma", ()) or ())
-    if interpret and vma:
+    if interpret and vma and jax.default_backend() == "cpu":
         # interpret-mode Pallas inside shard_map trips jax's vma accounting
-        # in the emulation machinery itself (a CPU-test-only configuration);
-        # use the numerically-identical dense form there. Compiled kernels
-        # (real TPU) take the pallas_call path with vma-tagged outputs.
+        # in the emulation machinery itself (a CPU-test-only configuration,
+        # unreachable on a TPU backend); use the numerically-identical
+        # dense form there. Compiled kernels take the pallas_call path
+        # with vma-tagged outputs.
         logp = jax.nn.log_softmax(flat.astype(jnp.float32), axis=-1)
         loss = -jnp.take_along_axis(logp, lab[:, None], axis=-1)[:, 0]
         return loss.reshape(shape)
@@ -452,33 +454,51 @@ def _per_seq_n_valid(n_valid, batch):
     return jnp.broadcast_to(nv, (batch,))
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, nv_ref, o_ref, *, block_k, scale):
+def _online_softmax_update(q, k, v, live, carry, scale):
+    """One key block of the decode kernels' online softmax. q (Q, d),
+    k/v (block, d), live (Q, block) bool. Row statistics ride as (Q, 1)
+    columns: Mosaic has no layout for the rank-1 (Q,) vectors a plain
+    axis reduction would carry through the fori_loop."""
+    o, m, l = carry
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(live, s, _NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    o_new = o * alpha + jnp.dot(p.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32)
+    return o_new, m_new, l_new
+
+
+def _online_softmax_init(n_q, d):
+    return (jnp.zeros((n_q, d), jnp.float32),
+            jnp.full((n_q, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((n_q, 1), jnp.float32))
+
+
+def _online_softmax_finish(carry, dtype):
+    o, _, l = carry
+    return (o / jnp.maximum(l, 1e-30)).astype(dtype)
+
+
+def _decode_kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, *, block_k, scale):
+    """One (b, h) grid step; nv_ref (B,) is a scalar-prefetch (SMEM) ref."""
     q = q_ref[...]  # (1, d)
-    nv = nv_ref[0]
+    nv = nv_ref[pl.program_id(0)]
 
     def body(j, carry):
-        o, m, l = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :]
-        v = v_ref[pl.ds(j * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        idx = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(idx < nv, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        o_new = o * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[pl.ds(start, block_k), :]
+        v = v_ref[pl.ds(start, block_k), :]
+        idx = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        return _online_softmax_update(q, k, v, idx < nv, carry, scale)
 
-    d = q.shape[1]
-    o0 = jnp.zeros((1, d), jnp.float32)
-    m0 = jnp.full((1,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((1,), jnp.float32)
     num_k = (nv + block_k - 1) // block_k  # dynamic: stream only live blocks
-    o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l[:, None]).astype(o_ref.dtype)
+    carry = jax.lax.fori_loop(0, num_k, body,
+                              _online_softmax_init(1, q.shape[1]))
+    o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
 
 
 def dense_decode_attention(q, k_cache, v_cache, n_valid):
@@ -689,164 +709,105 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
     nv = _per_seq_n_valid(n_valid, B)
     kernel = functools.partial(_decode_kernel, block_k=blk,
                                scale=1.0 / np.sqrt(D))
-    o = pl.pallas_call(
-        kernel,
+    # n_valid rides as a scalar-prefetch (SMEM) argument: a rank-1 (1,)
+    # block over a (B,) array is not a legal TPU block shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, H),
         in_specs=[
-            pl.BlockSpec((None, None, 1, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1,), lambda b, h: (b,)),
+            pl.BlockSpec((None, None, 1, D), lambda b, h, *refs: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, T, D), lambda b, h, *refs: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, T, D), lambda b, h, *refs: (b, h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, 1, D),
-                               lambda b, h: (b, h, 0, 0)),
+                               lambda b, h, *refs: (b, h, 0, 0)),
+    )
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr, nv)
+    )(nv, qr, kr, vr)
     return o.reshape(B, H, D)
 
 
 # ---------------------------------------------------------------------------
 # Paged decode: single-query attention where K/V live in a global page pool
 # shared by every sequence (the vLLM/PagedAttention data structure). Each
-# sequence owns a page-table row; the kernel walks it with pl.ds gathers and
-# runs the same online-softmax accumulation as _decode_kernel. Per-sequence
-# valid lengths make it the continuous-batching serving kernel: slots at
-# different depths decode in ONE launch of one compiled program.
+# sequence owns a page-table row; the kernel walks it with dynamic page
+# indices and runs the same online-softmax accumulation as _decode_kernel.
+# Per-sequence valid lengths make it the continuous-batching serving kernel:
+# slots at different depths decode in ONE launch of one compiled program.
+#
+# Pool layout is head-major, (H, num_pages, page_size, D): the block one
+# grid step maps is then one head's whole pool, (num_pages, page_size, D),
+# whose last two dimensions are whole — the only shape the TPU lowering
+# accepts for a per-head slice (a head axis squeezed in the second-minor
+# position is refused). The grid is (H, B) with the head outermost so the
+# pool block is fetched once per head, not once per (head, sequence).
 # ---------------------------------------------------------------------------
 
-
-def _paged_decode_kernel(pt_ref, nv_ref, q_ref, k_ref, v_ref, o_ref, *,
-                         page_size, scale):
-    """One (b, h) grid step. pt_ref (B, P_max) and nv_ref (B,) are
-    scalar-prefetch refs (SMEM — readable for control flow and pl.ds
-    gather indices); k_ref/v_ref see the whole pool for head h."""
-    b = pl.program_id(0)
-    q = q_ref[...]  # (1, d)
-    nv = nv_ref[b]
-
-    def body(j, carry):
-        o, m, l = carry
-        page = pt_ref[b, j]
-        k = k_ref[pl.ds(page, 1)].reshape(page_size, -1)
-        v = v_ref[pl.ds(page, 1)].reshape(page_size, -1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        idx = (j * page_size
-               + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        s = jnp.where(idx < nv, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        o_new = o * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
-
-    d = q.shape[1]
-    o0 = jnp.zeros((1, d), jnp.float32)
-    m0 = jnp.full((1,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((1,), jnp.float32)
-    # walk only the live pages of THIS sequence (dynamic bound, like the
-    # dynamic num_k of _decode_kernel); dead slots (nv == 0) do no work
-    num_pages = (nv + page_size - 1) // page_size
-    o, m, l = jax.lax.fori_loop(0, num_pages, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l[:, None]).astype(o_ref.dtype)
+# Scoped VMEM the paged kernels ask Mosaic for. One head's K and V pool
+# blocks, double-buffered by the pipeline, must fit under it; a pool that
+# does not is an error (_check_pool_fits_vmem), never a dense fallback.
+PAGED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_PAGED_VMEM_RESERVE_BYTES = 4 * 1024 * 1024  # q/o blocks, carries, spills
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, n_valid,
-                           interpret=None):
-    """Single-query attention over a paged KV cache.
+def paged_pool_vmem_bytes(num_pages, page_size, head_dim, dtype):
+    """VMEM the pipeline holds for one head of a paged pool: K and V
+    blocks, two buffers each, every page padded to the dtype's native
+    (sublane, 128-lane) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * 4 // itemsize
+    rows = -(-page_size // sublane) * sublane
+    lanes = -(-head_dim // 128) * 128
+    return 2 * 2 * num_pages * rows * lanes * itemsize
 
-    q: (B, H, D) — one query per decode slot;
-    k_pages/v_pages: (num_pages, page_size, H, D) — the global page pool;
-    page_table: (B, P_max) int32 — page ids owned by each slot, in
-    sequence order (entries past the live length are ignored);
-    n_valid: (B,) int32 (or scalar) — tokens live per slot; 0 marks a
-    dead slot (its output is the zero-length softmax of the null page —
-    finite garbage the caller discards).
 
-    Returns (B, H, D). The pool stays in its natural layout; the grid is
-    (B, H) and each step streams only ceil(n_valid/page_size) pages of
-    its own sequence via pl.ds gathers driven by the scalar-prefetched
-    page table (so HBM traffic per decoded token is the live cache, not
-    B x T_max)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, D = q.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
-    nv = _per_seq_n_valid(n_valid, B)
-    pt = jnp.asarray(page_table, jnp.int32)
-    qr = q.reshape(B, H, 1, D)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H),
-        in_specs=[
-            pl.BlockSpec((None, None, 1, D),
-                         lambda b, h, *refs: (b, h, 0, 0)),
-            pl.BlockSpec((num_pages, page_size, None, D),
-                         lambda b, h, *refs: (0, 0, h, 0)),
-            pl.BlockSpec((num_pages, page_size, None, D),
-                         lambda b, h, *refs: (0, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, 1, D),
-                               lambda b, h, *refs: (b, h, 0, 0)),
-    )
-    kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
-                               scale=1.0 / np.sqrt(D))
-    o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        interpret=interpret,
-    )(pt, nv, qr, k_pages, v_pages)
-    return o.reshape(B, H, D)
+def _check_pool_fits_vmem(num_pages, page_size, head_dim, dtype):
+    budget = PAGED_VMEM_LIMIT_BYTES - _PAGED_VMEM_RESERVE_BYTES
+    need = paged_pool_vmem_bytes(num_pages, page_size, head_dim, dtype)
+    if need > budget:
+        per_page = need // num_pages
+        raise ValueError(
+            f"paged KV pool of {num_pages} pages (page_size={page_size}, "
+            f"head_dim={head_dim}, {jnp.dtype(dtype).name}) needs "
+            f"{need} bytes of VMEM per head; the paged decode kernels map "
+            f"one head's whole pool into VMEM and fit at most "
+            f"{budget // per_page} such pages ({budget} bytes)")
 
 
 def _paged_decode_wide_kernel(pt_ref, nb_ref, q_ref, k_ref, v_ref, o_ref, *,
                               page_size, scale):
-    """One (b, h) grid step with Q query rows at consecutive positions:
+    """One (h, b) grid step with Q query rows at consecutive positions:
     row i sits at position nb + i and attends idx < nb + i + 1 — the
-    paged prefix plus causal masking WITHIN the call. Same page walk and
-    online-softmax accumulation as _paged_decode_kernel, with per-row
-    (Q,) carries instead of (1,)."""
-    b = pl.program_id(0)
+    paged prefix plus causal masking WITHIN the call. pt_ref (B, P_max)
+    and nb_ref (B,) are scalar-prefetch refs (SMEM — readable for control
+    flow and dynamic page indices); k_ref/v_ref see the whole pool of
+    head h as (num_pages, page_size, d)."""
+    b = pl.program_id(1)
     q = q_ref[...]  # (Q, d)
     nb = nb_ref[b]
-    n_q = q.shape[0]
+    n_q, d = q.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 1)
 
     def body(j, carry):
-        o, m, l = carry
         page = pt_ref[b, j]
-        k = k_ref[pl.ds(page, 1)].reshape(page_size, -1)
-        v = v_ref[pl.ds(page, 1)].reshape(page_size, -1)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        idx = (j * page_size
-               + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        s = jnp.where(idx < nb + row + 1, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1)
-        o_new = o * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+        live = j * page_size + col < nb + row + 1
+        return _online_softmax_update(q, k_ref[page], v_ref[page], live,
+                                      carry, scale)
 
-    d = q.shape[1]
-    o0 = jnp.zeros((n_q, d), jnp.float32)
-    m0 = jnp.full((n_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_q,), jnp.float32)
     # the deepest row attends nb + Q tokens; clamp the walk to the table
     # width so speculative rows past a sequence's last owned page never
-    # index the table out of bounds (their outputs are discarded)
+    # index the table out of bounds (their outputs are discarded). Dead
+    # slots (nb == 0 with Q == 1) walk one page of the null page.
     num_pages = jnp.minimum((nb + n_q + page_size - 1) // page_size,
                             pt_ref.shape[1])
-    o, m, l = jax.lax.fori_loop(0, num_pages, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[...] = (o / l[:, None]).astype(o_ref.dtype)
+    carry = jax.lax.fori_loop(0, num_pages, body,
+                              _online_softmax_init(n_q, d))
+    o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
 
 
 def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
@@ -855,39 +816,43 @@ def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
     tokens per sequence in ONE launch.
 
     q: (B, Q, H, D) — query i of sequence b sits at position
-    n_base[b] + i; k_pages/v_pages: (num_pages, page_size, H, D) pool
+    n_base[b] + i; k_pages/v_pages: (H, num_pages, page_size, D) pool
     (the caller has already scattered the Q new tokens' K/V into it);
-    page_table: (B, P_max) int32; n_base: (B,) int32 — tokens cached
-    per sequence BEFORE this call's first query. Query i attends
-    positions < n_base + i + 1 (paged prefix + intra-call causal), so a
-    single launch serves chunked prefill (Q = chunk), cached-prefix
-    tail prefill (n_base = cached tokens) and speculative verification
-    (Q = lookahead + 1) — the vLLM/Sarathi "one kernel, many query
-    widths" trick on the repo's own page walk.
+    page_table: (B, P_max) int32 — page ids owned by each sequence, in
+    order (entries past the live length are ignored); n_base: (B,) int32
+    — tokens cached per sequence BEFORE this call's first query. Query i
+    attends positions < n_base + i + 1 (paged prefix + intra-call
+    causal), so a single launch serves chunked prefill (Q = chunk),
+    cached-prefix tail prefill (n_base = cached tokens) and speculative
+    verification (Q = lookahead + 1) — the vLLM/Sarathi "one kernel,
+    many query widths" trick on the repo's own page walk.
+
+    Each grid step streams only ceil((n_base+Q)/page_size) pages of its
+    own sequence out of the VMEM-resident head pool. Raises ValueError
+    when one head's pool does not fit PAGED_VMEM_LIMIT_BYTES.
 
     Returns (B, Q, H, D)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    from jax.experimental.pallas import tpu as pltpu
-
     B, Q, H, D = q.shape
-    num_pages, page_size = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page_size = k_pages.shape[1], k_pages.shape[2]
+    _check_pool_fits_vmem(num_pages, page_size, D, k_pages.dtype)
     nb = _per_seq_n_valid(n_base, B)
     pt = jnp.asarray(page_table, jnp.int32)
     qr = q.transpose(0, 2, 1, 3)  # (B, H, Q, D)
+    pool_spec = pl.BlockSpec((None, num_pages, page_size, D),
+                             lambda h, b, *refs: (h, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H),
+        grid=(H, B),
         in_specs=[
             pl.BlockSpec((None, None, Q, D),
-                         lambda b, h, *refs: (b, h, 0, 0)),
-            pl.BlockSpec((num_pages, page_size, None, D),
-                         lambda b, h, *refs: (0, 0, h, 0)),
-            pl.BlockSpec((num_pages, page_size, None, D),
-                         lambda b, h, *refs: (0, 0, h, 0)),
+                         lambda h, b, *refs: (b, h, 0, 0)),
+            pool_spec,
+            pool_spec,
         ],
         out_specs=pl.BlockSpec((None, None, Q, D),
-                               lambda b, h, *refs: (b, h, 0, 0)),
+                               lambda h, b, *refs: (b, h, 0, 0)),
     )
     kernel = functools.partial(_paged_decode_wide_kernel,
                                page_size=page_size,
@@ -896,6 +861,25 @@ def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Q, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(pt, nb, qr, k_pages, v_pages)
     return o.transpose(0, 2, 1, 3)
+
+
+def paged_decode_attention(q, k_pages, v_pages, page_table, n_valid,
+                           interpret=None):
+    """Single-query attention over a paged KV cache — the Q = 1 case of
+    paged_decode_attention_wide.
+
+    q: (B, H, D) — one query per decode slot; k_pages/v_pages:
+    (H, num_pages, page_size, D); page_table: (B, P_max) int32;
+    n_valid: (B,) int32 (or scalar) — tokens live per slot INCLUDING the
+    one just written; 0 marks a dead slot (its output is finite garbage
+    from the null page that the caller discards). Returns (B, H, D)."""
+    nv = _per_seq_n_valid(n_valid, q.shape[0])
+    o = paged_decode_attention_wide(q[:, None], k_pages, v_pages,
+                                    page_table, jnp.maximum(nv - 1, 0),
+                                    interpret=interpret)
+    return o[:, 0]

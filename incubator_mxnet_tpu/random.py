@@ -11,6 +11,7 @@ from __future__ import annotations
 import threading
 
 import jax
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 __all__ = ["seed", "next_key", "current_seed", "get_state", "set_state"]
 
@@ -27,17 +28,6 @@ _BLOCK_N = 256
 _BLOCK = None
 _BLOCK_BASE = 0
 _REFILL = None
-
-try:  # moved between jax.core and jax._src.core across jax versions
-    from jax.core import trace_state_clean as _trace_state_clean
-except ImportError:
-    try:
-        from jax._src.core import trace_state_clean as _trace_state_clean
-    except ImportError:
-        def _trace_state_clean():
-            # unknown jax internals: disable the block path entirely
-            # (correctness of traced callers over the amortization win)
-            return False
 
 
 def seed(seed_state, ctx="all"):

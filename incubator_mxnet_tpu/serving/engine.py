@@ -307,9 +307,10 @@ class ServingEngine:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), paged
 
     def _copy_fn(self, paged, src, dst):
+        # pool is (L, H, num_pages, page_size, Dh): pages are axis 2
         k, v = paged["k"], paged["v"]
-        return {"k": k.at[:, dst].set(k[:, src]),
-                "v": v.at[:, dst].set(v[:, src])}
+        return {"k": k.at[:, :, dst].set(k[:, :, src]),
+                "v": v.at[:, :, dst].set(v[:, :, src])}
 
     def _wide(self, n_q):
         """Wide-query program for `n_q` rows per slot — one named site

@@ -1,7 +1,7 @@
 """Host-side page allocator + prefix cache for the paged KV cache.
 
 The device pool (`models.transformer.init_paged_kv_cache`) is
-`(L, num_pages, page_size, H, Dh)`; this allocator owns the free list
+`(L, H, num_pages, page_size, Dh)`; this allocator owns the free list
 over `num_pages` and hands out page ids. Page 0 is the RESERVED NULL
 PAGE: it is never allocated, and dead decode slots / padded prefill rows
 scatter their writes there, so an all-zero page-table row is always a

@@ -4,7 +4,7 @@ Heartbeat/GetDeadNodes; reference surfaced as KVStore::get_num_dead_node).
 
 Launched with W>=3 workers.  The LAST rank exits immediately after its
 first beat; the survivors must observe exactly one dead node once the
-timeout lapses, and zero dead nodes before their own exit barrier.  Runs
+timeout lapses, and zero dead nodes before that.  Runs
 on raw sockets — no jax.distributed — so a worker vanishing cannot wedge a
 collective."""
 import os
@@ -48,6 +48,15 @@ def main():
         time.sleep(0.3)
     assert hb.num_dead() == 1, hb.num_dead()
     print(f"rank {rank}/{nw}: dist_heartbeat OK")
+    sys.stdout.flush()
+    if rank == 0:
+        # exit barrier: rank 0 hosts the heartbeat service, and a survivor
+        # that has not made its observation yet when the service goes away
+        # spends minutes in reconnect retries. Stay until every other
+        # survivor has left too (its beats go stale).
+        deadline = time.time() + 30
+        while time.time() < deadline and hb.num_dead() < nw - 1:
+            time.sleep(0.3)
 
 
 if __name__ == "__main__":

@@ -497,55 +497,6 @@ def test_nhwc_resnet_trains():
     assert float(loss.asscalar()) < l0
 
 
-def test_train_step_init_on_device():
-    """init_on_device regenerates params/states on the target device with
-    the host moments (BN gamma exactly 1, conv kernels at Xavier scale,
-    momentum zeros) and the step still trains."""
-    import jax
-    import numpy as np
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import fused, gluon, nd
-    from incubator_mxnet_tpu.gluon import nn
-
-    mx.random.seed(0)
-    net = nn.HybridSequential()
-    net.add(nn.Conv2D(8, 3, padding=1, layout="NHWC"))
-    net.add(nn.BatchNorm(axis=-1))
-    net.add(nn.Flatten())
-    net.add(nn.Dense(5))
-    net.initialize(mx.init.Xavier())
-    L = gluon.loss.SoftmaxCrossEntropyLoss()
-    opt = mx.optimizer.SGD(learning_rate=0.1, momentum=0.9)
-    step = fused.GluonTrainStep(net, lambda n, x, y: L(n(x), y), opt,
-                                device=jax.devices()[0], init_on_device=True)
-    rng = np.random.RandomState(0)
-    x = nd.array(rng.rand(4, 8, 8, 3).astype("float32"))
-    y = nd.array(rng.randint(0, 5, 4).astype("float32"))
-    step._build(x, y)  # materialize on device, before any update runs
-    # regenerated values: BN gamma exactly ones, conv kernel at host scale
-    by_name = dict(zip(step.names, step._params))
-    gamma = next(np.asarray(d) for n, d in by_name.items()
-                 if n.endswith("gamma"))
-    np.testing.assert_array_equal(gamma, np.ones_like(gamma))
-    kernel_host = next(p.data().asnumpy()
-                       for n, p in net.collect_params().items()
-                       if "conv" in n and n.endswith("weight"))
-    kernel_dev = next(np.asarray(d) for n, d in by_name.items()
-                      if "conv" in n and n.endswith("weight"))
-    assert not np.array_equal(kernel_dev, kernel_host)  # fresh draw...
-    assert np.isclose(kernel_dev.std(), kernel_host.std(),
-                      rtol=0.5)  # ...at the same scale
-    # momentum state starts at zeros on device
-    st = next(s for s, m in zip(step._states, step.grad_mask) if m)
-    flat = jax.tree_util.tree_leaves(st)
-    assert flat and all(not np.asarray(leaf).any() for leaf in flat)
-    l0 = float(step(x, y).asscalar())
-    assert np.isfinite(l0)
-    for _ in range(4):
-        loss = step(x, y)
-    assert float(loss.asscalar()) < l0
-
-
 def test_train_step_compute_dtype_mixed_precision():
     """compute_dtype='bfloat16': params/optimizer states stay float32
     (master weights), the forward runs in bf16, and a few steps track the

@@ -4,7 +4,7 @@ The manifest `tests/data/ref_public_ops.txt` is pinned output of
 `tools/gen_ref_op_manifest.py`, which scrapes the reference NNVM registry
 (ref: src/operator/**/*.cc NNVM_REGISTER_OP / MXNET_OPERATOR_REGISTER_* /
 .add_alias). Pinning it makes "the registry diff vs the reference is empty"
-a tested invariant rather than a PARITY.md claim: if the manifest or the
+a tested invariant rather than a claim in prose: if the manifest or the
 registry drifts, this fails.
 """
 import os

@@ -9,7 +9,7 @@ import pytest
 from incubator_mxnet_tpu.models import transformer as tfm
 from incubator_mxnet_tpu.ops.pallas_kernels import (
     DECODE_BLOCK, dense_decode_attention, flash_decode,
-    paged_decode_attention)
+    paged_decode_attention, paged_decode_attention_wide)
 from incubator_mxnet_tpu.serving import PageAllocator, ServingEngine
 
 
@@ -20,8 +20,15 @@ def _small_cfg(**kw):
     return tfm.TransformerConfig(**base)
 
 
+def _pool(pages):
+    """(P, ps, H, D) test pages -> the kernels' head-major pool layout
+    (H, P, ps, D)."""
+    return jnp.asarray(pages.transpose(2, 0, 1, 3))
+
+
 def _gather_dense(k_pages, v_pages, page_table, page_size):
-    """Rebuild the per-sequence dense caches a page table describes."""
+    """Rebuild the per-sequence dense caches a page table describes
+    (pages given token-major, (P, ps, H, D))."""
     B, P_max = page_table.shape
     T = P_max * page_size
     H, D = k_pages.shape[2], k_pages.shape[3]
@@ -48,7 +55,7 @@ def test_paged_decode_matches_dense_ragged():
     page_table = np.array([[1, 2, 3, 0], [4, 0, 0, 0],
                            [5, 6, 0, 0], [0, 0, 0, 0]], np.int32)
     got = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
+        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
         jnp.asarray(page_table), jnp.asarray(n_valid), interpret=True))
     kc, vc = _gather_dense(k_pages, v_pages, page_table, ps)
     want = np.asarray(dense_decode_attention(
@@ -79,13 +86,55 @@ def test_paged_decode_pages_reused_after_free():
     n_valid = np.array([2 * ps], np.int32)
     q = rng.randn(1, H, D).astype(np.float32)
     got = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
+        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
         jnp.asarray(table), jnp.asarray(n_valid), interpret=True))
     kc, vc = _gather_dense(k_pages, v_pages, table, ps)
     want = np.asarray(dense_decode_attention(
         jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
         jnp.asarray(n_valid)))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("Q", [1, 4])
+def test_paged_decode_wide_matches_dense_per_row(Q):
+    """Row i of the wide kernel is single-query attention at depth
+    n_base + i + 1 over the same pages (paged prefix + causal within
+    the call)."""
+    rng = np.random.RandomState(3)
+    B, H, D, ps, P, P_max = 3, 2, 32, 8, 16, 4
+    q = rng.randn(B, Q, H, D).astype(np.float32)
+    k_pages = rng.randn(P, ps, H, D).astype(np.float32)
+    v_pages = rng.randn(P, ps, H, D).astype(np.float32)
+    n_base = np.array([11, 0, 16], np.int32)
+    page_table = np.array([[1, 2, 3, 0], [4, 0, 0, 0], [5, 6, 7, 0]],
+                          np.int32)
+    got = np.asarray(paged_decode_attention_wide(
+        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
+        jnp.asarray(page_table), jnp.asarray(n_base), interpret=True))
+    kc, vc = _gather_dense(k_pages, v_pages, page_table, ps)
+    for i in range(Q):
+        want = np.asarray(dense_decode_attention(
+            jnp.asarray(q[:, i]), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(n_base + i + 1)))
+        np.testing.assert_allclose(got[:, i], want, rtol=2e-5, atol=2e-5)
+
+
+def test_paged_pool_too_large_for_vmem_raises():
+    """A pool whose per-head block cannot sit in VMEM is an error naming
+    the largest pool that fits — never a silent dense fallback."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+
+    H, D, ps = 2, 64, 16
+    fits = (pk.PAGED_VMEM_LIMIT_BYTES - pk._PAGED_VMEM_RESERVE_BYTES) \
+        // (pk.paged_pool_vmem_bytes(1, ps, D, jnp.bfloat16))
+    pool = jax.ShapeDtypeStruct((H, fits + 1, ps, D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, H, D), jnp.bfloat16)
+    pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
+    nv = jax.ShapeDtypeStruct((1,), jnp.int32)
+    with pytest.raises(ValueError, match=f"at most {fits} such pages"):
+        jax.eval_shape(paged_decode_attention, q, pool, pool, pt, nv)
+    ok = jax.ShapeDtypeStruct((H, fits, ps, D), jnp.bfloat16)
+    jax.eval_shape(paged_decode_attention, q, ok, ok, pt, nv)
 
 
 def test_dense_decode_accepts_per_sequence_vector():

@@ -1,14 +1,14 @@
 #!/usr/bin/env python
-"""Config sweep over the headline train step for the next chip window.
+"""Config sweep over the headline train step (needs a TPU, like bench.py).
 
 The round-5 profiler finding (docs/PERF_ANALYSIS.md §0): the bf16 step is
-HBM-bandwidth-bound and batch 256 REGRESSES (remat/spill). This sweep
-turns a future measurement window into optimization data instead of a
-re-measurement: each config runs bench.py's own child (BENCH_CHILD=1,
-honest device-get sync inside) and logs one JSON line per config —
-including `bytes_per_step` from XLA's cost model, so the traffic levers
-(remat policy, fused epilogue, stochastic rounding) report the byte
-reduction next to the throughput they buy.
+HBM-bandwidth-bound and batch 256 REGRESSES (remat/spill). Each config
+runs bench.py's own child (BENCH_CHILD=1), one process per config so each
+holds the chip alone (this parent never imports jax), and logs one JSON
+line per config — including `bytes_per_step` from XLA's cost model, so
+the traffic levers (remat policy, fused epilogue, stochastic rounding)
+report the byte reduction next to the throughput they buy. A config that
+times out or crashes is logged and makes the sweep exit non-zero.
 
 Usage: python tools/bench_sweep.py [--configs a,b,...]
                                    [--remat-policy P] [--fused-epilogue]
@@ -18,7 +18,7 @@ under the selective policy.)
 Configs (comma list; default all):
   bs64        bf16 NHWC batch 64   (below the spill threshold?)
   bs96        bf16 NHWC batch 96
-  base        bf16 NHWC batch 128  (the banked headline, for control)
+  base        bf16 NHWC batch 128  (the headline configuration, for control)
   bs256       bf16 NHWC batch 256  (the measured regression case)
   remat       bf16 NHWC batch 128 + blanket jax.checkpoint (legacy)
   remat-convs bf16 NHWC batch 128 + MXTPU_REMAT_POLICY=convs
@@ -93,6 +93,7 @@ def main():
         f"remat_policy={args.remat_policy} "
         f"shard_policy={args.shard_policy} "
         f"fused_epilogue={args.fused_epilogue} -> {_log_path}")
+    failed = []
     for name in args.configs.split(","):
         cfg = CONFIGS[name.strip()]
         env = dict(os.environ)
@@ -105,7 +106,6 @@ def main():
             env["MXTPU_FUSED_EPILOGUE"] = "1"
         env["BENCH_CHILD"] = "1"
         env.setdefault("BENCH_ITERS", "20")
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jaxcache")
         t0 = time.perf_counter()
         try:
             p = subprocess.run([sys.executable,
@@ -114,6 +114,7 @@ def main():
                                timeout=args.timeout, env=env)
         except subprocess.TimeoutExpired:
             log(f"{name}: TIMEOUT after {args.timeout}s")
+            failed.append(name)
             continue
         line = None
         for ln in reversed((p.stdout or "").strip().splitlines()):
@@ -124,13 +125,17 @@ def main():
             if isinstance(d, dict) and "ips" in d:
                 line = d
                 break
-        if line is None:
-            log(f"{name}: rc={p.returncode} no JSON "
+        if p.returncode != 0 or line is None:
+            log(f"{name}: rc={p.returncode} "
                 f"(stderr: {(p.stderr or '').strip()[-300:]})")
+            failed.append(name)
             continue
         line["config"] = name
         line["wall_s"] = round(time.perf_counter() - t0, 1)
         log(json.dumps(line))
+    if failed:
+        sys.exit(f"bench_sweep: {len(failed)} config(s) failed: "
+                 f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

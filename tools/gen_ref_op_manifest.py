@@ -3,7 +3,7 @@
 
 Produces the pinned manifest `tests/data/ref_public_ops.txt` that
 `tests/test_registry_manifest.py` diffs the live registry against, turning
-"registry diff empty" from a PARITY.md claim into a tested invariant.
+"registry diff empty" from a claim in prose into a tested invariant.
 
 Sources scraped (ref: src/operator/**/*.cc):
 - `NNVM_REGISTER_OP(x)` registrations

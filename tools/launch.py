@@ -7,10 +7,33 @@ DCN/ICI collectives. Supports local multi-process launch (the reference's
 `--launcher local` used by the nightly dist tests) and ssh host lists.
 """
 import argparse
+import glob
 import os
 import secrets
 import subprocess
 import sys
+
+
+# PCI device ids of Google (vendor 0x1ae0) TPU chips, as jax's own
+# hardware_utils lists them: v3, v4, v5p, v5e, v6e, 7x and one unnamed part
+_TPU_PCI_DEVICE_IDS = {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063",
+                       "0x006f", "0x0076"}
+
+
+def _local_tpu_chips():
+    """TPU chips on this host's PCI bus, counted from sysfs: the launcher
+    must not import jax, or it would hold the chips its ranks ask for."""
+    n = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor) as f:
+                if f.read().strip() != "0x1ae0":
+                    continue
+            with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+                n += f.read().strip() in _TPU_PCI_DEVICE_IDS
+        except OSError:
+            continue
+    return n
 
 
 def main():
@@ -30,6 +53,18 @@ def main():
     ps_secret = os.environ.get("MXTPU_PS_SECRET") or secrets.token_hex(16)
 
     if args.launcher == "local":
+        chips = _local_tpu_chips()
+        if (chips and args.num_workers > 1
+                and os.environ.get("JAX_PLATFORMS", "") != "cpu"):
+            # a TPU chip belongs to one process: N local ranks would each
+            # open every chip, and all but the first fail or hang
+            sys.exit(
+                f"launch.py: this host has {chips} TPU chip(s); "
+                f"{args.num_workers} local ranks would each open all of "
+                f"them, and a chip belongs to one process. Drive the "
+                f"chips of one host from ONE process (a jax.sharding.Mesh "
+                f"over jax.devices()), or set JAX_PLATFORMS=cpu for a "
+                f"CPU-only run.")
         procs = []
         for rank in range(args.num_workers):
             env = dict(os.environ)
