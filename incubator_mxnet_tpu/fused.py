@@ -545,6 +545,10 @@ class GluonTrainStep:
                 x if isinstance(x, NDArray) else NDArray(jnp.asarray(x)),
                 y if isinstance(y, NDArray) else NDArray(jnp.asarray(y)),
             )
+        with _telemetry.span("trainstep.call", n=self._n + 1):
+            return self._call(x, y)
+
+    def _call(self, x, y):
         xd = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         yd = y._data if isinstance(y, NDArray) else jnp.asarray(y)
         with _stepstats.phase("h2d"):
@@ -554,10 +558,15 @@ class GluonTrainStep:
             elif self.device is not None:
                 xd = jax.device_put(xd, self.device)
                 yd = jax.device_put(yd, self.device)
-        key = _global_random.next_key()
-        self._n += 1
-        self.opt.num_update = self._n
-        lr = self.opt.lr_scheduler(self._n) if self.opt.lr_scheduler else self.opt.lr
+        with _stepstats.phase("scalars"):
+            # the key, and two tiny uploads a step: lr and the step count
+            key = _global_random.next_key()
+            self._n += 1
+            self.opt.num_update = self._n
+            lr = (self.opt.lr_scheduler(self._n) if self.opt.lr_scheduler
+                  else self.opt.lr)
+            lr = jnp.asarray(lr, jnp.float32)
+            t = jnp.asarray(float(self._n), jnp.float32)
         sig = None
         telem = _telemetry.enabled()
         if telem and not getattr(self._step, "is_cached", False):
@@ -571,10 +580,7 @@ class GluonTrainStep:
         old_states = self._states if telem else None
         with _stepstats.phase("dispatch"):
             loss, self._params, self._states = self._step(
-                self._params, self._states, xd, yd, key,
-                jnp.asarray(lr, jnp.float32),
-                jnp.asarray(float(self._n), jnp.float32),
-            )
+                self._params, self._states, xd, yd, key, lr, t)
         if telem:
             self._retrack_states(old_states)
         if sig is not None:
@@ -605,6 +611,10 @@ class GluonTrainStep:
         if not self._built:
             self._build(NDArray._from_data(xd[0]), NDArray._from_data(yd[0]))
         k = int(xd.shape[0])
+        with _telemetry.span("trainstep.call", n=self._n + 1, steps=k):
+            return self._scan_call(xd, yd, k)
+
+    def _scan_call(self, xd, yd, k):
         if self._data_sharding is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 

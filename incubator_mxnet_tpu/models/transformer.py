@@ -113,10 +113,18 @@ def _stack_keys(params):
     return [k for k in params if k not in _NON_STACKED]
 
 
+# The scopes "norm", "attention" and "ffn" below are for whoever reads the
+# device side: they land in every op's `op_name` metadata (a dumped HLO,
+# TensorBoard's op profile), so a block's time can be sorted by them after a
+# refactor renames the Python around them. The v5e's trace events as
+# `jax.profiler.ProfileData` gives them carry the HLO text and no `op_name`
+# (PERF.md, PR 25): the benchmark's reduction does not see the scopes yet.
+
 def _ln(x, g, b, eps=1e-5):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.var(x, -1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * g + b
+    with jax.named_scope("norm"):
+        mu = jnp.mean(x, -1, keepdims=True)
+        var = jnp.var(x, -1, keepdims=True)
+        return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
 def _split_heads(x, n_heads):
@@ -194,15 +202,18 @@ def _layer(lp, x, cfg, attn_fn):
     q = _split_heads(h @ lp["wq"], cfg.n_heads)
     k = _split_heads(h @ lp["wk"], cfg.n_heads)
     v = _split_heads(h @ lp["wv"], cfg.n_heads)
-    a = attn_fn(q, k, v)
+    with jax.named_scope("attention"):
+        a = attn_fn(q, k, v)
     B, T, _ = x.shape
     x = x + a.reshape(B, T, cfg.d_model) @ lp["wo"]
     h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-    if cfg.n_experts:
-        flat = h.reshape(B * T, cfg.d_model)
-        out, aux = moe_ffn(flat, lp["router"], lp["w1"], lp["w2"])
-        return x + out.reshape(B, T, cfg.d_model), aux
-    return x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"], jnp.zeros((), x.dtype)
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            flat = h.reshape(B * T, cfg.d_model)
+            out, aux = moe_ffn(flat, lp["router"], lp["w1"], lp["w2"])
+            return x + out.reshape(B, T, cfg.d_model), aux
+        return (x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"],
+                jnp.zeros((), x.dtype))
 
 
 def apply(params, tokens, cfg: TransformerConfig, attn_fn=None):
@@ -431,14 +442,17 @@ def decode_step_paged(params, paged, tokens, positions, page_table,
         v_pool = _pool_write(v_pool, write_idx, v)
         from ..ops.pallas_kernels import paged_decode_attention
 
-        a = paged_decode_attention(q, k_pool, v_pool, page_table, n_valid)
+        with jax.named_scope("attention"):
+            a = paged_decode_attention(q, k_pool, v_pool, page_table,
+                                       n_valid)
         x = x + a.reshape(S, cfg.d_model) @ lp["wo"]
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts:
-            out, _ = moe_ffn(h, lp["router"], lp["w1"], lp["w2"])
-            x = x + out
-        else:
-            x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+        with jax.named_scope("ffn"):
+            if cfg.n_experts:
+                out, _ = moe_ffn(h, lp["router"], lp["w1"], lp["w2"])
+                x = x + out
+            else:
+                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
         return x, (k_pool, v_pool)
 
     x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))
@@ -481,15 +495,17 @@ def prefill_paged(params, paged, prompts, true_lens, page_table,
                              k.reshape((S * T_b,) + k.shape[2:]))
         v_pool = _pool_write(v_pool, write_idx,
                              v.reshape((S * T_b,) + v.shape[2:]))
-        a = _dense_attention(q, k, v, causal=True)
+        with jax.named_scope("attention"):
+            a = _dense_attention(q, k, v, causal=True)
         x = x + a.reshape(S, T_b, cfg.d_model) @ lp["wo"]
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts:
-            flat_h = h.reshape(S * T_b, cfg.d_model)
-            out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
-            x = x + out.reshape(S, T_b, cfg.d_model)
-        else:
-            x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+        with jax.named_scope("ffn"):
+            if cfg.n_experts:
+                flat_h = h.reshape(S * T_b, cfg.d_model)
+                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
+                x = x + out.reshape(S, T_b, cfg.d_model)
+            else:
+                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
         return x, (k_pool, v_pool)
 
     x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))
@@ -550,16 +566,18 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
                              v.reshape((S * Q,) + v.shape[2:]))
         from ..ops.pallas_kernels import paged_decode_attention_wide
 
-        a = paged_decode_attention_wide(q, k_pool, v_pool, page_table,
-                                        start)
+        with jax.named_scope("attention"):
+            a = paged_decode_attention_wide(q, k_pool, v_pool, page_table,
+                                            start)
         x = x + a.reshape(S, Q, cfg.d_model) @ lp["wo"]
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts:
-            flat_h = h.reshape(S * Q, cfg.d_model)
-            out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
-            x = x + out.reshape(S, Q, cfg.d_model)
-        else:
-            x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+        with jax.named_scope("ffn"):
+            if cfg.n_experts:
+                flat_h = h.reshape(S * Q, cfg.d_model)
+                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
+                x = x + out.reshape(S, Q, cfg.d_model)
+            else:
+                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
         return x, (k_pool, v_pool)
 
     x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))

@@ -159,6 +159,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
         _fwd_kernel, block_k=block_k, seq_len=T, causal=causal, scale=scale)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(B * H, T // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -197,6 +198,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, seq_len=T,
                           causal=causal, scale=scale),
+        name="flash_attention_bwd_dq",
         grid=(B * H, T // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -216,6 +218,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, seq_len=T,
                           causal=causal, scale=scale),
+        name="flash_attention_bwd_dkv",
         grid=(B * H, T // block_k),
         in_specs=[
             pl.BlockSpec((None, T, D), lambda b, j: (b, 0, 0)),
@@ -329,6 +332,7 @@ def _xent_fwd(logits, labels, block_b, interpret, vma):
     grid = (pl.cdiv(b, block_b),)
     loss, lse = pl.pallas_call(
         _xent_fwd_kernel,
+        name="softmax_xent_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, v), lambda i: (i, 0)),
@@ -352,6 +356,7 @@ def _xent_bwd_call(logits, labels, lse, dloss, block_b, interpret, vma):
     grid = (pl.cdiv(b, block_b),)
     return pl.pallas_call(
         _xent_bwd_kernel,
+        name="softmax_xent_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, v), lambda i: (i, 0)),
@@ -439,12 +444,12 @@ _FALLBACKS_HELP = ("flash_decode calls that fell back to the dense "
 
 def _count_dense_fallback(reason):
     # trace-time event (shapes are static), so the counter costs nothing
-    # on the per-step hot path; lazy import keeps this module jax-only
-    # when telemetry is off
-    from .. import telemetry
+    # on the per-step hot path, and it counts whether or not telemetry is
+    # on: a program is traced once, often before anyone enables it
+    from ..telemetry import REGISTRY
 
-    telemetry.inc(DENSE_FALLBACKS_TOTAL, help=_FALLBACKS_HELP,
-                  reason=reason)
+    REGISTRY.counter(DENSE_FALLBACKS_TOTAL, _FALLBACKS_HELP).inc(
+        1, reason=reason)
 
 
 def _per_seq_n_valid(n_valid, batch):
@@ -569,6 +574,7 @@ def _epilogue_fwd_call(x, scale, shift, residual, block_r, interpret):
     if residual is None:
         return pl.pallas_call(
             _epilogue_fwd_kernel,
+            name="bn_act_epilogue_fwd",
             grid=grid,
             in_specs=[row_spec, chan_spec, chan_spec],
             out_specs=row_spec,
@@ -577,6 +583,7 @@ def _epilogue_fwd_call(x, scale, shift, residual, block_r, interpret):
         )(x, scale, shift)
     return pl.pallas_call(
         _epilogue_res_fwd_kernel,
+        name="bn_act_epilogue_res_fwd",
         grid=grid,
         in_specs=[row_spec, chan_spec, chan_spec, row_spec],
         out_specs=row_spec,
@@ -610,6 +617,7 @@ def _epilogue_bwd_call(x, scale, y, dy, with_res, block_r, interpret):
         out_shape.append(jax.ShapeDtypeStruct((r, c), dy.dtype))
     return pl.pallas_call(
         kernel,
+        name="bn_act_epilogue_bwd",
         grid=grid,
         in_specs=[row_spec, chan_spec, row_spec, row_spec],
         out_specs=out_specs,
@@ -724,6 +732,7 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
     )
     o = pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         interpret=interpret,
@@ -859,6 +868,10 @@ def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
                                scale=1.0 / np.sqrt(D))
     o = pl.pallas_call(
         kernel,
+        # the name a trace reduction finds the kernel by: the engine's
+        # decode step is the Q = 1 case
+        name=("paged_decode_attention" if Q == 1
+              else "paged_decode_attention_wide"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Q, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

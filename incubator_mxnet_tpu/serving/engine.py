@@ -108,6 +108,18 @@ REQ_PREFILL_SPAN = "serving.request.prefill"
 REQ_DECODE_SPAN = "serving.request.decode"
 REQ_STEP_KIND = "req_step"  # batched decode-progress record, one per STEP
 
+# the engine's phase spans (children of serving.prefill and
+# serving.decode) and the short names they go by in a step's phase tally
+PHASES = {"serving.h2d": "h2d", "serving.dispatch": "dispatch",
+          "serving.fetch": "fetch", "serving.bookkeep": "bookkeep"}
+# a step is slow when it took more than this many rolling medians (the
+# rule of the benchmark's stall_share.sat), judged against the last
+# _STEP_WINDOW steps once _STEP_MIN of them are in; the median is worked
+# out again every _STEP_MIN steps
+SLOW_STEP_FACTOR = 3.0
+_STEP_WINDOW = 64
+_STEP_MIN = 8
+
 # sub-ms to minutes: decode steps are ms-scale, queued requests can wait
 _LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                     0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
@@ -124,7 +136,12 @@ class Request:
     eos_id: int | None = None
     submitted_at: float = 0.0
     admitted_at: float = 0.0
-    ttft_s: float = 0.0       # set at prefill; 0 until admitted
+    # when the first token reached the host (None until then), and that
+    # minus submit. A caller of step() sees the token first_token_held_s
+    # later, when the step that made it returns (None until it has)
+    first_token_at: float | None = None
+    ttft_s: float = 0.0
+    first_token_held_s: float | None = None
     trace: dict | None = None  # per-request trace context (tracing on)
 
 
@@ -151,6 +168,29 @@ def _default_buckets(max_len):
             b *= 2
         buckets.append(max_len)
     return [b for b in buckets if b <= max_len] or [max_len]
+
+
+class _Phase:
+    """One of the engine's PHASES as a context manager: a telemetry span
+    whose time on the engine's clock also adds to the running step's
+    phase tally, with or without a profiler session. One instance per
+    engine and phase, entered again each time (a phase never nests in
+    itself), so a phase allocates nothing beyond its span."""
+
+    __slots__ = ("_name", "_key", "_tally", "_clock", "_span", "_t0")
+
+    def __init__(self, name, tally, clock):
+        self._name, self._key = name, PHASES[name]
+        self._tally, self._clock = tally, clock
+
+    def __enter__(self):
+        self._span = telemetry.span(self._name)
+        self._span.__enter__()
+        self._t0 = self._clock()
+
+    def __exit__(self, *exc):
+        self._tally[self._key] += self._clock() - self._t0
+        return self._span.__exit__(*exc)
 
 
 class ServingEngine:
@@ -236,6 +276,18 @@ class ServingEngine:
         self._results: dict[int, RequestResult] = {}
         self._ids = itertools.count()
         self.steps = 0
+        # the running step's seconds by phase, and one reusable context
+        # manager per phase that adds to it
+        self._tally = dict.fromkeys(PHASES.values(), 0.0)
+        self._h2d, self._dispatch, self._fetch, self._bookkeep = (
+            _Phase(name, self._tally, clock) for name in PHASES)
+        # durations of the last steps that ran a program (the slow-step
+        # rule's rolling median), and the requests whose first token the
+        # running step made, with the finish records that wait for it
+        self._step_s: deque = deque(maxlen=_STEP_WINDOW)
+        self._median_s = None
+        self._first_tokens: list = []
+        self._held_finishes: list = []
 
         # host-side goodput accounting (source of truth independent of
         # whether the metrics registry is enabled): device token-position
@@ -352,10 +404,16 @@ class ServingEngine:
             raise ValueError(
                 f"request needs {need} pages but the pool only has "
                 f"{self.allocator.capacity}")
-        with self._lock:
+        # step() holds the lock throughout, so a gateway thread can wait
+        # here for a whole step: the span starts before the lock
+        t_call = self._clock()
+        with telemetry.span("serving.submit") as sp, self._lock:
             rid = next(self._ids)
             req = Request(rid, prompt, int(max_new_tokens), eos_id,
                           submitted_at=self._clock())
+            sp.set_metadata(
+                request=rid,
+                lock_wait_us=int(1e6 * (req.submitted_at - t_call)))
             if _dtrace.trace_active():
                 # trace context is born HERE (or adopted from trace_ctx):
                 # tid groups the whole lifecycle, sid is the root
@@ -369,10 +427,11 @@ class ServingEngine:
                 if psid is not None:
                     req.trace["pid"] = psid
             self._queue.append(req)
-            telemetry.set_gauge(QUEUE_DEPTH, len(self._queue))
-            telemetry.set_gauge(
-                OLDEST_QUEUED,
-                self._clock() - self._queue[0].submitted_at)
+            if telemetry.enabled():
+                telemetry.set_gauge(QUEUE_DEPTH, len(self._queue))
+                telemetry.set_gauge(
+                    OLDEST_QUEUED,
+                    self._clock() - self._queue[0].submitted_at)
             return rid
 
     def step(self):
@@ -381,8 +440,13 @@ class ServingEngine:
         every live slot one token in a single decode program. Returns
         the number of live slots after the iteration."""
         with self._lock:
-            with telemetry.span("serving.step", step=self.steps):
-                self._admit()
+            t_step = self._clock()
+            with telemetry.span("serving.step", step=self.steps,
+                                live=self.slots_in_use,
+                                queued=len(self._queue)):
+                with telemetry.span("serving.admit") as sp:
+                    admitted, blocked = self._admit()
+                    sp.set_metadata(admitted=admitted, blocked=blocked)
                 if self.prefill_chunk:
                     self._prefill_chunks_once()
                 if self.spec_ngram:
@@ -391,7 +455,41 @@ class ServingEngine:
                     live = self._decode_once()
             self.steps += 1
             self._export_gauges()
+            self._close_step(t_step)
             return live
+
+    def _close_step(self, t_step):
+        """The end of step(), as late as the lock allows: the moment a
+        caller can first read what the step made. Stamps how long each
+        first token of this step was held since it reached the host, logs
+        the finish records that waited for that stamp, and judges the
+        step's duration against the rolling median."""
+        now = self._clock()
+        for req in self._first_tokens:
+            req.first_token_held_s = now - req.first_token_at
+        self._first_tokens.clear()
+        for args in self._held_finishes:
+            self._log_finish(*args)
+        self._held_finishes.clear()
+        tally = self._tally
+        if not any(tally.values()):
+            return  # nothing ran: an idle poll is no sample of a step
+        dur = now - t_step
+        recent = self._step_s
+        if len(recent) >= _STEP_MIN:
+            if self._median_s is None or self.steps % _STEP_MIN == 0:
+                # a sort of 64 floats is 3 us: not on every step
+                self._median_s = sorted(recent)[len(recent) // 2]
+            median = self._median_s
+            if dur > SLOW_STEP_FACTOR * median > 0:
+                _recorder.log_event(
+                    "serving_step_slow", step=self.steps - 1,
+                    step_s=round(dur, 6), median_s=round(median, 6),
+                    phases={k: round(v, 6) for k, v in tally.items()},
+                    other_s=round(dur - sum(tally.values()), 6))
+        recent.append(dur)
+        for k in tally:
+            tally[k] = 0.0
 
     def run(self, max_steps=100_000):
         """Drive step() until the queue and every slot drain; returns
@@ -503,24 +601,29 @@ class ServingEngine:
     def _admit(self):
         """FIFO admission: stop at the first request that can't get a
         slot or its pages (head-of-line order keeps scheduling
-        deterministic — no small request overtakes a starved big one)."""
+        deterministic — no small request overtakes a starved big one).
+        Returns how many it admitted and what stopped it ("slots",
+        "pages", or "none" when the queue emptied)."""
         levered = self.prefix_cache is not None or self.prefill_chunk
+        admitted = 0
         while self._queue:
             slot = self._free_slot()
             if slot is None:
                 telemetry.inc(ADMISSION_BLOCKED, reason="slots")
-                return
+                return admitted, "slots"
             req = self._queue[0]
             if levered:
                 if not self._admit_levered(slot, req):
-                    return  # backpressure: wait for an eviction
+                    return admitted, "pages"  # wait for an eviction
+                admitted += 1
                 continue
             total = req.prompt.size + req.max_new_tokens
             pages = self.allocator.alloc(self.allocator.pages_needed(total),
                                          owner=req.request_id)
             if pages is None:
                 telemetry.inc(ADMISSION_BLOCKED, reason="pages")
-                return  # backpressure: wait for an eviction
+                return admitted, "pages"  # wait for an eviction
+            admitted += 1
             self._queue.popleft()
             req.admitted_at = self._clock()
             telemetry.observe(QUEUE_WAIT_SECONDS,
@@ -534,6 +637,7 @@ class ServingEngine:
                     pid=req.trace["sid"],
                     extra={"request": req.request_id})
             self._prefill_into(slot, req, pages)
+        return admitted, "none"
 
     def _prefill_into(self, slot, req, pages):
         T_p = req.prompt.size
@@ -543,12 +647,22 @@ class ServingEngine:
         prompt = np.zeros((1, T_b), np.int32)
         prompt[0, :T_p] = req.prompt
         clk_prefill = self._clock()
-        with telemetry.span("serving.prefill", request=req.request_id,
-                            bucket=T_b):
-            tok, self.paged = self._prefills[T_b](
-                self.params, self.paged, jnp.asarray(prompt),
-                jnp.asarray([T_p], np.int32), jnp.asarray(row[None]))
-        first = int(np.asarray(tok)[0])
+        # to the first token ON THE HOST: the dispatch returns at once,
+        # the fetch is where the prefill's device time is waited for
+        with telemetry.span(
+                "serving.prefill", request=req.request_id, bucket=T_b,
+                prompt_len=T_p,
+                queue_wait_us=int(1e6 * (req.admitted_at
+                                         - req.submitted_at))):
+            with self._h2d:
+                prompt, true_len, table = (
+                    jnp.asarray(prompt), jnp.asarray([T_p], np.int32),
+                    jnp.asarray(row[None]))
+            with self._dispatch:
+                tok, self.paged = self._prefills[T_b](
+                    self.params, self.paged, prompt, true_len, table)
+            with self._fetch:
+                first = int(np.asarray(tok)[0])
         clk_first = self._clock()
         pad = T_b - T_p
         self._tokens["prefill"] += T_p
@@ -560,7 +674,9 @@ class ServingEngine:
             telemetry.inc(TOKENS_TOTAL, amount=float(pad), kind="pad")
             telemetry.inc(WASTED_TOKENS, amount=float(pad),
                           reason="prefill_pad")
+        req.first_token_at = clk_first
         req.ttft_s = clk_first - req.submitted_at
+        self._first_tokens.append(req)
         telemetry.observe(TTFT_SECONDS, req.ttft_s,
                           buckets=_LATENCY_BUCKETS)
         if req.trace is not None:
@@ -672,8 +788,13 @@ class ServingEngine:
             # synchronous tail prefill: run every chunk before the next
             # admission (chunked mode instead leaves the descriptor for
             # step() to advance one chunk per iteration)
-            while self._slot_prefill[slot] is not None:
-                self._prefill_chunks_once(only_slot=slot)
+            with telemetry.span(
+                    "serving.prefill", request=req.request_id,
+                    prompt_len=T_p, cached=n_cached,
+                    queue_wait_us=int(1e6 * (req.admitted_at
+                                             - req.submitted_at))):
+                while self._slot_prefill[slot] is not None:
+                    self._prefill_chunks_once(only_slot=slot)
         return True
 
     def _prefill_chunks_once(self, only_slot=None):
@@ -708,29 +829,34 @@ class ServingEngine:
                     self._slot_req[s].request_id,
                     self._slot_pages[s][lo:hi + 1])
         with telemetry.span("serving.prefill_chunk", slots=len(pend)):
-            out, self.paged = self._wide(C)(
-                self.params, self.paged, jnp.asarray(toks),
-                jnp.asarray(start), jnp.asarray(n_real),
-                jnp.asarray(tables))
-        out = np.asarray(out)
-        for s in pend:
-            st = self._slot_prefill[s]
-            n = int(n_real[s])
-            st["pos"] += n
-            st["chunks"] += 1
-            self._prefill_chunks += 1
-            self._tokens["prefill"] += n
-            telemetry.inc(TOKENS_TOTAL, amount=float(n), kind="prefill")
-            telemetry.inc(PREFILL_CHUNKS)
-            pad = C - n
-            if pad:
-                self._tokens["pad"] += pad
-                telemetry.inc(TOKENS_TOTAL, amount=float(pad),
-                              kind="pad")
-                telemetry.inc(WASTED_TOKENS, amount=float(pad),
-                              reason="prefill_pad")
-            if st["pos"] >= st["prompt"].size:
-                self._finish_prefill(s, int(out[s, n - 1]))
+            with self._h2d:
+                args = (jnp.asarray(toks), jnp.asarray(start),
+                        jnp.asarray(n_real), jnp.asarray(tables))
+            with self._dispatch:
+                out, self.paged = self._wide(C)(
+                    self.params, self.paged, *args)
+            with self._fetch:
+                out = np.asarray(out)
+            with self._bookkeep:
+                for s in pend:
+                    self._note_chunk(s, int(n_real[s]), C, out)
+
+    def _note_chunk(self, s, n, C, out):
+        st = self._slot_prefill[s]
+        st["pos"] += n
+        st["chunks"] += 1
+        self._prefill_chunks += 1
+        self._tokens["prefill"] += n
+        telemetry.inc(TOKENS_TOTAL, amount=float(n), kind="prefill")
+        telemetry.inc(PREFILL_CHUNKS)
+        pad = C - n
+        if pad:
+            self._tokens["pad"] += pad
+            telemetry.inc(TOKENS_TOTAL, amount=float(pad), kind="pad")
+            telemetry.inc(WASTED_TOKENS, amount=float(pad),
+                          reason="prefill_pad")
+        if st["pos"] >= st["prompt"].size:
+            self._finish_prefill(s, int(out[s, n - 1]))
 
     def _finish_prefill(self, slot, first):
         """Last tail chunk done: record TTFT, install the slot's decode
@@ -743,7 +869,9 @@ class ServingEngine:
         prompt = st["prompt"]
         T_p = prompt.size
         clk_first = self._clock()
+        req.first_token_at = clk_first
         req.ttft_s = clk_first - req.submitted_at
+        self._first_tokens.append(req)
         telemetry.observe(TTFT_SECONDS, req.ttft_s,
                           buckets=_LATENCY_BUCKETS)
         if req.trace is not None:
@@ -833,10 +961,17 @@ class ServingEngine:
         rejected rows need no rollback (their K/V sits beyond the
         slot's advanced position — dead data the next step
         overwrites)."""
-        live_slots = [s for s, r in enumerate(self._slot_req)
-                      if r is not None and self._slot_prefill[s] is None]
-        if not live_slots:
-            return self.slots_in_use
+        live_slots = self._decoding_slots()
+        if live_slots:
+            with telemetry.span("serving.decode", live=len(live_slots)):
+                self._decode_spec_live(live_slots)
+        return self.slots_in_use
+
+    def _decoding_slots(self):
+        return [s for s, r in enumerate(self._slot_req)
+                if r is not None and self._slot_prefill[s] is None]
+
+    def _decode_spec_live(self, live_slots):
         if self.prefix_cache is not None:
             for s in live_slots:
                 if self._slot_cow_idx[s] >= 0:
@@ -867,11 +1002,17 @@ class ServingEngine:
                 self._page_san.note_write(
                     self._slot_req[s].request_id,
                     self._slot_pages[s][lo:hi + 1])
-        tok, self.paged = self._wide(Q)(
-            self.params, self.paged, jnp.asarray(toks),
-            jnp.asarray(start), jnp.asarray(n_real),
-            jnp.asarray(self._tables))
-        tok = np.asarray(tok)
+        with self._h2d:
+            args = (jnp.asarray(toks), jnp.asarray(start),
+                    jnp.asarray(n_real), jnp.asarray(self._tables))
+        with self._dispatch:
+            tok, self.paged = self._wide(Q)(self.params, self.paged, *args)
+        with self._fetch:
+            tok = np.asarray(tok)
+        with self._bookkeep:
+            self._note_spec_tokens(live_slots, props, tok, Q)
+
+    def _note_spec_tokens(self, live_slots, props, tok, Q):
         for s in live_slots:
             req = self._slot_req[s]
             prop = props[s]
@@ -928,13 +1069,15 @@ class ServingEngine:
             if self.trace_lane is not None:
                 rec["lane"] = self.trace_lane
             _dtrace.record_span(rec)
-        return self.slots_in_use
 
     def _decode_once(self):
-        live_slots = [s for s, r in enumerate(self._slot_req)
-                      if r is not None and self._slot_prefill[s] is None]
-        if not live_slots:
-            return self.slots_in_use
+        live_slots = self._decoding_slots()
+        if live_slots:
+            with telemetry.span("serving.decode", live=len(live_slots)):
+                self._decode_live(live_slots)
+        return self.slots_in_use
+
+    def _decode_live(self, live_slots):
         if self.prefix_cache is not None:
             for s in live_slots:
                 if self._slot_cow_idx[s] >= 0:
@@ -946,10 +1089,17 @@ class ServingEngine:
                     self._slot_req[s].request_id,
                     [self._slot_pages[s][int(self._positions[s])
                                          // self.page_size]])
-        tok, self.paged = self._decode(
-            self.params, self.paged, jnp.asarray(self._next_tok),
-            jnp.asarray(self._positions), jnp.asarray(self._tables))
-        tok = np.asarray(tok)
+        with self._h2d:
+            args = (jnp.asarray(self._next_tok),
+                    jnp.asarray(self._positions), jnp.asarray(self._tables))
+        with self._dispatch:
+            tok, self.paged = self._decode(self.params, self.paged, *args)
+        with self._fetch:
+            tok = np.asarray(tok)
+        with self._bookkeep:
+            self._note_tokens(live_slots, tok)
+
+    def _note_tokens(self, live_slots, tok):
         n_live = len(live_slots)
         self._tokens["decode"] += n_live
         telemetry.inc(TOKENS_TOTAL, amount=float(n_live), kind="decode")
@@ -974,7 +1124,6 @@ class ServingEngine:
             self._next_tok[s] = tok[s]
             if self._is_done(req, self._slot_out[s]):
                 self._finish(s)
-        return self.slots_in_use
 
     def _is_done(self, req, out):
         if req.eos_id is not None and out and out[-1] == req.eos_id:
@@ -1016,10 +1165,13 @@ class ServingEngine:
             self._wasted_evicted += wasted
             telemetry.inc(WASTED_TOKENS, amount=float(wasted),
                           reason="evicted")
-        self._record_timeline(req, len(out), reason, queue_wait, latency)
-        _recorder.log_event("serving_request_finish",
-                            request=req.request_id, outcome=reason,
-                            tokens=len(out))
+        if req.first_token_at is not None and req.first_token_held_s is None:
+            # finished in the step that made its first token: how long
+            # that token was held is known when the step returns
+            self._held_finishes.append(
+                (req, len(out), reason, queue_wait, latency))
+        else:
+            self._log_finish(req, len(out), reason, queue_wait, latency)
         if self.slo is not None:
             self.slo.observe_request(
                 ttft=req.ttft_s, queue_wait=queue_wait,
@@ -1076,16 +1228,32 @@ class ServingEngine:
             record["lane"] = self.trace_lane
         _dtrace.record_span(record)
 
-    def _record_timeline(self, req, n_tokens, reason, queue_wait, latency):
+    def _log_finish(self, req, n_tokens, reason, queue_wait, latency):
+        """The always-on record of a finished request, once in the
+        timelines (SLO dumps, /debug/engine) and once in the flight
+        recorder's ring. `submitted` is on the engine's clock
+        (`time.monotonic` unless one was injected); the three parts
+        queue_wait_s (submit -> admit) + prefill_s (admit -> first token
+        on the host) + first_token_held_s (-> return of the step() that
+        made it) add up to the time to first token as a caller of step()
+        sees it, and ttft_s to the first two. A request that never made a
+        token has only its wait."""
+        first = req.first_token_at
+        stamps = {
+            "submitted": req.submitted_at,
+            "queue_wait_s": queue_wait,
+            "prefill_s": None if first is None else first - req.admitted_at,
+            "first_token_held_s": req.first_token_held_s,
+            "ttft_s": None if first is None else req.ttft_s,
+            "latency_s": latency,
+        }
         self._timelines.append({
             "request_id": req.request_id,
             "prompt_len": int(req.prompt.size),
-            "tokens": n_tokens,
-            "finish": reason,
-            "queue_wait_s": queue_wait,
-            "ttft_s": req.ttft_s if req.admitted_at else None,
-            "latency_s": latency,
-        })
+            "tokens": n_tokens, "finish": reason, **stamps})
+        _recorder.log_event("serving_request_finish",
+                            request=req.request_id, outcome=reason,
+                            tokens=n_tokens, **stamps)
 
     # -- introspection ------------------------------------------------------
 
@@ -1259,10 +1427,7 @@ class ServingEngine:
                     queue_wait_s=waited, latency_s=waited)
                 telemetry.inc(REQUESTS_TOTAL, outcome="cancelled")
                 telemetry.set_gauge(QUEUE_DEPTH, len(self._queue))
-                self._record_timeline(req, 0, "cancelled", waited, waited)
-                _recorder.log_event("serving_request_finish",
-                                    request=request_id,
-                                    outcome="cancelled", tokens=0)
+                self._log_finish(req, 0, "cancelled", waited, waited)
                 if req.trace is not None:
                     self._emit_request_record(
                         REQ_SPAN, req.trace, ts=req.trace["ns_submit"],
@@ -1281,6 +1446,8 @@ class ServingEngine:
         return False
 
     def _export_gauges(self):
+        if not telemetry.enabled():
+            return
         telemetry.set_gauge(QUEUE_DEPTH, len(self._queue))
         telemetry.set_gauge(SLOTS_IN_USE, self.slots_in_use)
         telemetry.set_gauge(PAGES_IN_USE, self.allocator.num_in_use)

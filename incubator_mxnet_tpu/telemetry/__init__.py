@@ -8,10 +8,11 @@ memory watermarks, all feeding one thread-safe registry with Prometheus
 and JSON exporters.
 
 Off by default. `MXNET_TELEMETRY=1` (or `telemetry.enable()`) turns it on;
-while off every instrumented site short-circuits through no-op stubs —
-`span()` hands back a shared do-nothing context manager and the module
-helpers return before touching the registry, so the cost is one cached
-boolean check per site.
+while off the module helpers return before touching the registry (one
+cached boolean check per site) and `span()` hands back a bare
+`jax.profiler.TraceAnnotation`: under a microsecond with no profiler
+session, and inside one — whoever started it — the program's spans sit in
+the `.xplane.pb` on the device trace's clock.
 
     import incubator_mxnet_tpu as mx
     mx.telemetry.enable()
@@ -30,12 +31,15 @@ from .metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, REGISTRY, DEFAULT_BUCKETS,
     BYTES_BUCKETS,
 )
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .names import (  # noqa: F401
-    METRIC_NAMES, SPAN_NAMES, is_registered_metric, is_registered_span,
+    METRIC_NAMES, SPAN_NAMES, SPAN_LABEL_KEYS, is_registered_metric,
+    is_registered_span,
 )
 from . import distributed  # noqa: F401
 from . import recorder  # noqa: F401
-from .spans import Span, NoopSpan, NOOP_SPAN, current_span, SPAN_HISTOGRAM  # noqa: F401
+from .spans import Span, current_span, SPAN_HISTOGRAM  # noqa: F401
 from .recorder import log_event  # noqa: F401
 from .exporters import (  # noqa: F401
     dump_json, prometheus_text, start_http_server, to_dict,
@@ -51,7 +55,7 @@ from .tb import LogTelemetryCallback  # noqa: F401
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "DEFAULT_BUCKETS", "BYTES_BUCKETS",
-    "Span", "NoopSpan", "current_span", "span",
+    "Span", "current_span", "span",
     "distributed", "recorder", "log_event",
     "dump_json", "prometheus_text", "start_http_server", "to_dict",
     "register_debug_handler", "unregister_debug_handler",
@@ -59,7 +63,7 @@ __all__ = [
     "stepstats", "ledger", "compilereg", "slo",
     "enabled", "enable", "disable", "refresh_from_env",
     "counter", "gauge", "histogram", "inc", "observe", "set_gauge",
-    "METRIC_NAMES", "SPAN_NAMES", "is_registered_metric",
+    "METRIC_NAMES", "SPAN_NAMES", "SPAN_LABEL_KEYS", "is_registered_metric",
     "is_registered_span",
 ]
 
@@ -124,16 +128,18 @@ def refresh_from_env():
     return enabled()
 
 
-def span(name, **tags):
-    """Timed, nestable tracing region; see spans.Span. Returns the shared
-    no-op span while both telemetry and distributed tracing are off; a
-    trace-only span (no registry/profiler sinks) when only
-    MXTPU_TRACE_DIR is set."""
+def span(name, **attrs):
+    """Timed, nestable tracing region; see spans.Span. Keywords in
+    SPAN_LABEL_KEYS are tags (metric labels), the rest attributes of the
+    trace event. While both telemetry and distributed tracing are off the
+    span is profiler-only, a bare TraceAnnotation: no registry write, no
+    flight event. With only MXTPU_TRACE_DIR set it is trace-only (no
+    registry or aggregate-table sinks)."""
     if enabled():
-        return Span(name, tags)
+        return Span(name, attrs)
     if distributed.trace_active():
-        return Span(name, tags, metrics=False)
-    return NOOP_SPAN
+        return Span(name, attrs, metrics=False)
+    return _TraceAnnotation(name, **attrs)
 
 
 # -- registry conveniences (always live; instrument through the helpers

@@ -115,11 +115,7 @@ def _add_locked(role, nbytes):
     if nbytes > 0 and _total > _peak:
         _peak = _total
         sp = current_span()
-        if sp is not None and getattr(sp, "name", None):
-            tag = (sp.tags or {}).get("phase")
-            _peak_span = f"{sp.name}[{tag}]" if tag else sp.name
-        else:
-            _peak_span = None
+        _peak_span = sp.name if sp is not None else None
         _peak_breakdown = dict(_by_role)
         return True
     return False

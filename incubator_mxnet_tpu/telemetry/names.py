@@ -15,8 +15,8 @@ instrumentation, not application metrics.
 """
 from __future__ import annotations
 
-__all__ = ["METRIC_NAMES", "SPAN_NAMES", "is_registered_metric",
-           "is_registered_span"]
+__all__ = ["METRIC_NAMES", "SPAN_NAMES", "SPAN_LABEL_KEYS",
+           "is_registered_metric", "is_registered_span"]
 
 # name -> (kind, one-line description). Kind is documentation (the
 # registry in metrics.py enforces kind consistency at runtime).
@@ -336,16 +336,38 @@ SPAN_NAMES = frozenset({
     "executor.backward",
     "trainer.step",
     "trainer.allreduce_grads",
-    "trainer.phase",
+    # one span per stepstats phase, the phase in the NAME: a profiler
+    # trace keeps an event's name and nothing else to tell them apart
+    "trainer.phase.data_fetch",
+    "trainer.phase.h2d",
+    "trainer.phase.scalars",
+    "trainer.phase.sparse_pull",
+    "trainer.phase.dispatch",
+    "trainer.phase.device_sync",
+    "trainer.phase.allreduce",
+    "trainer.phase.pushpull",
+    "trainer.phase.optimizer_update",
+    "trainstep.call",
     "ps.client.rpc",
     "ps.server.handle",
     "ps.server.merge",
     "ps.server.barrier",
     "embedding.pull",
     "embedding.push",
+    # the serving engine, from the outside in: submit() on the caller's
+    # thread; step() and, nested by the thread, admission, each prefill
+    # (to the first token on the host), the decode, and under the last two
+    # the upload / dispatch / blocking fetch / per-slot bookkeeping
+    "serving.submit",
     "serving.step",
+    "serving.admit",
     "serving.prefill",
     "serving.prefill_chunk",
+    "serving.decode",
+    "serving.h2d",
+    "serving.dispatch",
+    "serving.fetch",
+    "serving.bookkeep",
     # per-request lifecycle records (trace-only; emitted straight
     # through distributed.record_span, one lane per request in the
     # trace_merge --requests view)
@@ -361,6 +383,13 @@ SPAN_NAMES = frozenset({
     "fleet.failover",
     "fleet.resubmit",
 })
+
+# span() keyword arguments that label mxtpu_span_seconds: each takes a few
+# values. Every other keyword (a step number, a request id, a bucket, a
+# count) is an attribute of the trace event and of the trace record, never
+# a label — a server must not grow a series per step or per request.
+# (`error`, which a span sets itself when its body raises, is the one other.)
+SPAN_LABEL_KEYS = frozenset({"train", "command", "sync"})
 
 
 def is_registered_metric(name):

@@ -1,11 +1,19 @@
 """Tracing spans: nested wall-time regions that feed several sinks at once.
 
 A span records its duration into the metrics registry
-(`mxtpu_span_seconds{span=...}`), forwards to
-`jax.profiler.TraceAnnotation` when a jax trace is running (so spans line
-up with the XLA device timeline in TensorBoard/Perfetto), and accumulates
-into the profiler's per-op aggregate table when `aggregate_stats` is on —
-unifying with `profiler.dumps()` instead of growing a second table.
+(`mxtpu_span_seconds{span=...}`), always opens a
+`jax.profiler.TraceAnnotation` of its name (next to free while no profiler
+session runs; inside one, whoever started it, the span lands in the
+`.xplane.pb` on the device trace's clock), and accumulates into the
+profiler's per-op aggregate table when `aggregate_stats` is on — unifying
+with `profiler.dumps()` instead of growing a second table.
+
+What a call site passes to `span(name, **attrs)` splits in two. Keys in
+`names.SPAN_LABEL_KEYS` (a few values each: `train`, `command`, ...) are
+TAGS and label the histogram. Everything else (a step number, a request
+id, a bucket, a count) is an ATTRIBUTE: it goes to the annotation, where a
+trace reader finds it as an event stat, and to the trace record's `extra`,
+never to a metric label, so a server's series stay bounded.
 
 When distributed tracing is active (`MXTPU_TRACE_DIR`), every span also
 carries Dapper-style identity — `trace_id`/`span_id`/`parent_id` — and is
@@ -28,16 +36,19 @@ from __future__ import annotations
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from .. import profiler as _profiler
 from . import distributed as _distributed
 from . import recorder as _recorder
 from .metrics import REGISTRY
+from .names import SPAN_LABEL_KEYS
 
 __all__ = ["Span", "current_span", "SPAN_HISTOGRAM", "SPAN_ERRORS"]
 
 SPAN_HISTOGRAM = "mxtpu_span_seconds"
 _SPAN_HELP = ("Wall time of named host-side spans (executor forward/backward,"
-              " trainer step, ...); tags become extra labels.")
+              " trainer step, ...), by span name and its bounded tags.")
 SPAN_ERRORS = "mxtpu_span_errors_total"
 _ERRORS_HELP = ("Spans whose body raised, by span name (the exception type "
                 "is tagged on the span itself).")
@@ -55,36 +66,49 @@ class Span:
     (create a fresh Span per region); re-use across threads is not either —
     both mirror TraceAnnotation's contract.
 
+    `attrs` is what the call site passed: keys in SPAN_LABEL_KEYS become
+    `tags` (metric labels), the rest `extra` (see the module docstring).
+
     `metrics=False` builds a trace-only span: it still gets identity and
     lands in the trace file / flight recorder, but skips the registry and
-    profiler sinks — the shape `span()` hands out when distributed tracing
-    is on while telemetry proper is off."""
+    aggregate-table sinks — the shape `span()` hands out when distributed
+    tracing is on while telemetry proper is off."""
 
     __slots__ = ("name", "tags", "parent", "trace_id", "span_id",
                  "parent_id", "extra", "_start_ns", "_t0", "_annot",
                  "_metrics")
 
-    def __init__(self, name, tags=None, metrics=True):
+    def __init__(self, name, attrs=None, metrics=True):
         self.name = name
-        self.tags = dict(tags or {})
+        attrs = attrs or {}
+        self.tags = {k: v for k, v in attrs.items() if k in SPAN_LABEL_KEYS}
+        self.extra = {k: v for k, v in attrs.items()
+                      if k not in SPAN_LABEL_KEYS} or None
         self.parent = None
         self.trace_id = None
         self.span_id = None
         self.parent_id = None
-        self.extra = None
         self._start_ns = None
         self._t0 = None
         self._annot = None
         self._metrics = metrics
 
     def annotate(self, **kv):
-        """Attach key/values to the span's trace record (not metric
-        labels — no cardinality cost). Used for e.g. the RPC send/recv
-        timestamps that drive clock-skew correction in trace_merge."""
+        """Attach key/values to the span's trace record and, while the
+        span is open, to its profiler event (not metric labels — no
+        cardinality cost). Used for e.g. the RPC send/recv timestamps
+        that drive clock-skew correction in trace_merge, and for what a
+        span learns only inside its body (a lock wait, a count)."""
         if self.extra is None:
             self.extra = {}
         self.extra.update(kv)
+        if self._annot is not None:
+            self._annot.set_metadata(**kv)
         return self
+
+    # TraceAnnotation's name for it: a call site holding whichever form
+    # `telemetry.span()` handed out says `sp.set_metadata(...)`
+    set_metadata = annotate
 
     def bump(self, key, amount=1):
         """Increment a numeric annotation (e.g. per-span retry count)."""
@@ -109,12 +133,12 @@ class Span:
                 else:
                     self.trace_id = _distributed.new_id()
             self._start_ns = time.time_ns()
-        if _profiler._STATE["running"]:
-            try:
-                self._annot = _profiler.scope(self.name)
-                self._annot.__enter__()
-            except Exception:
-                self._annot = None  # tracing must never break the workload
+        try:
+            self._annot = TraceAnnotation(self.name, **self.tags,
+                                          **(self.extra or {}))
+            self._annot.__enter__()
+        except Exception:
+            self._annot = None  # tracing must never break the workload
         self._t0 = time.perf_counter()
         return self
 
@@ -159,32 +183,3 @@ class Span:
             "span_end", name=self.name, dur_ns=int(dur * 1e9),
             **({"error": self.tags["error"]} if exc_type is not None else {}))
         return False
-
-
-class NoopSpan:
-    """Shared do-nothing span for the disabled path: one module-level
-    instance, safe to re-enter from any thread."""
-
-    __slots__ = ()
-    name = None
-    tags = {}
-    parent = None
-    trace_id = None
-    span_id = None
-    parent_id = None
-    extra = None
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def annotate(self, **kv):
-        return self
-
-    def bump(self, key, amount=1):
-        return self
-
-
-NOOP_SPAN = NoopSpan()

@@ -15,8 +15,8 @@ the measurement substrate for ROADMAP's HBM-bandwidth work.
 Phases are fed two ways:
 
 - ``phase(name)`` — context manager that times a region, opens a
-  ``trainer.phase`` span (so traces and flight events line up with the
-  breakdown), and accumulates into the current step. Sites that nest
+  ``trainer.phase.<name>`` span (so traces and flight events line up with
+  the breakdown), and accumulates into the current step. Sites that nest
   phases double-count; keep phases flat.
 - ``record(name, seconds)`` — for sites that already measured (the
   DataLoader fetch timer).
@@ -28,15 +28,17 @@ so the breakdown denominator is the full loop iteration — phase
 coverage (phase sum / total) then measures how much of the real step
 the instrumentation explains.
 
-Everything here is a no-op while telemetry is disabled: zero registry
-writes, zero recorder events (see tests/test_telemetry.py::
-test_disabled_paths_hit_noop_stubs).
+While telemetry is disabled nothing here writes to the registry or the
+recorder (tests/test_telemetry.py::test_disabled_paths_write_nothing); a
+phase is then a bare profiler annotation, like any span.
 """
 from __future__ import annotations
 
 import collections
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .. import config as _config
 from .metrics import REGISTRY
@@ -45,9 +47,11 @@ from . import distributed as _distributed
 from . import recorder as _recorder
 
 __all__ = ["phase", "record", "step_end", "snapshot", "reset",
-           "PHASE_SPAN", "PHASE_GAUGE", "ANOMALIES_TOTAL"]
+           "PHASE_SPAN_PREFIX", "PHASE_GAUGE", "ANOMALIES_TOTAL"]
 
-PHASE_SPAN = "trainer.phase"
+# a phase's span is named PHASE_SPAN_PREFIX + its phase (each registered in
+# names.SPAN_NAMES): the name is all a profiler trace keeps of an event
+PHASE_SPAN_PREFIX = "trainer.phase."
 PHASE_GAUGE = "mxtpu_step_phase_seconds"
 _PHASE_HELP = ("Rolling per-phase step-time quantiles from StepStats, by "
                "phase and quantile (q=0.5/0.99); phase=total is the whole "
@@ -62,8 +66,8 @@ _ANOM_HELP = ("Steps whose wall time exceeded MXNET_TELEMETRY_ANOMALY_FACTOR"
 # the PS fleet: with MXTPU_SPARSE_PREFETCH the background thread absorbs
 # the RPC wall time and this phase shrinks toward zero — the direct
 # observatory readout of the pull/forward overlap win.
-PHASES = ("data_fetch", "h2d", "sparse_pull", "dispatch", "device_sync",
-          "allreduce", "pushpull", "optimizer_update")
+PHASES = ("data_fetch", "h2d", "scalars", "sparse_pull", "dispatch",
+          "device_sync", "allreduce", "pushpull", "optimizer_update")
 
 _lock = threading.Lock()
 _acc = {}            # phase -> accumulated seconds, current step
@@ -85,22 +89,9 @@ def _on():
     return fn()
 
 
-class _NoopPhase:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_PHASE = _NoopPhase()
-
-
 class _Phase:
-    """Times a region, mirrors it as a trainer.phase span, and feeds the
-    current step's accumulator (unless trace-only)."""
+    """Times a region, mirrors it as a trainer.phase.<name> span, and
+    feeds the current step's accumulator (unless trace-only)."""
 
     __slots__ = ("name", "_span", "_feed", "_t0")
 
@@ -122,15 +113,15 @@ class _Phase:
 
 
 def phase(name):
-    """Context manager for one step phase. No-op while both telemetry and
-    distributed tracing are off; trace-only (span, no stats) when only
-    MXTPU_TRACE_DIR is set."""
+    """Context manager for one step phase. A bare profiler annotation
+    while both telemetry and distributed tracing are off; trace-only
+    (span, no stats) when only MXTPU_TRACE_DIR is set."""
+    span_name = PHASE_SPAN_PREFIX + name
     if _on():
-        return _Phase(name, Span(PHASE_SPAN, {"phase": name}), feed=True)
+        return _Phase(name, Span(span_name), feed=True)
     if _distributed.trace_active():
-        return _Phase(name, Span(PHASE_SPAN, {"phase": name}, metrics=False),
-                      feed=False)
-    return _NOOP_PHASE
+        return _Phase(name, Span(span_name, metrics=False), feed=False)
+    return TraceAnnotation(span_name)
 
 
 def record(name, seconds):

@@ -112,8 +112,8 @@ def test_ledger_peak_attribution_names_active_span_and_phase(telem):
             ledger.track(big, "optimizer_state")
     info = ledger.peak_info()
     assert info["peak_bytes"] == base._data.nbytes + big._data.nbytes
-    # the innermost span at the peak is the phase span, phase-tagged
-    assert info["span"] == "trainer.phase[optimizer_update]"
+    # the innermost span at the peak is the phase span, named by its phase
+    assert info["span"] == "trainer.phase.optimizer_update"
     assert info["breakdown"]["optimizer_state"] == big._data.nbytes
     peak_gauge = telemetry.REGISTRY.get("mxtpu_ledger_peak_bytes")
     assert peak_gauge.value() == info["peak_bytes"]

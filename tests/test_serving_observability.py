@@ -492,3 +492,287 @@ def test_allocator_occupancy_and_fragmentation():
     assert alloc.occupancy() == 0.0
     # everything free again: ids 1..8 are one contiguous run
     assert alloc.fragmentation() == 0.0
+
+
+# -- program spans on the profiler's clock -----------------------------------
+
+def _tree(events):
+    """[(depth, name)] by containment."""
+    out, ends = [], []
+    for name, start, end, _ in events:
+        while ends and ends[-1] <= start:
+            ends.pop()
+        out.append((len(ends), name))
+        ends.append(end)
+    return out
+
+
+@pytest.mark.parametrize("telemetry_on", [False, True])
+def test_engine_span_tree_in_a_directly_started_session(
+        profiled_spans, telemetry_on, monkeypatch):
+    """The xplane of a session nobody told the program about holds the
+    engine's span tree with its nesting and attributes, with telemetry
+    off (bare annotations) and on (Span opens one too)."""
+    if telemetry_on:
+        monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    telemetry.refresh_from_env()
+    telemetry.REGISTRY.reset()
+    eng = _tiny_engine()
+    eng.submit(_prompt(5), 3)
+    eng.run()  # compile outside the trace
+    rids = []
+
+    def body():
+        rids.append(eng.submit(_prompt(5), 3))
+        eng.step()   # admits and prefills, then decodes
+        eng.step()   # decodes; the request has its 3 tokens
+    try:
+        (events,) = profiled_spans(body)
+    finally:
+        monkeypatch.delenv("MXNET_TELEMETRY", raising=False)
+        telemetry.refresh_from_env()
+        telemetry.REGISTRY.reset()
+    phases = ["serving.h2d", "serving.dispatch", "serving.fetch"]
+    decode = ([(1, "serving.decode")] + [(2, p) for p in phases]
+              + [(2, "serving.bookkeep")])
+    assert _tree(events) == (
+        [(0, "serving.submit"), (0, "serving.step"), (1, "serving.admit"),
+         (2, "serving.prefill")] + [(3, p) for p in phases] + decode
+        + [(0, "serving.step"), (1, "serving.admit")] + decode)
+    stats = [(n, st) for n, _, _, st in events if st]
+    (rid,) = rids
+    assert stats[0][0] == "serving.submit"
+    assert stats[0][1]["request"] == rid and stats[0][1]["lock_wait_us"] >= 0
+    step0 = eng.steps - 2
+    assert stats[1] == ("serving.step",
+                        {"step": step0, "live": 0, "queued": 1})
+    assert stats[2] == ("serving.admit", {"admitted": 1, "blocked": "none"})
+    name, prefill = stats[3]
+    assert name == "serving.prefill" and prefill["request"] == rid
+    assert prefill["bucket"] == 16 and prefill["prompt_len"] == 5
+    assert prefill["queue_wait_us"] >= 0
+    assert stats[4] == ("serving.decode", {"live": 1})
+    assert stats[5] == ("serving.step",
+                        {"step": step0 + 1, "live": 1, "queued": 0})
+    assert stats[6] == ("serving.admit", {"admitted": 0, "blocked": "none"})
+
+
+class _SlowToHost:
+    """A device result whose copy to the host takes `seconds`: what a
+    real prefill is, where the dispatch returns at once."""
+
+    def __init__(self, value, seconds, clock=None):
+        self._value, self._seconds, self._clock = value, seconds, clock
+
+    def __array__(self, dtype=None, copy=None):
+        import time
+
+        if self._clock is None:
+            time.sleep(self._seconds)
+        else:
+            self._clock.t += self._seconds
+        return np.asarray(self._value, dtype)
+
+
+def test_prefill_span_ends_after_the_first_token_is_on_the_host(metrics_on):
+    eng = _tiny_engine()
+    eng.submit(_prompt(5), 2)
+    eng.run()
+    telemetry.REGISTRY.reset()
+    real = eng._prefills[16]
+
+    def lazy(*args):
+        tok, paged = real(*args)
+        return _SlowToHost(tok, 0.05), paged
+    eng._prefills[16] = lazy
+    eng.submit(_prompt(5), 2)
+    eng.run()
+    hist = telemetry.REGISTRY.get(telemetry.SPAN_HISTOGRAM)
+    by_span = {l["span"]: c for l, c in hist.series()}
+    assert by_span["serving.prefill"].count == 1
+    assert by_span["serving.prefill"].sum >= 0.05   # the fetch is inside
+    assert by_span["serving.fetch"].sum >= 0.05
+    assert by_span["serving.dispatch"].sum < 0.05   # and not the dispatch
+
+
+def test_span_series_stay_bounded_over_steps_and_requests(metrics_on):
+    """50 steps and 10 requests: one histogram child per span name, none
+    per step or per request."""
+    eng = _tiny_engine(slots=2)
+    for i in range(10):
+        eng.submit(_prompt(4 + i % 3, seed=i), 12)
+    while eng.steps < 50:
+        if not eng.queue_depth and not eng.slots_in_use:
+            eng.submit(_prompt(4), 12)
+        eng.step()
+    hist = telemetry.REGISTRY.get(telemetry.SPAN_HISTOGRAM)
+    series = [labels for labels, _ in hist.series()]
+    assert all(set(labels) == {"span"} for labels in series)
+    names = [labels["span"] for labels in series]
+    assert len(names) == len(set(names)) <= 10
+    assert set(names) == {
+        "serving.submit", "serving.step", "serving.admit", "serving.prefill",
+        "serving.decode", "serving.h2d", "serving.dispatch", "serving.fetch",
+        "serving.bookkeep"}
+    by_span = {l["span"]: c for l, c in hist.series()}
+    assert by_span["serving.step"].count == 50
+
+
+def test_everything_off_a_step_writes_nothing():
+    telemetry.disable()
+    telemetry.REGISTRY.reset()
+    eng = _tiny_engine()
+    eng.submit(_prompt(5), 20)
+    eng.step()
+
+    def kinds():
+        return [e["kind"] for e in _recorder.snapshot()]
+    before = kinds()
+    for _ in range(5):
+        eng.step()
+    assert eng.slots_in_use == 1
+    assert kinds() == before            # no span_end, nothing per step
+    assert telemetry.REGISTRY.collect() == []
+
+
+# -- always-on records: TTFT by part, slow steps ------------------------------
+
+class _Clock:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+# the ring is process-wide and request ids start at 0 in every engine:
+# these tests tell their events by a clock no other engine is on
+_EPOCH = 2.0e9
+
+
+def _finish_events(rid):
+    return [e for e in _recorder.snapshot()
+            if e["kind"] == "serving_request_finish"
+            and e["request"] == rid and e.get("submitted", 0) >= _EPOCH]
+
+
+def test_request_finish_carries_ttft_by_part_on_the_engine_clock():
+    """Two requests admitted in ONE step: the first one's token is held
+    through the second one's prefill and the decode. Device work is given
+    a duration on the injected clock: a prefill 0.25 s, a decode 0.5 s."""
+    clk = _Clock()
+    eng = _tiny_engine(clock=clk)
+    eng.submit(_prompt(5), 2)
+    eng.run()  # compile
+    real_prefill, real_decode = eng._prefills[16], eng._decode
+
+    def prefill(*args):
+        tok, paged = real_prefill(*args)
+        return _SlowToHost(tok, 0.25, clk), paged
+
+    def decode(*args):
+        tok, paged = real_decode(*args)
+        return _SlowToHost(tok, 0.5, clk), paged
+    eng._prefills[16], eng._decode = prefill, decode
+
+    clk.t = _EPOCH
+    r0 = eng.submit(_prompt(5), 2)
+    clk.t = _EPOCH + 0.125
+    r1 = eng.submit(_prompt(6, seed=1), 3)
+    clk.t = _EPOCH + 1.0                # the step starts
+    eng.step()
+    delivered = clk.t                   # the first moment a caller can read
+    assert delivered == _EPOCH + 1.0 + 0.25 + 0.25 + 0.5
+    assert set(eng.live_tokens()) == {r1}       # r0 had its 2 tokens
+    (e0,) = _finish_events(r0)
+    assert e0["submitted"] == _EPOCH and e0["outcome"] == "length"
+    assert e0["queue_wait_s"] == 1.0            # the step began 1 s later
+    assert e0["prefill_s"] == 0.25
+    assert e0["first_token_held_s"] == 0.75     # r1's prefill + the decode
+    assert e0["ttft_s"] == 1.25 == eng.results()[r0].ttft_s
+    assert (e0["queue_wait_s"] + e0["prefill_s"] + e0["first_token_held_s"]
+            == delivered - e0["submitted"])
+    assert e0["latency_s"] == 2.0 and e0["tokens"] == 2
+    assert not _finish_events(r1)
+    eng.step()
+    (e1,) = _finish_events(r1)
+    assert e1["submitted"] == _EPOCH + 0.125
+    assert e1["queue_wait_s"] == 1.25 - 0.125      # behind r0's prefill
+    assert e1["prefill_s"] == 0.25
+    assert e1["first_token_held_s"] == 0.5          # the decode alone
+    assert (e1["queue_wait_s"] + e1["prefill_s"] + e1["first_token_held_s"]
+            == delivered - e1["submitted"])
+    # the same fields in the timelines (/debug/engine, SLO dumps)
+    t0, t1 = eng.recent_timelines()[-2:]
+    assert (t0["request_id"], t1["request_id"]) == (r0, r1)
+    for timeline, event in ((t0, e0), (t1, e1)):
+        for key in ("submitted", "queue_wait_s", "prefill_s",
+                    "first_token_held_s", "ttft_s", "latency_s"):
+            assert timeline[key] == event[key]
+    # cancelled in the queue: its wait, and no part it never had
+    eng._prefills[16], eng._decode = real_prefill, real_decode
+    eng.submit(_prompt(4), 20)
+    eng.submit(_prompt(4), 20)
+    r4 = eng.submit(_prompt(4), 4)
+    eng.step()
+    clk.t += 1.5
+    assert eng.cancel(r4)
+    (e4,) = _finish_events(r4)
+    assert e4["outcome"] == "cancelled" and e4["queue_wait_s"] == 1.5
+    assert e4["prefill_s"] is e4["first_token_held_s"] is e4["ttft_s"] is None
+
+
+def test_slowed_step_logs_one_serving_step_slow():
+    clk = _Clock()
+    eng = _tiny_engine(clock=clk)
+    real_decode = eng._decode
+    cost = {"fetch": 0.25}
+
+    def decode(*args):
+        tok, paged = real_decode(*args)
+        return _SlowToHost(tok, cost["fetch"], clk), paged
+    eng._decode = decode
+
+    def slow():
+        return [e for e in _recorder.snapshot()
+                if e["kind"] == "serving_step_slow"]
+    already = len(slow())
+    for _ in range(3):
+        eng.step()                      # an idle poll is no sample
+    eng.submit(_prompt(5), 26)
+    for _ in range(10):
+        eng.step()
+    assert len(slow()) == already and len(eng._step_s) == 10
+    cost["fetch"] = 1.0                 # 4 x the median of 0.25
+    eng.step()
+    cost["fetch"] = 0.25
+    for _ in range(5):
+        eng.step()
+    events = slow()[already:]
+    assert len(events) == 1
+    (e,) = events
+    assert e["step"] == 13 and e["step_s"] == 1.0 and e["median_s"] == 0.25
+    # where the time went: the blocking fetch (device or runtime), not a
+    # host phase
+    assert e["phases"] == {"h2d": 0.0, "dispatch": 0.0, "fetch": 1.0,
+                           "bookkeep": 0.0}
+    assert e["other_s"] == 0.0
+
+
+def test_dense_fallback_counts_with_telemetry_off():
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.ops.pallas_kernels import (
+        DENSE_FALLBACKS_TOTAL, flash_decode)
+
+    telemetry.disable()
+    telemetry.REGISTRY.reset()
+    try:
+        q = jnp.ones((1, 2, 8), jnp.float32)
+        cache = jnp.ones((1, 200, 2, 8), jnp.float32)  # 200 % 128 != 0
+        flash_decode(q, cache, cache, jnp.asarray(5, jnp.int32))
+        fam = telemetry.REGISTRY.get(DENSE_FALLBACKS_TOTAL)
+        assert fam is not None
+        assert sum(child.value for _, child in fam.series()) == 1.0
+    finally:
+        telemetry.REGISTRY.reset()

@@ -11,6 +11,7 @@ import pytest
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import autograd, gluon, nd, telemetry
 from incubator_mxnet_tpu.gluon import nn
+from incubator_mxnet_tpu.telemetry import recorder, stepstats
 
 
 @pytest.fixture
@@ -69,7 +70,7 @@ def test_metric_type_conflict_and_counter_monotonicity(telem):
 
 def test_nested_spans_accumulate_into_registry(telem):
     assert telemetry.current_span() is None
-    with telem.span("outer", phase="train") as outer:
+    with telem.span("outer", train="1", step=7) as outer:
         assert telemetry.current_span() is outer
         with telem.span("inner") as inner:
             assert inner.parent is outer
@@ -80,8 +81,12 @@ def test_nested_spans_accumulate_into_registry(telem):
     assert telemetry.current_span() is None
     hist = telemetry.REGISTRY.get(telemetry.SPAN_HISTOGRAM)
     series = {tuple(sorted(l.items())): child for l, child in hist.series()}
-    outer_key = (("phase", "train"), ("span", "outer"))
+    # `train` is a registered label key; `step` identifies, so it is an
+    # attribute of the trace record and never a label
+    outer_key = (("span", "outer"), ("train", "1"))
     inner_key = (("span", "inner"),)
+    assert set(series) == {outer_key, inner_key}
+    assert outer.tags == {"train": "1"} and outer.extra == {"step": 7}
     assert series[outer_key].count == 1
     assert series[inner_key].count == 2
     # inner time is contained in outer wall time
@@ -285,16 +290,22 @@ def test_tensorboard_compatible_periodic_logger(telem):
 
 # -- disabled path ----------------------------------------------------------
 
-def test_disabled_paths_hit_noop_stubs():
+def test_disabled_paths_write_nothing():
+    from jax.profiler import TraceAnnotation
+
     telemetry.disable()
     telemetry.REGISTRY.reset()
     try:
-        s = telemetry.span("anything", tag="x")
-        assert s is telemetry.NOOP_SPAN
-        assert telemetry.span("other") is s  # shared singleton
-        with s:
-            with s:
+        # profiler-only: a bare annotation, whatever the attributes
+        s = telemetry.span("anything", command="x", step=3)
+        assert type(s) is TraceAnnotation
+        assert type(stepstats.phase("dispatch")) is TraceAnnotation
+        before = sum(e["kind"] == "span_end" for e in recorder.snapshot())
+        with s as sp:
+            sp.set_metadata(late=1)  # what Span.annotate is to a Span
+            with telemetry.span("anything"):
                 pass
+        assert telemetry.current_span() is None
         telemetry.inc("t_should_not_exist_total")
         telemetry.observe("t_should_not_exist_seconds", 1.0)
         telemetry.set_gauge("t_should_not_exist_depth", 1)
@@ -302,8 +313,42 @@ def test_disabled_paths_hit_noop_stubs():
         assert telemetry.REGISTRY.collect() == []
         assert telemetry.prometheus_text() == "\n"
         assert telemetry.dump_json()["metrics"] == {}
+        assert before == sum(e["kind"] == "span_end"
+                             for e in recorder.snapshot())
     finally:
         telemetry.REGISTRY.reset()
+
+
+@pytest.mark.parametrize("telemetry_on", [False, True])
+def test_train_step_spans_reach_a_session_nobody_told_them_of(
+        profiled_spans, telemetry_on):
+    """trainstep.call and the three phases under it, each phase under its
+    own NAME, in a trace that mx.profiler.set_state never started — with
+    telemetry off (bare annotations) and on (Span opens one too)."""
+    from incubator_mxnet_tpu.fused import GluonTrainStep
+
+    net = nn.Dense(2, in_units=4)
+    net.initialize(mx.init.Normal(0.1))
+    L = gluon.loss.L2Loss()
+    step = GluonTrainStep(net, lambda n, a, b: L(n(a), b),
+                          mx.optimizer.SGD(learning_rate=0.1))
+    x, y = nd.array(np.ones((3, 4), "float32")), nd.array(
+        np.zeros((3, 2), "float32"))
+    step(x, y)  # build and compile outside the trace
+    (telemetry.enable if telemetry_on else telemetry.disable)()
+    try:
+        (events,) = profiled_spans(lambda: (step(x, y), step(x, y)))
+    finally:
+        telemetry.disable()
+        telemetry.REGISTRY.reset()
+        stepstats.reset()
+    calls = [e for e in events if e[0] == "trainstep.call"]
+    assert [c[3] for c in calls] == [{"n": 2}, {"n": 3}]
+    for _, lo, hi, _ in calls:
+        inside = [e[0] for e in events if lo <= e[1] and e[2] <= hi
+                  and e[0] != "trainstep.call"]
+        assert inside == ["trainer.phase.h2d", "trainer.phase.scalars",
+                          "trainer.phase.dispatch"]
 
 
 def test_enable_from_env(monkeypatch):

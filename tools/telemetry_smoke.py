@@ -141,7 +141,7 @@ def main():
     from incubator_mxnet_tpu import config as _config
     from incubator_mxnet_tpu.telemetry import recorder as _recorder
 
-    # tracing off + telemetry off => span() is NOOP_SPAN: the training
+    # tracing off + telemetry off => span() is a bare annotation: the training
     # loop must not log a single span event into the ring
     before = sum(1 for e in _recorder.snapshot() if e["kind"] == "span_end")
     run_loop(*args)
