@@ -389,10 +389,14 @@ def phase_kernels(sz):
                   oracle(pk.dense_decode_attention, q1.astype(f32),
                          kc.astype(f32), vc.astype(f32), depth), dtype)
 
-        # paged kernels at the engine's default geometry
+        # paged kernels at the engine's default geometry: one pool for
+        # every layer, K|V fused per row, read and written by layer index
+        # (layer 1 of 2 here; layer 0 is noise nothing may touch)
         ps, W = sz.page_size, sz.table_width
         P = B * W + 1
         kp, vp = rand((H, P, ps, D), dtype), rand((H, P, ps, D), dtype)
+        pool = jnp.stack([rand((H, P, ps, 2 * D), dtype),
+                          jnp.concatenate([kp, vp], -1)])
         table = (1 + jax.random.permutation(next(key), B * W)
                  ).reshape(B, W).astype(jnp.int32)
         kd, vd = (_gather_pages(a.astype(f32), table) for a in (kp, vp))
@@ -400,7 +404,7 @@ def phase_kernels(sz):
         nv = nv.at[0].set(W * ps).at[1].set(1)
         orc.close(f"paged_decode_attention[{dn}]",
                   kernel(lambda *a: pk.paged_decode_attention(
-                      *a, interpret=ip), q1, kp, vp, table, nv),
+                      *a, layer=1, interpret=ip), q1, pool, table, nv),
                   oracle(pk.dense_decode_attention, q1.astype(f32), kd, vd,
                          nv), dtype)
         for Q in sz.wide_q:
@@ -411,8 +415,20 @@ def phase_kernels(sz):
                        vd, nb + i + 1) for i in range(Q)], axis=1)
             orc.close(f"paged_decode_attention_wide[{dn},Q={Q}]",
                       kernel(lambda *a: pk.paged_decode_attention_wide(
-                          *a, interpret=ip), qw, kp, vp, table, nb),
+                          *a, layer=1, interpret=ip), qw, pool, table, nb),
                       want, dtype)
+            # the write those Q rows take first: each sequence stores its
+            # first n_w rows from nb on, and no other row of the pool moves
+            kw, vw = rand((B, Q, H, D), dtype), rand((B, Q, H, D), dtype)
+            n_w = jax.random.randint(next(key), (B,), 0, Q + 1).at[0].set(Q)
+            plan = pk.paged_write_plan(table, nb, n_w, Q, ps)
+            got = pk.paged_kv_write(pool, 1, kw, vw, plan, interpret=ip)
+            pos = nb[:, None] + jnp.arange(Q)[None]
+            page = jnp.take_along_axis(table, pos // ps, axis=1)
+            page = jnp.where(jnp.arange(Q)[None] < n_w[:, None], page, P)
+            want = pool.at[1, :, page, pos % ps].set(
+                jnp.concatenate([kw, vw], -1), mode="drop")
+            orc.close(f"paged_kv_write[{dn},Q={Q}]", got, want, dtype)
 
     # bn_act_epilogue at ResNet-50's first and last stage, bf16 (no dots)
     for r, c in sz.epilogue_shapes:

@@ -382,83 +382,89 @@ def prefill(params, cache, prompt, cfg: TransformerConfig):
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_pages: int,
                         page_size: int):
-    """Per-layer paged K/V pool: (L, H, num_pages, page_size, Dh) —
-    head-major, the layout ops.pallas_kernels' paged kernels block per
-    head. No position scalar — slot positions live with the caller (the
-    engine), one per decode slot. Page 0 is the null page by convention
-    (serving.pages.PageAllocator never hands it out): dead slots and
-    padded prefill rows scatter their writes there."""
+    """The paged K/V pool, one buffer for every layer:
+    {"kv": (L, H, num_pages, page_size, 2 * Dh)}, head-major, a token's
+    K in lanes [0, Dh) of its row and its V in lanes [Dh, 2 * Dh) (why:
+    the layout comment above ops.pallas_kernels' paged kernels).
+
+    The pool is carried, never copied: the engine donates it to every
+    program, the layer loop below carries it, each layer's new rows are
+    written in place (ops.pallas_kernels.paged_kv_write) and the paged
+    kernels read a layer where it lies, by layer index. No position
+    scalar — slot positions live with the caller (the engine), one per
+    decode slot. Page 0 is the null page by convention
+    (serving.pages.PageAllocator never hands it out): dead slots read and
+    write it, and rows with nowhere to go (padding, overrun) are dropped."""
     H = cfg.n_heads
     Dh = cfg.d_model // H
-    shape = (cfg.n_layers, H, num_pages, page_size, Dh)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype)}
+    shape = (cfg.n_layers, H, num_pages, page_size, 2 * Dh)
+    return {"kv": jnp.zeros(shape, cfg.dtype)}
 
 
-def _page_write_index(page_table, positions, page_size):
-    """Flat pool row (page * page_size + offset) where each slot's next
-    token lands. positions: (S,) — tokens already cached per slot."""
-    page = jnp.take_along_axis(
-        page_table, (positions // page_size)[:, None], axis=1)[:, 0]
-    return page * page_size + positions % page_size
+def _paged_layers(params, paged, x, start, n_write, page_table, cfg,
+                  attend):
+    """The one layer loop of the three paged programs.
 
+    x: (S, T, d) — row t of sequence s is the token at position
+    start[s] + t; its K/V rows are stored for t < n_write[s] and
+    dropped otherwise. The pool is the loop's CARRY next to x: each
+    layer writes its rows into pool[l] in place and `attend(q, k, v,
+    pool, l)` -> (S, T, H, Dh) reads what it needs. With the pool donated
+    at the jit boundary, input, carry and output are one allocation.
+    Returns (x, new_paged)."""
+    from ..ops.pallas_kernels import paged_kv_write, paged_write_plan
 
-def _pool_write(pool, write_idx, rows):
-    """Scatter token rows (N, H, Dh) into one layer's pool
-    (H, num_pages, page_size, Dh) at flat rows write_idx (N,), each
-    page * page_size + offset."""
-    H, num_pages, page_size, Dh = pool.shape
-    flat = pool.reshape(H, num_pages * page_size, Dh)
-    flat = flat.at[:, write_idx].set(rows.astype(pool.dtype).swapaxes(0, 1))
-    return flat.reshape(pool.shape)
+    S, T, _ = x.shape
+    plan = paged_write_plan(page_table, start, n_write, T,
+                            paged["kv"].shape[3])
+    stacked = {k: params[k] for k in _stack_keys(params)}
+
+    def body(carry, layer_in):
+        x, pool = carry
+        lp, l = layer_in
+        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+        # the barrier keeps the head split out of the matmul: fused in, XLA
+        # turns the product into a convolution over heads that wants the
+        # weight transposed, and re-lays-out all three in every layer
+        q, k, v = (
+            _split_heads(lax.optimization_barrier(h @ lp[w]), cfg.n_heads)
+            for w in ("wq", "wk", "wv"))  # (S, T, H, Dh) each
+        pool = paged_kv_write(pool, l, k, v, plan)
+        with jax.named_scope("attention"):
+            a = attend(q, k, v, pool, l)
+        x = x + a.reshape(S, T, cfg.d_model) @ lp["wo"]
+        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
+        with jax.named_scope("ffn"):
+            if cfg.n_experts:
+                flat_h = h.reshape(S * T, cfg.d_model)
+                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
+                x = x + out.reshape(S, T, cfg.d_model)
+            else:
+                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+        return (x, pool), None
+
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    (x, pool), _ = lax.scan(body, (x, paged["kv"]), (stacked, layers))
+    return x, {"kv": pool}
 
 
 def decode_step_paged(params, paged, tokens, positions, page_table,
                       cfg: TransformerConfig):
-    """One token for every decode slot, each at its OWN depth.
+    """One token for every decode slot, each at its OWN depth: the
+    Q = 1 case of decode_step_paged_wide.
 
-    paged: init_paged_kv_cache dict; tokens (S,) int32; positions (S,)
+    paged: init_paged_kv_cache dict, carried through the layer loop and
+    written in place (donate it); tokens (S,) int32; positions (S,)
     int32 — tokens already cached per slot (the new token is written at
     that offset, then attention covers positions+1); page_table
     (S, P_max) int32 rows of owned page ids. Dead slots (all-zero table
     row, position 0) write to the null page and produce garbage logits
     the caller discards. Returns (logits (S, V), new_paged). Shapes are
     static in (S, P_max, pool) — every call is one XLA program."""
-    S = tokens.shape[0]
-    page_size = paged["k"].shape[3]
-    x = params["embed"][tokens] + params["pos"][positions]  # (S, d)
-    n_valid = positions + 1
-    write_idx = _page_write_index(page_table, positions, page_size)
-
-    stacked = {k: params[k] for k in _stack_keys(params)}
-
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in  # (H, num_pages, page_size, Dh)
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q = (h @ lp["wq"]).reshape(S, cfg.n_heads, -1)
-        k = (h @ lp["wk"]).reshape(S, cfg.n_heads, -1)
-        v = (h @ lp["wv"]).reshape(S, cfg.n_heads, -1)
-        k_pool = _pool_write(k_pool, write_idx, k)
-        v_pool = _pool_write(v_pool, write_idx, v)
-        from ..ops.pallas_kernels import paged_decode_attention
-
-        with jax.named_scope("attention"):
-            a = paged_decode_attention(q, k_pool, v_pool, page_table,
-                                       n_valid)
-        x = x + a.reshape(S, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        with jax.named_scope("ffn"):
-            if cfg.n_experts:
-                out, _ = moe_ffn(h, lp["router"], lp["w1"], lp["w2"])
-                x = x + out
-            else:
-                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
-        return x, (k_pool, v_pool)
-
-    x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    logits = x @ params["embed"].T
-    return logits, {"k": new_k, "v": new_v}
+    logits, paged = decode_step_paged_wide(
+        params, paged, tokens[:, None], positions,
+        jnp.ones_like(positions), page_table, cfg)
+    return logits[:, 0], paged
 
 
 def prefill_paged(params, paged, prompts, true_lens, page_table,
@@ -468,53 +474,25 @@ def prefill_paged(params, paged, prompts, true_lens, page_table,
     prompts: (S, T_b) int32 padded to the bucket length; true_lens (S,)
     — real prompt length per row (padding rows use 0); page_table
     (S, P_max). Causal attention makes every position < true_len exact
-    regardless of the padding tail; padded positions scatter to the null
-    page and their activations are never read. Returns (new_paged,
+    regardless of the padding tail; padded positions are not stored and
+    their activations are never read. The pool is carried and written in
+    place, whole pages at a time (_paged_layers). Returns (new_paged,
     logits (S, V) at each row's LAST REAL token — the first sampled
     continuation token, matching prefill()'s x[:, -1] for full rows."""
     S, T_b = prompts.shape
-    page_size = paged["k"].shape[3]
     x = params["embed"][prompts] + params["pos"][:T_b][None]
-    stacked = {k: params[k] for k in _stack_keys(params)}
 
-    t = jnp.arange(T_b)
-    valid = t[None, :] < true_lens[:, None]  # (S, T_b)
-    page = jnp.take_along_axis(
-        page_table, jnp.broadcast_to((t // page_size)[None], (S, T_b)),
-        axis=1)
-    write_idx = jnp.where(valid, page * page_size + t[None] % page_size,
-                          0).reshape(S * T_b)
+    def attend(q, k, v, pool, l):
+        return _dense_attention(q, k, v, causal=True)
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q = _split_heads(h @ lp["wq"], cfg.n_heads)
-        k = _split_heads(h @ lp["wk"], cfg.n_heads)
-        v = _split_heads(h @ lp["wv"], cfg.n_heads)
-        k_pool = _pool_write(k_pool, write_idx,
-                             k.reshape((S * T_b,) + k.shape[2:]))
-        v_pool = _pool_write(v_pool, write_idx,
-                             v.reshape((S * T_b,) + v.shape[2:]))
-        with jax.named_scope("attention"):
-            a = _dense_attention(q, k, v, causal=True)
-        x = x + a.reshape(S, T_b, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        with jax.named_scope("ffn"):
-            if cfg.n_experts:
-                flat_h = h.reshape(S * T_b, cfg.d_model)
-                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
-                x = x + out.reshape(S, T_b, cfg.d_model)
-            else:
-                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
-        return x, (k_pool, v_pool)
-
-    x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))
+    x, paged = _paged_layers(params, paged, x, jnp.zeros_like(true_lens),
+                             true_lens, page_table, cfg, attend)
     last = jnp.maximum(true_lens - 1, 0)  # (S,)
     x_last = jnp.take_along_axis(
         x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]  # (S, d)
     h = _ln(x_last, params["ln_f_g"], params["ln_f_b"])
     logits = h @ params["embed"].T
-    return {"k": new_k, "v": new_v}, logits
+    return paged, logits
 
 
 def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
@@ -528,62 +506,35 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
 
     tokens: (S, Q) int32 — token j of row s sits at position
     start[s] + j; start: (S,) int32 — tokens already cached per slot;
-    n_real: (S,) int32 — rows write K/V only for j < n_real (tokens
-    beyond scatter to the null page: chunk-tail padding, dead slots).
+    n_real: (S,) int32 — rows store K/V only for j < n_real (tokens
+    beyond are dropped: chunk-tail padding, dead slots).
     Attention for query j covers positions < start[s] + j + 1 — the
     paged prefix written by earlier calls plus intra-call causal — via
-    ops.pallas_kernels.paged_decode_attention_wide. Positions past the
-    page table's capacity or the positional table also land on the null
-    page (speculative rows may run past a sequence's last owned page;
-    their outputs are discarded by the caller).
+    ops.pallas_kernels.paged_decode_attention_wide, which reads the
+    carried pool by layer index. Positions past the page table's
+    capacity or the positional table are dropped too (speculative rows
+    may run past a sequence's last owned page; their outputs are
+    discarded by the caller).
 
     Returns (logits (S, Q, V), new_paged). Shapes are static in
     (S, Q, P_max, pool) — every call is one XLA program."""
     S, Q = tokens.shape
-    page_size = paged["k"].shape[3]
-    j = jnp.arange(Q, dtype=jnp.int32)
-    pos = start[:, None] + j[None, :]  # (S, Q) global positions
+    page_size = paged["kv"].shape[3]
+    pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]  # (S, Q)
     cap = min(page_table.shape[1] * page_size, params["pos"].shape[0])
-    writable = (j[None, :] < n_real[:, None]) & (pos < cap)
-    safe_pos = jnp.where(pos < cap, pos, 0)
-    x = params["embed"][tokens] + params["pos"][safe_pos]  # (S, Q, d)
-    page = jnp.take_along_axis(page_table, safe_pos // page_size, axis=1)
-    write_idx = jnp.where(
-        writable, page * page_size + safe_pos % page_size, 0
-    ).reshape(S * Q)
+    x = params["embed"][tokens] + params["pos"][
+        jnp.where(pos < cap, pos, 0)]  # (S, Q, d)
+    n_write = jnp.clip(cap - start, 0, n_real)
+    from ..ops.pallas_kernels import paged_decode_attention_wide
 
-    stacked = {k: params[k] for k in _stack_keys(params)}
+    def attend(q, k, v, pool, l):
+        return paged_decode_attention_wide(q, pool, page_table, start, l)
 
-    def body(x, layer_in):
-        lp, k_pool, v_pool = layer_in
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q = _split_heads(h @ lp["wq"], cfg.n_heads)  # (S, Q, H, Dh)
-        k = _split_heads(h @ lp["wk"], cfg.n_heads)
-        v = _split_heads(h @ lp["wv"], cfg.n_heads)
-        k_pool = _pool_write(k_pool, write_idx,
-                             k.reshape((S * Q,) + k.shape[2:]))
-        v_pool = _pool_write(v_pool, write_idx,
-                             v.reshape((S * Q,) + v.shape[2:]))
-        from ..ops.pallas_kernels import paged_decode_attention_wide
-
-        with jax.named_scope("attention"):
-            a = paged_decode_attention_wide(q, k_pool, v_pool, page_table,
-                                            start)
-        x = x + a.reshape(S, Q, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        with jax.named_scope("ffn"):
-            if cfg.n_experts:
-                flat_h = h.reshape(S * Q, cfg.d_model)
-                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
-                x = x + out.reshape(S, Q, cfg.d_model)
-            else:
-                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
-        return x, (k_pool, v_pool)
-
-    x, (new_k, new_v) = lax.scan(body, x, (stacked, paged["k"], paged["v"]))
+    x, paged = _paged_layers(params, paged, x, start, n_write, page_table,
+                             cfg, attend)
     x = _ln(x, params["ln_f_g"], params["ln_f_b"])
     logits = x @ params["embed"].T
-    return logits, {"k": new_k, "v": new_v}
+    return logits, paged
 
 
 def _filter_logits(logits, top_k=0, top_p=0.0):

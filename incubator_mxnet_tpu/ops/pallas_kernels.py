@@ -21,7 +21,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "softmax_xent", "flash_decode",
            "dense_decode_attention", "paged_decode_attention",
-           "paged_decode_attention_wide", "bn_act_epilogue",
+           "paged_decode_attention_wide", "paged_kv_write",
+           "paged_write_plan",
+           "bn_act_epilogue",
            "DECODE_BLOCK", "DENSE_FALLBACKS_TOTAL"]
 
 _NEG_INF = -1e30
@@ -748,30 +750,48 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
 # Per-sequence valid lengths make it the continuous-batching serving kernel:
 # slots at different depths decode in ONE launch of one compiled program.
 #
-# Pool layout is head-major, (H, num_pages, page_size, D): the block one
-# grid step maps is then one head's whole pool, (num_pages, page_size, D),
-# whose last two dimensions are whole — the only shape the TPU lowering
-# accepts for a per-head slice (a head axis squeezed in the second-minor
-# position is refused). The grid is (H, B) with the head outermost so the
-# pool block is fetched once per head, not once per (head, sequence).
+# The pool is ONE buffer for every layer, (L, H, num_pages, page_size, 2*D),
+# and it never moves: the layer loop carries it, paged_kv_write stores a
+# step's new rows into layer l in place (input_output_aliases), and the
+# attention kernels read layer l where it lies, by a scalar-prefetched layer
+# index in the block's index map. Nothing is sliced, copied or re-laid-out
+# per layer.
+#
+# A row holds a token's K in lanes [0, D) and its V in lanes [D, 2*D). With
+# D = 64 that is one full 128-lane row, so the row-major tiled layout Mosaic
+# wants for the block is also the layout XLA gives the array at the jit
+# boundary, without padding. (K and V as separate (..., page_size, 64)
+# arrays pad every row to 128 lanes for Mosaic, twice the bytes, and XLA
+# keeps them at the boundary with the page axis minor-most instead: every
+# step would re-lay-out the whole pool going in and coming out.) The kernels
+# read K as the row's first D lanes, a lane-prefix load that moves nothing,
+# and never cut V out: p . row carries the attention output in its V lanes,
+# and the caller drops the K lanes of the result.
+#
+# Head-major: the block one grid step maps is one head's whole pool in one
+# layer, (num_pages, page_size, 2*D), whose last two dimensions are whole,
+# the only shape the TPU lowering accepts for a per-head slice (a head axis
+# squeezed in the second-minor position is refused). The grid is (H, B) with
+# the head outermost so the pool block is fetched once per head, not once
+# per (head, sequence).
 # ---------------------------------------------------------------------------
 
-# Scoped VMEM the paged kernels ask Mosaic for. One head's K and V pool
-# blocks, double-buffered by the pipeline, must fit under it; a pool that
-# does not is an error (_check_pool_fits_vmem), never a dense fallback.
+# Scoped VMEM the paged kernels ask Mosaic for. One head's pool block,
+# double-buffered by the pipeline, must fit under it; a pool that does not
+# is an error (_check_pool_fits_vmem), never a dense fallback.
 PAGED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _PAGED_VMEM_RESERVE_BYTES = 4 * 1024 * 1024  # q/o blocks, carries, spills
 
 
 def paged_pool_vmem_bytes(num_pages, page_size, head_dim, dtype):
-    """VMEM the pipeline holds for one head of a paged pool: K and V
-    blocks, two buffers each, every page padded to the dtype's native
+    """VMEM the pipeline holds for one head of a paged pool: two buffers
+    of the fused K|V block, every page padded to the dtype's native
     (sublane, 128-lane) tile."""
     itemsize = jnp.dtype(dtype).itemsize
     sublane = 8 * 4 // itemsize
     rows = -(-page_size // sublane) * sublane
-    lanes = -(-head_dim // 128) * 128
-    return 2 * 2 * num_pages * rows * lanes * itemsize
+    lanes = -(-2 * head_dim // 128) * 128
+    return 2 * num_pages * rows * lanes * itemsize
 
 
 def _check_pool_fits_vmem(num_pages, page_size, head_dim, dtype):
@@ -787,16 +807,116 @@ def _check_pool_fits_vmem(num_pages, page_size, head_dim, dtype):
             f"{budget // per_page} such pages ({budget} bytes)")
 
 
-def _paged_decode_wide_kernel(pt_ref, nb_ref, q_ref, k_ref, v_ref, o_ref, *,
+def _layer_index(layer):
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _paged_write_kernel(l_ref, pg_ref, lo_ref, hi_ref, new_ref, old_ref,
+                        out_ref):
+    """One (s, j) grid step: the j-th page sequence s writes this call.
+    Rows [lo, hi) of the page take the new values, the rest keep what
+    the pool holds. All refs are (H, page_size, 2*D)."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    take = (row >= lo_ref[s, j]) & (row < hi_ref[s, j])
+    out_ref[...] = jnp.where(take, new_ref[...], old_ref[...])
+
+
+def paged_write_plan(page_table, start, n_write, n_rows, page_size):
+    """Where a call's new K/V rows go, worked out once for every layer.
+
+    page_table: (S, P_max) int32; start, n_write: (S,) int32 — row t of
+    sequence s sits at position start[s] + t and is stored only for
+    t < n_write[s] (padding, dead slots and speculative overrun store
+    nothing; positions past the table's capacity are dropped too);
+    n_rows: T, the rows each sequence brings; page_size: static.
+
+    Returns (pages, lo, hi, src): for the j-th page a sequence can touch,
+    pages (S, J) its id — the null page 0 where there is nothing to
+    store — and lo, hi (S, J) the rows [lo, hi) of it that take new
+    values; src (S, J * page_size) maps the page grid back to the call's
+    rows (aligned row i of sequence s is position
+    (start[s] // page_size) * page_size + i)."""
+    W = page_table.shape[1]
+    # pages a run of T rows can touch when it starts anywhere in a page
+    J = (n_rows + 2 * page_size - 2) // page_size
+    start = jnp.asarray(start, jnp.int32)
+    end = jnp.minimum(start + jnp.asarray(n_write, jnp.int32),
+                      W * page_size)
+    col = ((start // page_size)[:, None]
+           + jnp.arange(J, dtype=jnp.int32)[None])  # (S, J) table columns
+    lo = jnp.clip(start[:, None] - col * page_size, 0, page_size)
+    hi = jnp.clip(end[:, None] - col * page_size, 0, page_size)
+    pages = jnp.where(
+        hi > lo,
+        jnp.take_along_axis(jnp.asarray(page_table, jnp.int32),
+                            jnp.minimum(col, W - 1), axis=1), 0)
+    src = (jnp.arange(J * page_size, dtype=jnp.int32)[None]
+           - (start % page_size)[:, None])
+    return pages, lo, hi, jnp.clip(src, 0, n_rows - 1)
+
+
+def paged_kv_write(pool, layer, k, v, plan, interpret=None):
+    """Store new K/V rows into one layer of the paged pool, in place.
+
+    pool: (L, H, num_pages, page_size, 2*D); layer: int32 scalar (traced
+    in the layer loop); k, v: (S, T, H, D); plan: paged_write_plan's
+    tuple for these S sequences of T rows.
+
+    One grid step per (sequence, page touched): the page's block comes in
+    and goes out through the SAME buffer (input_output_aliases), so under
+    a donated jit and a loop that carries the pool, XLA allocates nothing
+    and copies nothing. Steps with no row to store point at the null
+    page 0 and leave it as it is. A sequence's pages are its own (the
+    allocator's copy-on-write guarantees it), so no two steps that store
+    rows share a page.
+
+    Returns the pool."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    pages, lo, hi, src = plan
+    H, page_size, D2 = pool.shape[1], pool.shape[3], pool.shape[4]
+    # rows head-major, K|V fused, shifted onto the page grid
+    rows = jnp.concatenate([k, v], axis=-1).astype(pool.dtype)
+    rows = jnp.take_along_axis(rows.transpose(0, 2, 1, 3),  # (S, H, T, 2*D)
+                               src[:, None, :, None], axis=2)
+    page_spec = pl.BlockSpec(
+        (None, H, None, page_size, D2),
+        lambda s, j, l, pg, *refs: (l[0], 0, pg[s, j], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=pages.shape,
+        in_specs=[
+            pl.BlockSpec((None, H, page_size, D2),
+                         lambda s, j, *refs: (s, 0, j, 0)),
+            page_spec,
+        ],
+        out_specs=page_spec,
+    )
+    return pl.pallas_call(
+        _paged_write_kernel,
+        name="paged_kv_write",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operand 5 (after the four scalar-prefetch arrays and the rows)
+        # is the pool, and it is the output
+        input_output_aliases={5: 0},
+        interpret=interpret,
+    )(_layer_index(layer), pages, lo, hi, rows, pool)
+
+
+def _paged_decode_wide_kernel(pt_ref, nb_ref, l_ref, q_ref, kv_ref, o_ref, *,
                               page_size, scale):
     """One (h, b) grid step with Q query rows at consecutive positions:
     row i sits at position nb + i and attends idx < nb + i + 1 — the
-    paged prefix plus causal masking WITHIN the call. pt_ref (B, P_max)
-    and nb_ref (B,) are scalar-prefetch refs (SMEM — readable for control
-    flow and dynamic page indices); k_ref/v_ref see the whole pool of
-    head h as (num_pages, page_size, d)."""
+    paged prefix plus causal masking WITHIN the call. pt_ref (B, P_max),
+    nb_ref (B,) and l_ref (1,) are scalar-prefetch refs (SMEM — readable
+    for control flow, dynamic page indices and the block index maps);
+    kv_ref sees the whole pool of head h in layer l as
+    (num_pages, page_size, 2*d), K|V per row; q_ref is (Q, d); o_ref is
+    (Q, 2*d) and its V lanes are the output."""
     b = pl.program_id(1)
-    q = q_ref[...]  # (Q, d)
+    q = q_ref[...]
     nb = nb_ref[b]
     n_q, d = q.shape
     row = jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 0)
@@ -805,8 +925,8 @@ def _paged_decode_wide_kernel(pt_ref, nb_ref, q_ref, k_ref, v_ref, o_ref, *,
     def body(j, carry):
         page = pt_ref[b, j]
         live = j * page_size + col < nb + row + 1
-        return _online_softmax_update(q, k_ref[page], v_ref[page], live,
-                                      carry, scale)
+        return _online_softmax_update(q, kv_ref[page, :, :d], kv_ref[page],
+                                      live, carry, scale)
 
     # the deepest row attends nb + Q tokens; clamp the walk to the table
     # width so speculative rows past a sequence's last owned page never
@@ -815,21 +935,23 @@ def _paged_decode_wide_kernel(pt_ref, nb_ref, q_ref, k_ref, v_ref, o_ref, *,
     num_pages = jnp.minimum((nb + n_q + page_size - 1) // page_size,
                             pt_ref.shape[1])
     carry = jax.lax.fori_loop(0, num_pages, body,
-                              _online_softmax_init(n_q, d))
+                              _online_softmax_init(n_q, o_ref.shape[1]))
     o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
 
 
-def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
+def paged_decode_attention_wide(q, pool, page_table, n_base, layer=0,
                                 interpret=None):
     """Wider-query attention over a paged KV cache: Q consecutive query
     tokens per sequence in ONE launch.
 
     q: (B, Q, H, D) — query i of sequence b sits at position
-    n_base[b] + i; k_pages/v_pages: (H, num_pages, page_size, D) pool
-    (the caller has already scattered the Q new tokens' K/V into it);
-    page_table: (B, P_max) int32 — page ids owned by each sequence, in
-    order (entries past the live length are ignored); n_base: (B,) int32
-    — tokens cached per sequence BEFORE this call's first query. Query i
+    n_base[b] + i; pool: (L, H, num_pages, page_size, 2*D), the whole
+    pool (paged_kv_write has already stored the Q new tokens' rows in
+    layer `layer`); layer: int32 scalar, the layer to read — the block's
+    index map takes it, nothing is sliced out of the pool; page_table:
+    (B, P_max) int32 — page ids owned by each sequence, in order
+    (entries past the live length are ignored); n_base: (B,) int32 —
+    tokens cached per sequence BEFORE this call's first query. Query i
     attends positions < n_base + i + 1 (paged prefix + intra-call
     causal), so a single launch serves chunked prefill (Q = chunk),
     cached-prefix tail prefill (n_base = cached tokens) and speculative
@@ -844,23 +966,21 @@ def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     B, Q, H, D = q.shape
-    num_pages, page_size = k_pages.shape[1], k_pages.shape[2]
-    _check_pool_fits_vmem(num_pages, page_size, D, k_pages.dtype)
+    num_pages, page_size, D2 = pool.shape[2:]
+    _check_pool_fits_vmem(num_pages, page_size, D, pool.dtype)
     nb = _per_seq_n_valid(n_base, B)
     pt = jnp.asarray(page_table, jnp.int32)
     qr = q.transpose(0, 2, 1, 3)  # (B, H, Q, D)
-    pool_spec = pl.BlockSpec((None, num_pages, page_size, D),
-                             lambda h, b, *refs: (h, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(H, B),
         in_specs=[
             pl.BlockSpec((None, None, Q, D),
                          lambda h, b, *refs: (b, h, 0, 0)),
-            pool_spec,
-            pool_spec,
+            pl.BlockSpec((None, None, num_pages, page_size, D2),
+                         lambda h, b, pt, nb, l: (l[0], h, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, Q, D),
+        out_specs=pl.BlockSpec((None, None, Q, D2),
                                lambda h, b, *refs: (b, h, 0, 0)),
     )
     kernel = functools.partial(_paged_decode_wide_kernel,
@@ -873,26 +993,27 @@ def paged_decode_attention_wide(q, k_pages, v_pages, page_table, n_base,
         name=("paged_decode_attention" if Q == 1
               else "paged_decode_attention_wide"),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Q, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Q, D2), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(pt, nb, qr, k_pages, v_pages)
-    return o.transpose(0, 2, 1, 3)
+    )(pt, nb, _layer_index(layer), qr, pool)
+    return o[..., D:].transpose(0, 2, 1, 3)  # the V lanes
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, n_valid,
+def paged_decode_attention(q, pool, page_table, n_valid, layer=0,
                            interpret=None):
     """Single-query attention over a paged KV cache — the Q = 1 case of
     paged_decode_attention_wide.
 
-    q: (B, H, D) — one query per decode slot; k_pages/v_pages:
-    (H, num_pages, page_size, D); page_table: (B, P_max) int32;
-    n_valid: (B,) int32 (or scalar) — tokens live per slot INCLUDING the
-    one just written; 0 marks a dead slot (its output is finite garbage
-    from the null page that the caller discards). Returns (B, H, D)."""
+    q: (B, H, D) — one query per decode slot; pool:
+    (L, H, num_pages, page_size, 2*D), read in layer `layer`;
+    page_table: (B, P_max) int32; n_valid: (B,) int32 (or scalar) —
+    tokens live per slot INCLUDING the one just written; 0 marks a dead
+    slot (its output is finite garbage from the null page that the
+    caller discards). Returns (B, H, D)."""
     nv = _per_seq_n_valid(n_valid, q.shape[0])
-    o = paged_decode_attention_wide(q[:, None], k_pages, v_pages,
-                                    page_table, jnp.maximum(nv - 1, 0),
+    o = paged_decode_attention_wide(q[:, None], pool, page_table,
+                                    jnp.maximum(nv - 1, 0), layer,
                                     interpret=interpret)
     return o[:, 0]

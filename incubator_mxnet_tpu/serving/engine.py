@@ -313,8 +313,9 @@ class ServingEngine:
         _exporters.register_debug_handler("/debug/engine",
                                           self.debug_snapshot)
 
-        # donation frees the old pool the moment the step runs; CPU
-        # buffers aren't donatable (jax warns and copies anyway)
+        # the donated pool is every program's input, loop carry and
+        # output in one allocation (models.transformer._paged_layers);
+        # CPU buffers aren't donatable (jax warns and copies anyway)
         donate = (1,) if jax.default_backend() != "cpu" else ()
         self._decode = compile_cache.wrap(
             "serving_decode_step",
@@ -359,10 +360,9 @@ class ServingEngine:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), paged
 
     def _copy_fn(self, paged, src, dst):
-        # pool is (L, H, num_pages, page_size, Dh): pages are axis 2
-        k, v = paged["k"], paged["v"]
-        return {"k": k.at[:, :, dst].set(k[:, :, src]),
-                "v": v.at[:, :, dst].set(v[:, :, src])}
+        # pool is (L, H, num_pages, page_size, 2 * Dh): pages are axis 2
+        kv = paged["kv"]
+        return {"kv": kv.at[:, :, dst].set(kv[:, :, src])}
 
     def _wide(self, n_q):
         """Wide-query program for `n_q` rows per slot — one named site

@@ -1,11 +1,11 @@
 """Host-side page allocator + prefix cache for the paged KV cache.
 
 The device pool (`models.transformer.init_paged_kv_cache`) is
-`(L, H, num_pages, page_size, Dh)`; this allocator owns the free list
-over `num_pages` and hands out page ids. Page 0 is the RESERVED NULL
-PAGE: it is never allocated, and dead decode slots / padded prefill rows
-scatter their writes there, so an all-zero page-table row is always a
-safe "empty" row. Allocation is all-or-nothing (a request either gets
+`(L, H, num_pages, page_size, 2 * Dh)`; this allocator owns the free
+list over `num_pages` and hands out page ids. Page 0 is the RESERVED NULL
+PAGE: it is never allocated, dead decode slots read and write it and
+padded prefill rows are stored nowhere, so an all-zero page-table row is
+always a safe "empty" row. Allocation is all-or-nothing (a request either gets
 every page it needs or stays in the queue — no mid-decode exhaustion),
 and `free()` returns pages for immediate reuse without touching device
 memory: stale K/V in a recycled page is dead data beyond every live

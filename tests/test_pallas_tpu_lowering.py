@@ -13,6 +13,8 @@ numbers; the chip checks them again in chip_smoke.py's kernel phase.
 """
 import functools
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -35,6 +37,11 @@ I32 = jnp.int32
 def _grad(f, n):
     return jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32)),
                     argnums=tuple(range(n)))
+
+
+def _write(n_rows, pool, k, v, table, start, n_write):
+    plan = pk.paged_write_plan(table, start, n_write, n_rows, PAGE)
+    return pk.paged_kv_write(pool, 1, k, v, plan, interpret=False)
 
 
 def _cases():
@@ -61,18 +68,26 @@ def _cases():
             functools.partial(pk.flash_decode, interpret=False),
             [((SLOTS, H, D), dt)]
             + [((SLOTS, SZ.decode_len, H, D), dt)] * 2 + [((SLOTS,), I32)]))
-        pool = [((H, POOL, PAGE, D), dt)] * 2
+        # one pool for every layer, K|V fused per row, read and written
+        # in layer 1 of 2
+        pool = [((2, H, POOL, PAGE, 2 * D), dt)]
         tail = [((SLOTS, TABLE_W), I32), ((SLOTS,), I32)]
         out.append((
             f"paged_decode_attention-{dn}",
-            functools.partial(pk.paged_decode_attention, interpret=False),
+            functools.partial(pk.paged_decode_attention, layer=1,
+                              interpret=False),
             [((SLOTS, H, D), dt)] + pool + tail))
         for q in SZ.wide_q:
             out.append((
                 f"paged_decode_attention_wide-{dn}-Q{q}",
-                functools.partial(pk.paged_decode_attention_wide,
+                functools.partial(pk.paged_decode_attention_wide, layer=1,
                                   interpret=False),
                 [((SLOTS, q, H, D), dt)] + pool + tail))
+        for q in (1,) + SZ.wide_q:
+            out.append((
+                f"paged_kv_write-{dn}-Q{q}",
+                functools.partial(_write, q),
+                pool + [((SLOTS, q, H, D), dt)] * 2 + tail + tail[1:]))
     epi = functools.partial(pk.bn_act_epilogue, interpret=False)
     for r, c in SZ.epilogue_shapes:
         plain = [((r, c), jnp.bfloat16), ((c,), jnp.float32),
@@ -92,7 +107,8 @@ IDS = [c[0] for c in CASES]
 
 def test_every_public_kernel_has_a_case():
     kernels = {n for n in pk.__all__
-               if callable(getattr(pk, n)) and n != "dense_decode_attention"}
+               if callable(getattr(pk, n))
+               and n not in ("dense_decode_attention", "paged_write_plan")}
     covered = {i.split("-")[0] for i in IDS}
     assert kernels == covered, kernels ^ covered
 
@@ -124,3 +140,82 @@ def test_mosaic_compiles_for_v5e(case, v5e_device):
     sharding = jax.sharding.SingleDeviceSharding(v5e_device)
     avals = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in args]
     jax.jit(fn).lower(*avals).compile()
+
+
+# -- the serving programs keep the KV pool where it lies -----------------------
+
+# GPT-2 XL's widths and the benchmark's engine geometry (16 slots, pages of
+# 16, max_len 1024: 1025 pages), at a depth that compiles in seconds
+XL = dict(vocab=50257, d_model=1600, n_heads=25, d_ff=6400, n_layers=4,
+          max_len=1024, dtype="bfloat16")
+XL_SLOTS, XL_PAGE = 16, 16
+XL_TABLE_W = XL["max_len"] // XL_PAGE
+XL_PAGES = XL_SLOTS * XL_TABLE_W + 1
+# what one layer's K (or V) holds: nothing this large may be copied, sliced,
+# transposed or scattered once per layer
+LAYER_K_ELEMS = XL["n_heads"] * XL_PAGES * XL_PAGE * (XL["d_model"]
+                                                      // XL["n_heads"])
+_BIG_OP = r"= \w+\[([\d,]+)\]\S* (copy|transpose|dynamic-slice|scatter)\("
+
+
+def _engine_programs(cfg):
+    """ServingEngine's jitted functions over a stand-in self, each with
+    its argument shapes after params and pool."""
+    import types
+    from incubator_mxnet_tpu.serving import ServingEngine
+
+    me = types.SimpleNamespace(cfg=cfg)
+    S, W = XL_SLOTS, XL_TABLE_W
+    return {
+        "decode": (functools.partial(ServingEngine._decode_fn, me),
+                   [(S,), (S,), (S, W)]),
+        "prefill_b1024": (functools.partial(ServingEngine._prefill_fn, me),
+                          [(1, 1024), (1,), (1, W)]),
+    }
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b1024"])
+def test_serving_program_carries_the_pool_in_place(program, v5e_device,
+                                                   monkeypatch):
+    """The compiled decode step and a prefill bucket hold the pool once:
+    donated input, loop carry and output are one allocation, and no op
+    moves a layer's worth of it. Fails if the pool goes back to scan
+    xs -> ys, to a scatter over a reshaped slice, or to a layout the
+    kernels cannot read in place."""
+    import re
+    from incubator_mxnet_tpu.models import transformer as tfm
+
+    # the kernels pick interpret mode from the backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = tfm.TransformerConfig(**XL)
+    sharding = jax.sharding.SingleDeviceSharding(v5e_device)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    # the benchmark's leaves: bf16 matrices, float32 positions
+    params = {k: on_chip(v.shape, jnp.float32 if k == "pos" else jnp.bfloat16)
+              for k, v in jax.eval_shape(
+                  lambda: tfm.init_params(cfg, 0)).items()}
+    paged = jax.tree_util.tree_map(
+        lambda v: on_chip(v.shape, v.dtype),
+        jax.eval_shape(lambda: tfm.init_paged_kv_cache(cfg, XL_PAGES,
+                                                       XL_PAGE)))
+    fn, tail = _engine_programs(cfg)[program]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, paged, *[on_chip(s, I32) for s in tail]).compile()
+
+    mem = compiled.memory_analysis()
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    pool_bytes = sum(v.size for v in paged.values()) * itemsize
+    layer_bytes = pool_bytes // cfg.n_layers  # one layer's K + V
+    # the tied embedding is re-laid-out for the logits matmul in every
+    # call (161 MB; the parent of PR 26 had that copy too): set it aside
+    embed_bytes = cfg.vocab * cfg.d_model * itemsize
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes - embed_bytes < layer_bytes, mem
+    big = [m.group(0) for m in re.finditer(_BIG_OP, compiled.as_text())
+           if np.prod([int(d) for d in m.group(1).split(",")])
+           >= LAYER_K_ELEMS
+           and m.group(1) != f"{cfg.vocab},{cfg.d_model}"]
+    assert not big, big

@@ -20,10 +20,14 @@ def _small_cfg(**kw):
     return tfm.TransformerConfig(**base)
 
 
-def _pool(pages):
-    """(P, ps, H, D) test pages -> the kernels' head-major pool layout
-    (H, P, ps, D)."""
-    return jnp.asarray(pages.transpose(2, 0, 1, 3))
+def _pool(k_pages, v_pages, layers=1, layer=0):
+    """(P, ps, H, D) test pages -> the kernels' pool,
+    (L, H, P, ps, 2 * D): head-major, K|V fused per row, the pages in
+    layer `layer` and noise in every other."""
+    kv = np.concatenate([k_pages, v_pages], -1).transpose(2, 0, 1, 3)
+    pool = np.random.RandomState(99).randn(layers, *kv.shape)
+    pool[layer] = kv
+    return jnp.asarray(pool.astype(k_pages.dtype))
 
 
 def _gather_dense(k_pages, v_pages, page_table, page_size):
@@ -55,7 +59,7 @@ def test_paged_decode_matches_dense_ragged():
     page_table = np.array([[1, 2, 3, 0], [4, 0, 0, 0],
                            [5, 6, 0, 0], [0, 0, 0, 0]], np.int32)
     got = np.asarray(paged_decode_attention(
-        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
+        jnp.asarray(q), _pool(k_pages, v_pages),
         jnp.asarray(page_table), jnp.asarray(n_valid), interpret=True))
     kc, vc = _gather_dense(k_pages, v_pages, page_table, ps)
     want = np.asarray(dense_decode_attention(
@@ -86,7 +90,7 @@ def test_paged_decode_pages_reused_after_free():
     n_valid = np.array([2 * ps], np.int32)
     q = rng.randn(1, H, D).astype(np.float32)
     got = np.asarray(paged_decode_attention(
-        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
+        jnp.asarray(q), _pool(k_pages, v_pages),
         jnp.asarray(table), jnp.asarray(n_valid), interpret=True))
     kc, vc = _gather_dense(k_pages, v_pages, table, ps)
     want = np.asarray(dense_decode_attention(
@@ -109,7 +113,7 @@ def test_paged_decode_wide_matches_dense_per_row(Q):
     page_table = np.array([[1, 2, 3, 0], [4, 0, 0, 0], [5, 6, 7, 0]],
                           np.int32)
     got = np.asarray(paged_decode_attention_wide(
-        jnp.asarray(q), _pool(k_pages), _pool(v_pages),
+        jnp.asarray(q), _pool(k_pages, v_pages),
         jnp.asarray(page_table), jnp.asarray(n_base), interpret=True))
     kc, vc = _gather_dense(k_pages, v_pages, page_table, ps)
     for i in range(Q):
@@ -127,14 +131,238 @@ def test_paged_pool_too_large_for_vmem_raises():
     H, D, ps = 2, 64, 16
     fits = (pk.PAGED_VMEM_LIMIT_BYTES - pk._PAGED_VMEM_RESERVE_BYTES) \
         // (pk.paged_pool_vmem_bytes(1, ps, D, jnp.bfloat16))
-    pool = jax.ShapeDtypeStruct((H, fits + 1, ps, D), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((1, H, fits + 1, ps, 2 * D), jnp.bfloat16)
     q = jax.ShapeDtypeStruct((1, H, D), jnp.bfloat16)
     pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
     nv = jax.ShapeDtypeStruct((1,), jnp.int32)
     with pytest.raises(ValueError, match=f"at most {fits} such pages"):
-        jax.eval_shape(paged_decode_attention, q, pool, pool, pt, nv)
-    ok = jax.ShapeDtypeStruct((H, fits, ps, D), jnp.bfloat16)
-    jax.eval_shape(paged_decode_attention, q, ok, ok, pt, nv)
+        jax.eval_shape(paged_decode_attention, q, pool, pt, nv)
+    ok = jax.ShapeDtypeStruct((1, H, fits, ps, 2 * D), jnp.bfloat16)
+    jax.eval_shape(paged_decode_attention, q, ok, pt, nv)
+
+
+# -- the carried pool: paged programs against the dense cache ------------------
+
+_PS, _W = 4, 8  # page size, table width: 32 positions a slot
+
+
+def _deep_cfg(**kw):
+    # three layers: a write or a read in the wrong layer shows
+    return _small_cfg(n_layers=3, max_len=_PS * _W, **kw)
+
+
+def _noise_pool(cfg, slots, seed=7):
+    """A pool of noise, not zeros: a row read from where nothing wrote
+    it, or written where it should not be, changes a number."""
+    paged = tfm.init_paged_kv_cache(cfg, slots * _W + 1, _PS)
+    rng = np.random.RandomState(seed)
+    return {"kv": jnp.asarray(
+        rng.randn(*paged["kv"].shape).astype(np.float32))}
+
+
+def _tables(slots, live, seed=5):
+    """Shuffled page ownership; slots not in `live` keep the all-zero
+    row of a dead slot."""
+    perm = 1 + np.random.RandomState(seed).permutation(slots * _W)
+    table = np.zeros((slots, _W), np.int32)
+    for s in live:
+        table[s] = perm[s * _W:(s + 1) * _W]
+    return table
+
+
+def _dense_run(params, cfg, prompt, feed):
+    """Reference: dense-cache prefill of `prompt`, then decode_step over
+    `feed`. Returns (prefill logits (V,), [logits after each fed token],
+    cache)."""
+    cache = tfm.init_kv_cache(cfg, 1, cfg.max_len)
+    cache, lg = tfm.prefill(params, cache, jnp.asarray(prompt)[None], cfg)
+    out = []
+    for t in feed:
+        step, cache = tfm.decode_step(
+            params, cache, jnp.asarray([t], jnp.int32), cfg)
+        out.append(np.asarray(step[0]))
+    return np.asarray(lg[0]), out, cache
+
+
+def _pool_rows(paged, table_row, n):
+    """K and V of positions [0, n) as a page table row maps them:
+    (L, n, H, Dh) each."""
+    kv = np.asarray(paged["kv"])  # (L, H, P, ps, 2*Dh)
+    rows = kv[:, :, table_row].reshape(kv.shape[0], kv.shape[1], -1,
+                                       kv.shape[-1])[:, :, :n]
+    rows = rows.transpose(0, 2, 1, 3)
+    d = rows.shape[-1] // 2
+    return rows[..., :d], rows[..., d:]
+
+
+def _changed_rows(old, new):
+    """{(page, offset)} of the rows that differ in any layer or head."""
+    diff = np.any(np.asarray(old["kv"]) != np.asarray(new["kv"]),
+                  axis=(0, 1, 4))
+    return {(int(p), int(o)) for p, o in zip(*np.nonzero(diff))}
+
+
+def _owned(table_row, positions):
+    return {(int(table_row[t // _PS]), t % _PS) for t in positions}
+
+
+@pytest.fixture(scope="module")
+def prefilled():
+    """Three slots, the middle one dead, prefilled through one bucket of
+    16 with a padding row: what the decode and wide cases start from."""
+    cfg = _deep_cfg()
+    params = tfm.init_params(cfg, seed=4)
+    rng = np.random.RandomState(21)
+    lens = np.array([13, 0, 6], np.int32)
+    table = _tables(3, live=(0, 2))
+    prompts = rng.randint(1, cfg.vocab, (3, 16)).astype(np.int32)
+    before = _noise_pool(cfg, 3)
+    paged, logits = tfm.prefill_paged(
+        params, before, jnp.asarray(prompts), jnp.asarray(lens),
+        jnp.asarray(table), cfg)
+    return dict(cfg=cfg, params=params, lens=lens, table=table,
+                prompts=prompts, before=before, paged=paged,
+                logits=np.asarray(logits))
+
+
+def test_prefill_paged_matches_dense_and_writes_only_its_rows(prefilled):
+    f = prefilled
+    for s in (0, 2):
+        n = int(f["lens"][s])
+        want, _, cache = _dense_run(f["params"], f["cfg"],
+                                    f["prompts"][s, :n], [])
+        np.testing.assert_allclose(f["logits"][s], want, rtol=2e-4,
+                                   atol=2e-4)
+        # every layer's rows sit where the table says, K and V apart
+        k, v = _pool_rows(f["paged"], f["table"][s], n)
+        np.testing.assert_allclose(k, np.asarray(cache["k"])[:, 0, :n],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(v, np.asarray(cache["v"])[:, 0, :n],
+                                   rtol=2e-5, atol=2e-5)
+    # the bucket's padding (positions 13-15, 6-15, the whole dead row)
+    # is stored nowhere: bit for bit, only the real rows changed
+    assert _changed_rows(f["before"], f["paged"]) == (
+        _owned(f["table"][0], range(13)) | _owned(f["table"][2], range(6)))
+
+
+def test_decode_step_paged_matches_dense_at_own_depths(prefilled):
+    f = prefilled
+    cfg, params, table = f["cfg"], f["params"], f["table"]
+    feed = np.random.RandomState(8).randint(1, cfg.vocab, (3, 3)).astype(
+        np.int32)
+    paged, pos, got = f["paged"], f["lens"].copy(), []
+    for i in range(3):  # the third step crosses slot 0's page boundary
+        before = paged
+        lg, paged = tfm.decode_step_paged(
+            params, paged, jnp.asarray(feed[:, i]), jnp.asarray(pos),
+            jnp.asarray(table), cfg)
+        got.append(np.asarray(lg))
+        # one row per live slot; the dead slot's lands on the null page
+        assert _changed_rows(before, paged) == (
+            _owned(table[0], [pos[0]]) | _owned(table[2], [pos[2]])
+            | {(0, 0)})
+        pos[[0, 2]] += 1
+    for s in (0, 2):
+        n = int(f["lens"][s])
+        _, want, _ = _dense_run(params, cfg, f["prompts"][s, :n], feed[s])
+        for i in range(3):
+            np.testing.assert_allclose(got[i][s], want[i], rtol=2e-4,
+                                       atol=2e-4)
+
+
+@pytest.mark.parametrize("n_real", [4, 2])
+def test_decode_step_paged_wide_matches_dense_rows(prefilled, n_real):
+    """Q = 4 rows a slot from an unaligned start: row j is the dense
+    cache's step j; rows past n_real (chunk padding) store nothing."""
+    f = prefilled
+    cfg, params, table = f["cfg"], f["params"], f["table"]
+    feed = np.random.RandomState(9).randint(1, cfg.vocab, (3, 4)).astype(
+        np.int32)
+    n = np.array([n_real, 0, 4], np.int32)
+    lg, paged = tfm.decode_step_paged_wide(
+        params, f["paged"], jnp.asarray(feed), jnp.asarray(f["lens"]),
+        jnp.asarray(n), jnp.asarray(table), cfg)
+    assert _changed_rows(f["paged"], paged) == (
+        _owned(table[0], range(13, 13 + n_real))
+        | _owned(table[2], range(6, 10)))
+    for s in (0, 2):
+        _, want, _ = _dense_run(params, cfg,
+                                f["prompts"][s, :int(f["lens"][s])], feed[s])
+        for j in range(n[s]):  # causal within the call: each real row
+            np.testing.assert_allclose(np.asarray(lg)[s, j], want[j],
+                                       rtol=2e-4, atol=2e-4)
+
+
+def test_wide_rows_past_capacity_are_dropped(prefilled):
+    """Speculative rows running past the table's last position store
+    nothing and index nothing out of bounds."""
+    f = prefilled
+    cfg, table = f["cfg"], f["table"]
+    start = np.array([cfg.max_len - 2, 0, 6], np.int32)
+    feed = np.ones((3, 4), np.int32)
+    lg, paged = tfm.decode_step_paged_wide(
+        f["params"], f["paged"], jnp.asarray(feed), jnp.asarray(start),
+        jnp.asarray(np.array([4, 0, 1], np.int32)), jnp.asarray(table),
+        cfg)
+    assert np.all(np.isfinite(np.asarray(lg)))
+    assert _changed_rows(f["paged"], paged) == (
+        _owned(table[0], [cfg.max_len - 2, cfg.max_len - 1])
+        | _owned(table[2], [6]))
+
+
+def test_paged_pages_reused_after_free_hold_the_new_sequence(prefilled):
+    """Slot 0's pages go to a new request in another slot: what it reads
+    is its own prefill, not the residue."""
+    f = prefilled
+    cfg, params = f["cfg"], f["params"]
+    table = np.zeros((3, _W), np.int32)
+    table[1] = f["table"][0][::-1]  # the freed pages, handed out again
+    prompt = np.random.RandomState(13).randint(1, cfg.vocab, (1, 8)).astype(
+        np.int32)
+    lens = np.array([0, 7, 0], np.int32)
+    prompts = np.concatenate([np.zeros_like(prompt), prompt,
+                              np.zeros_like(prompt)])
+    paged, lg0 = tfm.prefill_paged(
+        params, f["paged"], jnp.asarray(prompts), jnp.asarray(lens),
+        jnp.asarray(table), cfg)
+    feed = np.array([3, 5], np.int32)
+    got = []
+    for i, t in enumerate(feed):
+        lg, paged = tfm.decode_step_paged(
+            params, paged, jnp.asarray([0, t, 0], jnp.int32),
+            jnp.asarray(lens + np.array([0, i, 0], np.int32)),
+            jnp.asarray(table), cfg)
+        got.append(np.asarray(lg)[1])
+    want0, want, _ = _dense_run(params, cfg, prompt[0, :7], feed)
+    np.testing.assert_allclose(np.asarray(lg0)[1], want0, rtol=2e-4,
+                               atol=2e-4)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+
+def test_paged_programs_run_the_expert_branch():
+    """n_experts sits in the same loop body: full rows through
+    prefill_paged and one decode_step_paged against the dense cache
+    (same token batch, so the routers' capacity drops agree)."""
+    cfg = _deep_cfg(n_experts=2)
+    params = tfm.init_params(cfg, seed=6)
+    prompts = np.random.RandomState(2).randint(1, cfg.vocab, (2, 8)).astype(
+        np.int32)
+    table = _tables(2, live=(0, 1))
+    lens = np.array([8, 8], np.int32)
+    paged, lg = tfm.prefill_paged(
+        params, _noise_pool(cfg, 2), jnp.asarray(prompts),
+        jnp.asarray(lens), jnp.asarray(table), cfg)
+    cache = tfm.init_kv_cache(cfg, 2, cfg.max_len)
+    cache, want = tfm.prefill(params, cache, jnp.asarray(prompts), cfg)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(want), rtol=2e-4,
+                               atol=2e-4)
+    tok = jnp.asarray([9, 11], jnp.int32)
+    lg, _ = tfm.decode_step_paged(params, paged, tok, jnp.asarray(lens),
+                                  jnp.asarray(table), cfg)
+    want, _ = tfm.decode_step(params, cache, tok, cfg)
+    np.testing.assert_allclose(np.asarray(lg), np.asarray(want), rtol=2e-4,
+                               atol=2e-4)
 
 
 def test_dense_decode_accepts_per_sequence_vector():
