@@ -292,6 +292,44 @@ def _dense_attention(q, k, v, causal):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
 
 
+def _diff_attention_ref(q, pool, table, n_valid, window):
+    """Oracle of pk.paged_diff_attention: q (B, G, 4, D); pool one layer
+    (2G, P, ps, 2D); table (B, W) a growing table (window 0) or a ring."""
+    import jax
+    import jax.numpy as jnp
+
+    B, G, _, D = q.shape
+    ps, W = pool.shape[2], table.shape[1]
+    L = window or W * ps
+    # the L positions before each sequence's end, newest first
+    pos = n_valid[:, None] - 1 - jnp.arange(L)[None]            # (B, L)
+    page = jnp.take_along_axis(table, (jnp.maximum(pos, 0) // ps) % W, 1)
+    rows = pool[:, page, jnp.maximum(pos, 0) % ps]              # (2G, B, L, 2D)
+    rows = rows.reshape(G, 2, B, L, 2 * D)
+    keys = rows[..., :D]                                        # (G, 2, B, L, D)
+    value = jnp.concatenate([rows[:, 0, ..., D:], rows[:, 1, ..., D:]], -1)
+    s = jnp.einsum("bgerd,gebld->bgerl", q.reshape(B, G, 2, 2, D),
+                   keys) / (D ** 0.5)
+    s = jnp.where((pos >= 0)[:, None, None, None], s, -1e30)
+    out = jnp.einsum("bgerl,gblv->bgerv", jax.nn.softmax(s, -1), value)
+    return out.reshape(B, G, 4, 2 * D)
+
+
+def _selective_scan_ref(dt, a, Bm, Cm, A, s0):
+    import jax
+    import jax.numpy as jnp
+
+    def token(s, xs):
+        dt_t, a_t, b_t, c_t = xs                # (S, Di), (S, Di), (S, N) x 2
+        s = (jnp.exp(dt_t[:, None] * A) * s
+             + (dt_t * a_t)[:, None] * b_t[..., None])
+        return s, jnp.sum(s * c_t[..., None], axis=1)
+
+    sT, y = jax.lax.scan(token, s0, tuple(
+        x.swapaxes(0, 1) for x in (dt, a, Bm, Cm)))
+    return y.swapaxes(0, 1), sT
+
+
 def _gather_pages(pool, table):
     """(H, P, ps, D) pool + (B, W) table -> dense (B, W*ps, H, D) cache."""
     g = pool[:, table]                       # (H, B, W, ps, D)
@@ -308,7 +346,7 @@ def phase_kernels(sz):
     ip = sz.interpret
     f32 = jnp.float32
     orc = _Oracle()
-    key = iter(jax.random.split(jax.random.PRNGKey(0), 64))
+    key = iter(jax.random.split(jax.random.PRNGKey(0), 128))
 
     def rand(shape, dtype, scale=1.0):
         return (jax.random.normal(next(key), shape, f32) * scale).astype(dtype)
@@ -429,6 +467,33 @@ def phase_kernels(sz):
             want = pool.at[1, :, page, pos % ps].set(
                 jnp.concatenate([kw, vw], -1), mode="drop")
             orc.close(f"paged_kv_write[{dn},Q={Q}]", got, want, dtype)
+
+        # grouped differential attention (models.sambay): 4 query rows
+        # over each pair of K/V heads, by a growing table and by a ring of
+        # 3 pages under a window of 2 pages, slots far past the ring
+        q4 = rand((B, H // 2, 4, D), dtype)
+        for tag, tbl, depth, win in (
+                ("table", table, nv, 0),
+                ("ring", table[:, :3], nv + 7 * ps, 2 * ps)):
+            orc.close(f"paged_diff_attention[{dn},{tag}]",
+                      kernel(lambda *a: pk.paged_diff_attention(
+                          *a, layer=1, window=win, ring=bool(win),
+                          interpret=ip), q4, pool, tbl, depth),
+                      oracle(_diff_attention_ref, q4.astype(f32),
+                             pool[1].astype(f32), tbl, depth, win), dtype)
+
+    # selective_scan: a prompt's worth of steps from a given state, and
+    # the single step of a decode batch (float32 by contract)
+    Di, N = 2 * sz.d_model, 16
+    for S, T in ((1, 2 * pk.SCAN_TIME_BLOCK), (sz.decode_batch, 1)):
+        dt = jax.nn.softplus(rand((S, T, Di), f32) - 3.0)
+        args = (dt, rand((S, T, Di), f32), rand((S, T, N), f32),
+                rand((S, T, N), f32),
+                -jnp.broadcast_to(jnp.arange(1.0, N + 1)[:, None], (N, Di)),
+                rand((S, N, Di), f32))
+        orc.close(f"selective_scan[S={S},T={T}]",
+                  pk.selective_scan(*args, interpret=ip),
+                  oracle(_selective_scan_ref, *args), f32)
 
     # bn_act_epilogue at ResNet-50's first and last stage, bf16 (no dots)
     for r, c in sz.epilogue_shapes:

@@ -51,6 +51,7 @@ __all__ = [
     "decode_step_paged",
     "prefill_paged",
     "decode_step_paged_wide",
+    "TransformerPrograms",
 ]
 
 
@@ -66,6 +67,10 @@ class TransformerConfig:
     dtype: str = "float32"
     use_flash: bool = False  # Pallas flash-attention kernels for attention
     use_fused_xent: bool = False  # Pallas fused softmax-xent loss kernel
+
+    def paged_programs(self):
+        """What serving.ServingEngine asks of a model: TransformerPrograms."""
+        return TransformerPrograms(self)
 
 
 def init_params(cfg: TransformerConfig, seed: int = 0):
@@ -535,6 +540,57 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
     x = _ln(x, params["ln_f_g"], params["ln_f_b"])
     logits = x @ params["embed"].T
     return logits, paged
+
+
+class TransformerPrograms:
+    """What serving.ServingEngine asks of a model, for this one: its cache
+    (one paged pool for every layer), its three programs over it, the
+    host arrays a prefill takes, and what the host can say of the cache
+    without asking the device. The engine owns slots, queue, page tables
+    and admission, and no shape. models.sambay.SambaYPrograms is the
+    second implementer."""
+
+    # no fixed-size state: every lever's rollback is a page-table write
+    recurrent_state = False
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def init_cache(self, slots, num_pages, page_size):
+        return init_paged_kv_cache(self.cfg, num_pages, page_size)
+
+    def decode(self, params, cache, tokens, positions, table):
+        return decode_step_paged(params, cache, tokens, positions, table,
+                                 self.cfg)
+
+    def prefill(self, params, cache, prompt, true_len, table):
+        return prefill_paged(params, cache, prompt, true_len, table,
+                             self.cfg)
+
+    def wide(self, params, cache, tokens, start, n_real, table):
+        return decode_step_paged_wide(params, cache, tokens, start, n_real,
+                                      table, self.cfg)
+
+    def copy_page(self, cache, src, dst):
+        # pool is (L, H, num_pages, page_size, 2 * Dh): pages are axis 2
+        kv = cache["kv"]
+        return {"kv": kv.at[:, :, dst].set(kv[:, :, src])}
+
+    def prefill_inputs(self, prompt, true_len, row, slot):
+        """Host arrays of one prefill call after params and cache: the
+        padded prompt (1, T_b), its real length, its page-table row."""
+        return prompt, np.asarray([true_len], np.int32), row[None]
+
+    def prefill_shapes(self, bucket, table_width):
+        return [(1, bucket), (1,), (1, table_width)]
+
+    def cache_kinds(self, page_size):
+        return {"paged_kv": {"layers": self.cfg.n_layers, "grows": True}}
+
+    def attended(self, n_valid):
+        """Tokens one decode step attends, by cache kind, summed over the
+        live slots' depths `n_valid` and over the layers that read."""
+        return {"paged_kv": int(np.sum(n_valid)) * self.cfg.n_layers}
 
 
 def _filter_logits(logits, top_k=0, top_p=0.0):

@@ -22,7 +22,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention", "softmax_xent", "flash_decode",
            "dense_decode_attention", "paged_decode_attention",
            "paged_decode_attention_wide", "paged_kv_write",
-           "paged_write_plan",
+           "paged_write_plan", "paged_diff_attention",
+           "paged_ring_write_plan", "selective_scan",
            "bn_act_epilogue",
            "DECODE_BLOCK", "DENSE_FALLBACKS_TOTAL"]
 
@@ -466,9 +467,14 @@ def _online_softmax_update(q, k, v, live, carry, scale):
     k/v (block, d), live (Q, block) bool. Row statistics ride as (Q, 1)
     columns: Mosaic has no layout for the rank-1 (Q,) vectors a plain
     axis reduction would carry through the fori_loop."""
-    o, m, l = carry
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    return _online_softmax_accumulate(s, v, live, carry)
+
+
+def _online_softmax_accumulate(s, v, live, carry):
+    """The update above from the scaled scores s (Q, block) on."""
+    o, m, l = carry
     s = jnp.where(live, s, _NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -1017,3 +1023,231 @@ def paged_decode_attention(q, pool, page_table, n_valid, layer=0,
                                     jnp.maximum(nv - 1, 0), layer,
                                     interpret=interpret)
     return o[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# Grouped differential attention over the paged pool (models.sambay): 2 K/V
+# heads share one grid step with the 4 query heads that read them. Heads
+# 2g and 2g+1 of the pool hold k1_g|v[2g] and k2_g|v[2g+1]; query rows 0-1
+# (q1 of pairs 2g, 2g+1) are scored against k1_g, rows 2-3 (their q2)
+# against k2_g, and all four softmaxes weight the same 128-wide value
+# V_g = [v[2g] | v[2g+1]]: the two rows of a page side by side, (page, 4*D),
+# whose lanes [D, 2D) and [3D, 4D) carry the output (the K lanes ride along
+# as in the kernels above). The walk starts at the first page the window
+# reaches, and with `ring` the table is a ring of pages indexed modulo its
+# width: absolute page a lives in column a % width.
+#
+# One step maps both heads' whole pool, single-buffered: the same VMEM as
+# one head double-buffered, so _check_pool_fits_vmem's limit is unchanged.
+# ---------------------------------------------------------------------------
+
+
+def _paged_diff_kernel(pt_ref, nv_ref, l_ref, q_ref, kv_ref, o_ref, *,
+                       page_size, scale, window, ring, pages_per_step):
+    """One (g, b) grid step: 4 query rows of slot b over its n_valid
+    cached tokens (the query's own included), the last `window` of them
+    when there is a window. pt_ref (B, W), nv_ref (B,), l_ref (1,) are
+    scalar-prefetch refs; kv_ref is (2, num_pages, page_size, 2*d), q_ref
+    (4, d), o_ref (4, 4*d)."""
+    b = pl.program_id(1)
+    q = q_ref[...]
+    n = nv_ref[b]
+    d = q.shape[1]
+    width = pt_ref.shape[1]
+    span = pages_per_step * page_size
+    lo_tok = jnp.maximum(n - window, 0) if window else 0
+    first = lo_tok // page_size
+    last = jnp.maximum(n - 1, 0) // page_size
+    row = jax.lax.broadcasted_iota(jnp.int32, (4, span), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (4, span), 1)
+
+    def body(i, carry):
+        a0 = first + i * pages_per_step
+        k1, k2, vals = [], [], []
+        for c in range(pages_per_step):
+            a = a0 + c
+            page = pt_ref[b, a % width if ring
+                          else jnp.minimum(a, width - 1)]
+            r1, r2 = kv_ref[0, page], kv_ref[1, page]
+            k1.append(r1[:, :d])
+            k2.append(r2[:, :d])
+            vals.append(jnp.concatenate([r1, r2], axis=1))
+        k1, k2, vals = (x[0] if len(x) == 1 else jnp.concatenate(x, axis=0)
+                        for x in (k1, k2, vals))
+        s1, s2 = (jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+                  for k in (k1, k2))
+        idx = a0 * page_size + col
+        live = (idx >= lo_tok) & (idx < n)
+        return _online_softmax_accumulate(
+            jnp.where(row < 2, s1, s2) * scale, vals, live, carry)
+
+    steps = (last - first + pages_per_step) // pages_per_step
+    carry = jax.lax.fori_loop(0, steps, body,
+                              _online_softmax_init(4, o_ref.shape[1]))
+    o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
+
+
+def paged_diff_attention(q, pool, page_table, n_valid, layer=0, *,
+                         window=0, ring=False, pages_per_step=4,
+                         interpret=None):
+    """Single-token grouped differential attention over a paged cache.
+
+    q: (B, G, 4, D) — for K/V group g the query heads that read it, rows
+    0-1 scored against head 2g's keys and rows 2-3 against head 2g+1's;
+    pool: (L, 2*G, num_pages, page_size, 2*D), K|V fused per row, read in
+    layer `layer`; page_table: (B, W) int32; n_valid: (B,) tokens cached
+    per slot INCLUDING the query's own (0: a dead slot, whose output is
+    finite garbage the caller discards).
+    `window` > 0 keeps the last `window` tokens only; `ring` says
+    page_table is a ring (absolute page a in column a % W) instead of a
+    table that grows with the context.
+
+    Returns (B, G, 4, 2*D): each row's softmax over its keys applied to
+    [v[2g] | v[2g+1]], float32."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    B, G, R, D = q.shape
+    H, num_pages, page_size, D2 = pool.shape[1:]
+    if R != 4 or H != 2 * G or D2 != 2 * D:
+        raise ValueError(f"q {q.shape} does not group over pool {pool.shape}")
+    # two heads, one buffer: the bytes of one head double-buffered
+    _check_pool_fits_vmem(num_pages, page_size, D, pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(G, B),
+        in_specs=[
+            pl.BlockSpec((None, None, 4, D),
+                         lambda g, b, *refs: (b, g, 0, 0)),
+            pl.BlockSpec((None, 2, num_pages, page_size, D2),
+                         lambda g, b, pt, nv, l: (l[0], g, 0, 0, 0),
+                         pipeline_mode=pl.Buffered(1)),
+        ],
+        out_specs=pl.BlockSpec((None, None, 4, 2 * D2),
+                               lambda g, b, *refs: (b, g, 0, 0)),
+    )
+    kernel = functools.partial(
+        _paged_diff_kernel, page_size=page_size, scale=1.0 / np.sqrt(D),
+        window=int(window), ring=bool(ring),
+        pages_per_step=int(pages_per_step))
+    o = pl.pallas_call(
+        kernel,
+        # a trace reduction tells the two uses apart by name
+        name="paged_diff_attention_ring" if ring else "paged_diff_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G, 4, 2 * D2), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(jnp.asarray(page_table, jnp.int32), _per_seq_n_valid(n_valid, B),
+      _layer_index(layer), q.astype(pool.dtype), pool)
+    return jnp.concatenate([o[..., D:D2], o[..., D2 + D:]], axis=-1)
+
+
+def paged_ring_write_plan(ring_table, start, n_write, n_rows, page_size):
+    """paged_write_plan for a ring of pages: row t of sequence s sits at
+    position start[s] + t, stored for t < n_write[s], and absolute page a
+    lives in ring_table[s, a % R]. Only the rows the ring can still hold
+    when the call ends are stored: the last min(R, pages a run of n_rows
+    can touch) pages up to the one holding the last stored row. Returns
+    paged_kv_write's plan."""
+    R = ring_table.shape[1]
+    J = min(R, (n_rows + 2 * page_size - 2) // page_size)
+    start = jnp.asarray(start, jnp.int32)
+    end = start + jnp.asarray(n_write, jnp.int32)
+    last = (end - 1) // page_size  # -1 when nothing was ever stored
+    a = last[:, None] - (J - 1) + jnp.arange(J, dtype=jnp.int32)[None]
+    lo = jnp.clip(start[:, None] - a * page_size, 0, page_size)
+    hi = jnp.clip(end[:, None] - a * page_size, 0, page_size)
+    pages = jnp.where(
+        (a >= 0) & (hi > lo),
+        jnp.take_along_axis(jnp.asarray(ring_table, jnp.int32),
+                            jnp.maximum(a, 0) % R, axis=1), 0)
+    src = (jnp.repeat(a, page_size, axis=1) * page_size
+           + jnp.tile(jnp.arange(page_size, dtype=jnp.int32), J)[None]
+           - start[:, None])
+    return pages, lo, hi, jnp.clip(src, 0, n_rows - 1)
+
+
+# ---------------------------------------------------------------------------
+# Selective scan (Mamba-1's recurrence): s_t = exp(dt_t A) * s_{t-1}
+# + (dt_t a_t) B_t^T over a state s (N, channels) per sequence, float32,
+# y_t = s_t^T C_t. The time axis is the grid's last, sequential axis; the
+# state of one block of channels lives in VMEM scratch across it, so a
+# prompt costs its inputs once and no (T, N, channels) array exists. B_t
+# and C_t arrive as (N, 1) columns: the state has the channels on the
+# lanes, and a column broadcasts over them without a transpose.
+# ---------------------------------------------------------------------------
+
+SCAN_CHANNEL_BLOCK = 512
+SCAN_TIME_BLOCK = 128
+
+
+def _selective_scan_kernel(dt_ref, a_ref, b_ref, c_ref, A_ref, s0_ref, y_ref,
+                           sT_ref, s_scr, *, rows):
+    """One (sequence, channel block, time block) grid step. dt, a, y:
+    (bt, bd); b, c: (bt, N, 1); A, s0, sT, s_scr: (N, bd). Tokens go
+    `rows` at a time: one aligned load and store per group."""
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        s_scr[...] = s0_ref[...]
+
+    A = A_ref[...]
+
+    def group(g, s):
+        r0 = pl.multiple_of(g * rows, rows)
+        dt = dt_ref[pl.ds(r0, rows), :]
+        a = a_ref[pl.ds(r0, rows), :]
+        ys = []
+        for i in range(rows):
+            dt_t = dt[i:i + 1, :]                                # (1, bd)
+            s = (jnp.exp(dt_t * A) * s
+                 + (dt_t * a[i:i + 1, :]) * b_ref[r0 + i])       # (N, bd)
+            ys.append(jnp.sum(s * c_ref[r0 + i], axis=0, keepdims=True))
+        y_ref[pl.ds(r0, rows), :] = (ys[0] if rows == 1
+                                     else jnp.concatenate(ys, axis=0))
+        return s
+
+    s = jax.lax.fori_loop(0, dt_ref.shape[0] // rows, group, s_scr[...])
+    s_scr[...] = s
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        sT_ref[...] = s
+
+
+def selective_scan(dt, a, B, C, A, s0, interpret=None):
+    """dt, a: (S, T, Di) float32 (a row with dt = 0 leaves the state as it
+    is); B, C: (S, T, N); A: (N, Di), negative; s0: (S, N, Di) the state
+    before row 0. Returns (y (S, T, Di), the state after row T - 1)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    S, T, Di = dt.shape
+    N = A.shape[0]
+    bd = min(SCAN_CHANNEL_BLOCK, Di)
+    bt = SCAN_TIME_BLOCK if T % SCAN_TIME_BLOCK == 0 else T
+    if Di % bd:
+        raise ValueError(f"{Di} channels do not split into blocks of {bd}")
+    f32 = jnp.float32
+    rows_spec = pl.BlockSpec((None, bt, bd), lambda s, d, j: (s, j, d))
+    col_spec = pl.BlockSpec((None, bt, N, 1), lambda s, d, j: (s, j, 0, 0))
+    state_spec = pl.BlockSpec((None, N, bd), lambda s, d, j: (s, 0, d))
+    y, sT = pl.pallas_call(
+        functools.partial(_selective_scan_kernel,
+                          rows=8 if bt % 8 == 0 else 1),
+        name="selective_scan",
+        grid=(S, Di // bd, T // bt),
+        in_specs=[rows_spec, rows_spec, col_spec, col_spec,
+                  pl.BlockSpec((N, bd), lambda s, d, j: (0, d)), state_spec],
+        out_specs=[rows_spec, state_spec],
+        out_shape=[jax.ShapeDtypeStruct((S, T, Di), f32),
+                   jax.ShapeDtypeStruct((S, N, Di), f32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(dt.astype(f32), a.astype(f32), B.astype(f32)[..., None],
+      C.astype(f32)[..., None], A.astype(f32), s0.astype(f32))
+    return y, sT

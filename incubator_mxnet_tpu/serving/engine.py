@@ -59,7 +59,6 @@ import jax.numpy as jnp
 
 from .. import compile_cache, config, telemetry
 from ..analysis import sanitizers as _sanitizers
-from ..models import transformer as _tfm
 from ..telemetry import compilereg
 from ..telemetry import distributed as _dtrace
 from ..telemetry import exporters as _exporters
@@ -226,8 +225,11 @@ class ServingEngine:
         # shadow-state refcount checker (None unless MXTPU_SANITIZERS
         # lists "pages"); run() proves quiescence at drain through it
         self._page_san = _sanitizers.attach_page_sanitizer(self.allocator)
-        self.paged = _tfm.init_paged_kv_cache(cfg, num_pages,
-                                              self.page_size)
+        # the seam: the model brings its cache (pools by layer kind,
+        # per-slot state) and its programs; the engine owns no shape
+        self.model = cfg.paged_programs()
+        self.paged = self.model.init_cache(self.slots, num_pages,
+                                           self.page_size)
         self.prefill_buckets = _default_buckets(self.max_len)
         self._clock = clock
         # explicit timeline lane for this engine's trace records (fleet
@@ -252,6 +254,19 @@ class ServingEngine:
             spec_ngram = int(config.get("MXTPU_SPEC_NGRAM"))
         if spec_lookahead is None:
             spec_lookahead = int(config.get("MXTPU_SPEC_LOOKAHEAD"))
+        if self.model.recurrent_state:
+            levers = [name for name, on in (
+                ("prefix_cache", prefix_cache),
+                ("prefill_chunk", prefill_chunk),
+                ("spec_ngram", spec_ngram)) if on]
+            if levers:
+                raise ValueError(
+                    f"{', '.join(levers)} cannot serve "
+                    f"{type(cfg).__name__}: its layers carry fixed-size "
+                    f"recurrent state per slot, and the engine has no "
+                    f"state snapshots to resume a cached prefix from, to "
+                    f"hand a prompt on between chunks, or to roll "
+                    f"rejected speculation back out of")
         self.prefill_chunk = max(0, min(int(prefill_chunk), self.max_len))
         self.spec_ngram = max(0, int(spec_ngram))
         self.spec_lookahead = max(1, int(spec_lookahead))
@@ -295,6 +310,9 @@ class ServingEngine:
         self._tokens = {"prefill": 0, "decode": 0, "pad": 0,
                         "spec_rejected": 0}
         self._wasted_evicted = 0
+        # tokens the decode steps attended, by the model's kinds of cache
+        # (what a kernel's least bytes are worked out from)
+        self._attended = dict.fromkeys(self.model.attended(()), 0)
         # lever counters (host source of truth; mirrored to telemetry)
         self._prefix_lookups = 0
         self._prefix_hits = 0
@@ -344,25 +362,26 @@ class ServingEngine:
 
     # -- jitted programs ---------------------------------------------------
 
+    # self.cfg, not self.model: the programs are traced from the
+    # configuration alone (a stand-in self with only `cfg` compiles them)
+
     def _decode_fn(self, params, paged, tokens, positions, table):
-        logits, paged = _tfm.decode_step_paged(
-            params, paged, tokens, positions, table, self.cfg)
+        logits, paged = self.cfg.paged_programs().decode(
+            params, paged, tokens, positions, table)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), paged
 
-    def _prefill_fn(self, params, paged, prompt, true_len, table):
-        paged, logits = _tfm.prefill_paged(
-            params, paged, prompt, true_len, table, self.cfg)
+    def _prefill_fn(self, params, paged, *inputs):
+        paged, logits = self.cfg.paged_programs().prefill(
+            params, paged, *inputs)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), paged
 
     def _wide_fn(self, params, paged, tokens, start, n_real, table):
-        logits, paged = _tfm.decode_step_paged_wide(
-            params, paged, tokens, start, n_real, table, self.cfg)
+        logits, paged = self.cfg.paged_programs().wide(
+            params, paged, tokens, start, n_real, table)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), paged
 
     def _copy_fn(self, paged, src, dst):
-        # pool is (L, H, num_pages, page_size, 2 * Dh): pages are axis 2
-        kv = paged["kv"]
-        return {"kv": kv.at[:, :, dst].set(kv[:, :, src])}
+        return self.cfg.paged_programs().copy_page(paged, src, dst)
 
     def _wide(self, n_q):
         """Wide-query program for `n_q` rows per slot — one named site
@@ -555,9 +574,8 @@ class ServingEngine:
             if getattr(fn, "is_cached", False):
                 out[f"serving_prefill_b{T_b}"] = fn.warm(
                     a(self.params), a(self.paged),
-                    jax.ShapeDtypeStruct((1, T_b), i32),
-                    jax.ShapeDtypeStruct((1,), i32),
-                    jax.ShapeDtypeStruct((1, W), i32))
+                    *(jax.ShapeDtypeStruct(shape, i32)
+                      for shape in self.model.prefill_shapes(T_b, W)))
         # lever programs: exactly the wide widths the enabled knobs
         # will call, plus the page-copy program when caching is on
         wide_qs = set()
@@ -651,16 +669,15 @@ class ServingEngine:
         # the fetch is where the prefill's device time is waited for
         with telemetry.span(
                 "serving.prefill", request=req.request_id, bucket=T_b,
-                prompt_len=T_p,
+                prompt_len=T_p, slot=slot,
                 queue_wait_us=int(1e6 * (req.admitted_at
                                          - req.submitted_at))):
             with self._h2d:
-                prompt, true_len, table = (
-                    jnp.asarray(prompt), jnp.asarray([T_p], np.int32),
-                    jnp.asarray(row[None]))
+                inputs = [jnp.asarray(a) for a in
+                          self.model.prefill_inputs(prompt, T_p, row, slot)]
             with self._dispatch:
                 tok, self.paged = self._prefills[T_b](
-                    self.params, self.paged, prompt, true_len, table)
+                    self.params, self.paged, *inputs)
             with self._fetch:
                 first = int(np.asarray(tok)[0])
         clk_first = self._clock()
@@ -1089,6 +1106,9 @@ class ServingEngine:
                     self._slot_req[s].request_id,
                     [self._slot_pages[s][int(self._positions[s])
                                          // self.page_size]])
+        depths = self._positions[live_slots] + 1
+        for kind, n in self.model.attended(depths).items():
+            self._attended[kind] += n
         with self._h2d:
             args = (jnp.asarray(self._next_tok),
                     jnp.asarray(self._positions), jnp.asarray(self._tables))
@@ -1280,6 +1300,23 @@ class ServingEngine:
             "fraction": useful / processed if processed else 1.0,
         }
 
+    def cache_stats(self):
+        """The cache by kind, from the host's books (source of truth
+        beside goodput()): the growing pool's pages live (holding a live
+        slot's context now) and reserved (handed out at admission), what
+        the model keeps per slot beside it (ring pages, state bytes), and
+        the tokens the decode steps have attended so far by kind, summed
+        over the layers that read them."""
+        live = [self.allocator.pages_needed(int(self._positions[s]))
+                for s in self._decoding_slots()]
+        return {
+            "pool": {"pages_live": sum(live),
+                     "pages_reserved": self.allocator.num_in_use,
+                     "capacity": self.allocator.capacity},
+            "kinds": self.model.cache_kinds(self.page_size),
+            "attended_tokens": dict(self._attended),
+        }
+
     @property
     def prefix_hit_rate(self):
         """Fraction of admissions that mapped at least one cached page
@@ -1395,6 +1432,7 @@ class ServingEngine:
                 "occupancy": self.allocator.occupancy(),
                 "fragmentation": self.allocator.fragmentation(),
             },
+            "cache": self.cache_stats(),
             "prefix_cache": prefix_rows,
             "speculation": spec_rows,
             "chunked_prefill": chunk_rows,

@@ -38,8 +38,9 @@ def test_serve_phase(clock):
 def test_kernels_phase_compares_every_kernel(clock):
     out = cs.run_phase("kernels", clock, cs.phase_kernels, TINY)
     # per dtype: 4 flash_attention, 2 xent, flash_decode, paged, one wide
-    # and the write its rows take
-    assert out["compared"] == 2 * 10 + 4
+    # and the write its rows take, the grouped differential kernel by a
+    # table and by a ring; then 2 selective scans and 4 epilogues
+    assert out["compared"] == 2 * 12 + 2 + 4
 
 
 def test_a_kernel_off_its_oracle_fails_the_phase(clock, monkeypatch):

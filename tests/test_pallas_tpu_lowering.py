@@ -88,6 +88,26 @@ def _cases():
                 f"paged_kv_write-{dn}-Q{q}",
                 functools.partial(_write, q),
                 pool + [((SLOTS, q, H, D), dt)] * 2 + tail + tail[1:]))
+    # grouped differential attention (models.sambay) at the benchmark's
+    # geometry: 16 slots, 10 K/V pairs of 64, a shared pool of 7169 pages
+    # (the VMEM limit's reach) and the window layers' rings of 33
+    for name, pool, width, kw in (
+            ("shared", (1, 20, 7169, 16, 128), 448, {}),
+            ("ring", (8, 20, 529, 16, 128), 33,
+             {"window": 512, "ring": True})):
+        out.append((
+            f"paged_diff_attention-{name}",
+            functools.partial(pk.paged_diff_attention, layer=0,
+                              interpret=False, **kw),
+            [((16, 10, 4, 64), jnp.float32), (pool, jnp.bfloat16),
+             ((16, width), I32), ((16,), I32)]))
+    for S_, T_ in ((1, 4096), (16, 1)):  # a prompt bucket; a decode batch
+        out.append((
+            f"selective_scan-S{S_}-T{T_}",
+            functools.partial(pk.selective_scan, interpret=False),
+            [((S_, T_, 5120), jnp.float32)] * 2
+            + [((S_, T_, 16), jnp.float32)] * 2
+            + [((16, 5120), jnp.float32), ((S_, 16, 5120), jnp.float32)]))
     epi = functools.partial(pk.bn_act_epilogue, interpret=False)
     for r, c in SZ.epilogue_shapes:
         plain = [((r, c), jnp.bfloat16), ((c,), jnp.float32),
@@ -108,7 +128,8 @@ IDS = [c[0] for c in CASES]
 def test_every_public_kernel_has_a_case():
     kernels = {n for n in pk.__all__
                if callable(getattr(pk, n))
-               and n not in ("dense_decode_attention", "paged_write_plan")}
+               and n not in ("dense_decode_attention", "paged_write_plan",
+                             "paged_ring_write_plan")}
     covered = {i.split("-")[0] for i in IDS}
     assert kernels == covered, kernels ^ covered
 
