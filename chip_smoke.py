@@ -429,7 +429,9 @@ def phase_kernels(sz):
 
         # paged kernels at the engine's default geometry: one pool for
         # every layer, K|V fused per row, read and written by layer index
-        # (layer 1 of 2 here; layer 0 is noise nothing may touch)
+        # (layer 1 of 2 here; layer 0 is noise nothing may touch). The
+        # decode kernels gather each slot's pages from the pool in HBM,
+        # a 128-token block of a shuffled table at a time
         ps, W = sz.page_size, sz.table_width
         P = B * W + 1
         kp, vp = rand((H, P, ps, D), dtype), rand((H, P, ps, D), dtype)

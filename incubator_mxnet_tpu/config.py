@@ -444,9 +444,11 @@ register_knob("MXTPU_SPARSE_NNZ_BUCKETING", False, bool,
 register_knob("MXTPU_PAGE_SIZE", 16, int,
               "Tokens per KV-cache page in the paged decode pool "
               "(serving/pages.py). Smaller pages waste less capacity on "
-              "the last partial page per sequence but deepen the "
-              "page-table walk in paged_decode_attention; must keep the "
-              "page a TPU-friendly block (multiples of 8 recommended).")
+              "the last partial page per sequence; paged_decode_attention "
+              "reads 128 tokens' pages per loop step whatever the size "
+              "(one DMA per page, so smaller pages cost more of them); "
+              "a size that divides 128 fills the step; keep the page a "
+              "TPU-friendly block (16 for bf16, multiples of 8).")
 register_knob("MXTPU_DECODE_SLOTS", 8, int,
               "Fixed number of decode slots in the continuous-batching "
               "engine — the static batch dimension of every paged decode "

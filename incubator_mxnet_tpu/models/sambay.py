@@ -621,3 +621,8 @@ class SambaYPrograms:
         return {"shared_kv": int(n_valid.sum()) * (1 + cfg.n_cross_pairs),
                 "window_kv": int(np.minimum(n_valid, cfg.window).sum())
                 * cfg.n_self_pairs}
+
+    def fetched(self, n_valid, page_size):
+        """Nothing to tell: paged_diff_attention maps a pair of heads'
+        whole pool per grid step and gathers no block."""
+        return {}

@@ -592,6 +592,16 @@ class TransformerPrograms:
         live slots' depths `n_valid` and over the layers that read."""
         return {"paged_kv": int(np.sum(n_valid)) * self.cfg.n_layers}
 
+    def fetched(self, n_valid, page_size):
+        """Tokens one decode step's kernels fetch to attend those, by
+        cache kind: paged_decode_attention gathers a slot's pages a
+        block at a time and masks the last block's tail."""
+        from ..ops.pallas_kernels import paged_block_tokens
+
+        block = paged_block_tokens(page_size)
+        blocks = -(-np.asarray(n_valid, np.int64) // block)
+        return {"paged_kv": int(blocks.sum()) * block * self.cfg.n_layers}
+
 
 def _filter_logits(logits, top_k=0, top_p=0.0):
     """Standard sampling filters, static-shape (jit-safe): top_k keeps the

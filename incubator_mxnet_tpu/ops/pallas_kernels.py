@@ -760,8 +760,8 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
 # and it never moves: the layer loop carries it, paged_kv_write stores a
 # step's new rows into layer l in place (input_output_aliases), and the
 # attention kernels read layer l where it lies, by a scalar-prefetched layer
-# index in the block's index map. Nothing is sliced, copied or re-laid-out
-# per layer.
+# index (in the DMA's source slice, or in a block's index map). Nothing is
+# sliced, copied or re-laid-out per layer.
 #
 # A row holds a token's K in lanes [0, D) and its V in lanes [D, 2*D). With
 # D = 64 that is one full 128-lane row, so the row-major tiled layout Mosaic
@@ -774,17 +774,25 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
 # and never cut V out: p . row carries the attention output in its V lanes,
 # and the caller drops the K lanes of the result.
 #
-# Head-major: the block one grid step maps is one head's whole pool in one
-# layer, (num_pages, page_size, 2*D), whose last two dimensions are whole,
-# the only shape the TPU lowering accepts for a per-head slice (a head axis
-# squeezed in the second-minor position is refused). The grid is (H, B) with
-# the head outermost so the pool block is fetched once per head, not once
-# per (head, sequence).
+# Head-major: a page of one head is (page_size, 2*D) with its last two
+# dimensions whole, the only shape the TPU lowering accepts for a per-head
+# slice (a head axis squeezed in the second-minor position is refused), and
+# a page of a RUN of heads, pool[l, h0:h0+Hg, page], is Hg such runs of
+# whole rows: one strided DMA.
+#
+# paged_decode_attention(_wide) leaves the pool in HBM and walks a slot's
+# table in BLOCKS (_paged_decode_kernel): one grid step per slot, all its
+# heads at once; a block of 128 tokens' pages gathered into VMEM by one copy
+# per page, double-buffered; one loop step per block for every head. Only
+# the slot's live pages move, and the pool may be of any size.
+# paged_diff_attention (models.sambay) still maps a pair of heads' whole
+# pool into VMEM per grid step, and is held to PAGED_VMEM_LIMIT_BYTES.
 # ---------------------------------------------------------------------------
 
-# Scoped VMEM the paged kernels ask Mosaic for. One head's pool block,
-# double-buffered by the pipeline, must fit under it; a pool that does not
-# is an error (_check_pool_fits_vmem), never a dense fallback.
+# Scoped VMEM paged_diff_attention asks Mosaic for. Two heads' pool block,
+# single-buffered (the bytes of one head double-buffered), must fit under
+# it; a pool that does not is an error (_check_pool_fits_vmem), never a
+# dense fallback.
 PAGED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _PAGED_VMEM_RESERVE_BYTES = 4 * 1024 * 1024  # q/o blocks, carries, spills
 
@@ -808,8 +816,8 @@ def _check_pool_fits_vmem(num_pages, page_size, head_dim, dtype):
         raise ValueError(
             f"paged KV pool of {num_pages} pages (page_size={page_size}, "
             f"head_dim={head_dim}, {jnp.dtype(dtype).name}) needs "
-            f"{need} bytes of VMEM per head; the paged decode kernels map "
-            f"one head's whole pool into VMEM and fit at most "
+            f"{need} bytes of VMEM per head; paged_diff_attention maps "
+            f"a head's whole pool into VMEM and fits at most "
             f"{budget // per_page} such pages ({budget} bytes)")
 
 
@@ -911,38 +919,165 @@ def paged_kv_write(pool, layer, k, v, plan, interpret=None):
     )(_layer_index(layer), pages, lo, hi, rows, pool)
 
 
-def _paged_decode_wide_kernel(pt_ref, nb_ref, l_ref, q_ref, kv_ref, o_ref, *,
-                              page_size, scale):
-    """One (h, b) grid step with Q query rows at consecutive positions:
-    row i sits at position nb + i and attends idx < nb + i + 1 — the
-    paged prefix plus causal masking WITHIN the call. pt_ref (B, P_max),
-    nb_ref (B,) and l_ref (1,) are scalar-prefetch refs (SMEM — readable
-    for control flow, dynamic page indices and the block index maps);
-    kv_ref sees the whole pool of head h in layer l as
-    (num_pages, page_size, 2*d), K|V per row; q_ref is (Q, d); o_ref is
-    (Q, 2*d) and its V lanes are the output."""
-    b = pl.program_id(1)
+# Tokens one loop step of the paged decode walk covers: the lane width, so a
+# step's scores are full 128-lane rows. A block is this many tokens' pages,
+# consecutive page-table entries of one slot (8 pages of 16); a page at
+# least this long is a block by itself.
+PAGED_BLOCK_TOKENS = 128
+
+# VMEM one grid step of the walk may take, half of what Mosaic scopes a
+# kernel by default. Every head of a slot rides in one grid step while they
+# fit (25 heads of 64 in bf16 with one query row: 1.7 MB); beyond it the
+# heads go in equal groups, each a grid step of its own.
+_PAGED_BLOCK_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def paged_block_tokens(page_size):
+    """Tokens the paged decode kernel fetches per block, whole pages: what
+    a slot's depth is rounded up to when the rows it reads are counted."""
+    return max(PAGED_BLOCK_TOKENS // page_size, 1) * page_size
+
+
+def _paged_head_group(n_heads, n_q, block_tokens, row_lanes, dtype):
+    """Heads per grid step: the largest divisor of n_heads whose two block
+    buffers and float32 working rows (scores, probabilities and the
+    accumulator, in and out, of n_q query rows) fit
+    _PAGED_BLOCK_VMEM_BYTES."""
+    per_head = (2 * block_tokens * row_lanes * jnp.dtype(dtype).itemsize
+                + 4 * n_q * 3 * (block_tokens + row_lanes))
+    fit = max(_PAGED_BLOCK_VMEM_BYTES // per_head, 1)
+    return max(g for g in range(1, min(n_heads, fit) + 1)
+               if n_heads % g == 0)
+
+
+def _paged_decode_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref,
+                         buf, sem, *, page_size, scale):
+    """One (b, g) grid step: slot b, head group g, Q query rows at
+    consecutive positions whose LAST row attends nv tokens (row i attends
+    idx < nv - (Q - 1 - i): the paged prefix plus causal masking within
+    the call; nv == 0 is a dead slot, which fetches and computes nothing).
+
+    pt_ref (B, W), nv_ref (B,) and l_ref (1,) are scalar-prefetch refs
+    (SMEM: control flow and the DMA's page indices). pool_ref is the whole
+    pool where it lies in HBM, (L, H, num_pages, page_size, 2*d), K|V per
+    row. q_ref is (Hg, Q, d); o_ref (Hg, Q, 2*d), whose V lanes are the
+    output. buf (2, Hg, block, 2*d) and sem (2,) are the two block buffers
+    and their DMA semaphores.
+
+    The walk goes block by block: a block is `pages` consecutive table
+    entries, gathered by one strided copy per page that covers all Hg
+    heads (pool[l, h0:h0+Hg, page] is (Hg, page_size, 2*d) with whole
+    rows: Hg runs of one page each), block j + 1 in flight while block j
+    is computed. One loop step scores all Hg heads against the block's
+    128 K rows in one batched product, updates the online softmax over
+    full-lane rows, and takes p . rows in another: Hg independent chains
+    per step, so the MXUs and the DMA overlap across heads."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    hg, n_q, d = q_ref.shape
+    width = pt_ref.shape[1]
+    pages = buf.shape[2] // page_size
+    span = pages * page_size
+    layer, h0 = l_ref[0], g * hg
+    # positions past the table's capacity hold nothing (speculative
+    # overrun; those rows' outputs are discarded): they are never live,
+    # and the table is never indexed out of bounds
+    cap = width * page_size
+    n_pages = (jnp.minimum(nv_ref[b], cap) + page_size - 1) // page_size
+    n_blocks = (n_pages + pages - 1) // pages
+
+    def copies(j, slot):
+        # entries past the slot's last live page are not trusted: the
+        # block's tail is the null page 0, finite and masked
+        out = []
+        for c in range(pages):
+            col = j * pages + c
+            page = jnp.where(col < n_pages,
+                             pt_ref[b, jnp.minimum(col, width - 1)], 0)
+            out.append(pltpu.make_async_copy(
+                pool_ref.at[layer, pl.ds(h0, hg), page],
+                buf.at[slot, :, pl.ds(c * page_size, page_size)],
+                sem.at[slot]))
+        return out
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
     q = q_ref[...]
-    nb = nb_ref[b]
-    n_q, d = q.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (n_q, page_size), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 2)
+    limit = jnp.minimum(nv_ref[b] - (n_q - 1) + row, cap)
 
     def body(j, carry):
-        page = pt_ref[b, j]
-        live = j * page_size + col < nb + row + 1
-        return _online_softmax_update(q, kv_ref[page, :, :d], kv_ref[page],
-                                      live, carry, scale)
+        slot = j % 2
 
-    # the deepest row attends nb + Q tokens; clamp the walk to the table
-    # width so speculative rows past a sequence's last owned page never
-    # index the table out of bounds (their outputs are discarded). Dead
-    # slots (nb == 0 with Q == 1) walk one page of the null page.
-    num_pages = jnp.minimum((nb + n_q + page_size - 1) // page_size,
-                            pt_ref.shape[1])
-    carry = jax.lax.fori_loop(0, num_pages, body,
-                              _online_softmax_init(n_q, o_ref.shape[1]))
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        rows = buf[slot]  # (Hg, span, 2*d)
+        s = jax.lax.dot_general(
+            q, rows[:, :, :d], (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (Hg, Q, span)
+        o, m, l = carry
+        s = jnp.where(j * span + col < limit, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=2, keepdims=True)
+        o_new = o * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return o_new, m_new, l_new
+
+    carry = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.zeros(o_ref.shape, jnp.float32),
+         jnp.full((hg, n_q, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((hg, n_q, 1), jnp.float32)))
     o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
+
+
+def _paged_decode_call(q, pool, page_table, n_last, layer, interpret):
+    """q (B, Q, H, D) over pool (L, H, num_pages, page_size, 2*D) in layer
+    `layer`; n_last (B,): tokens the last query row of each slot attends.
+    Returns (B, Q, H, D)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    B, Q, H, D = q.shape
+    page_size, D2 = pool.shape[3:]
+    span = paged_block_tokens(page_size)
+    hg = _paged_head_group(H, Q, span, D2, pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, H // hg),
+        in_specs=[
+            pl.BlockSpec((None, hg, Q, D),
+                         lambda b, g, *refs: (b, g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((None, hg, Q, D2),
+                               lambda b, g, *refs: (b, g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, hg, span, D2), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    o = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, page_size=page_size,
+                          scale=1.0 / np.sqrt(D)),
+        # the name a trace reduction finds the kernel by: the engine's
+        # decode step is the Q = 1 case
+        name=("paged_decode_attention" if Q == 1
+              else "paged_decode_attention_wide"),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Q, D2), q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(page_table, jnp.int32), _per_seq_n_valid(n_last, B),
+      _layer_index(layer), q.transpose(0, 2, 1, 3), pool)
+    return o[..., D:].transpose(0, 2, 1, 3)  # the V lanes
 
 
 def paged_decode_attention_wide(q, pool, page_table, n_base, layer=0,
@@ -953,76 +1088,45 @@ def paged_decode_attention_wide(q, pool, page_table, n_base, layer=0,
     q: (B, Q, H, D) — query i of sequence b sits at position
     n_base[b] + i; pool: (L, H, num_pages, page_size, 2*D), the whole
     pool (paged_kv_write has already stored the Q new tokens' rows in
-    layer `layer`); layer: int32 scalar, the layer to read — the block's
-    index map takes it, nothing is sliced out of the pool; page_table:
+    layer `layer`); layer: int32 scalar, the layer to read — the kernel's
+    copies take it, nothing is sliced out of the pool; page_table:
     (B, P_max) int32 — page ids owned by each sequence, in order
-    (entries past the live length are ignored); n_base: (B,) int32 —
+    (entries past the live length are ignored; a row that starts at the
+    null page 0 owns nothing: a dead slot, which costs no copy and no
+    loop step and reads as zeros); n_base: (B,) int32 —
     tokens cached per sequence BEFORE this call's first query. Query i
     attends positions < n_base + i + 1 (paged prefix + intra-call
     causal), so a single launch serves chunked prefill (Q = chunk),
     cached-prefix tail prefill (n_base = cached tokens) and speculative
     verification (Q = lookahead + 1) — the vLLM/Sarathi "one kernel,
-    many query widths" trick on the repo's own page walk.
+    many query widths" trick on the repo's own block walk.
 
-    Each grid step streams only ceil((n_base+Q)/page_size) pages of its
-    own sequence out of the VMEM-resident head pool. Raises ValueError
-    when one head's pool does not fit PAGED_VMEM_LIMIT_BYTES.
+    Each grid step gathers only its own sequence's live pages out of the
+    pool in HBM, a block of paged_block_tokens(page_size) tokens at a
+    time; the pool's size is no concern of the kernel's.
 
     Returns (B, Q, H, D)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    B, Q, H, D = q.shape
-    num_pages, page_size, D2 = pool.shape[2:]
-    _check_pool_fits_vmem(num_pages, page_size, D, pool.dtype)
-    nb = _per_seq_n_valid(n_base, B)
-    pt = jnp.asarray(page_table, jnp.int32)
-    qr = q.transpose(0, 2, 1, 3)  # (B, H, Q, D)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(H, B),
-        in_specs=[
-            pl.BlockSpec((None, None, Q, D),
-                         lambda h, b, *refs: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, num_pages, page_size, D2),
-                         lambda h, b, pt, nb, l: (l[0], h, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, Q, D2),
-                               lambda h, b, *refs: (b, h, 0, 0)),
-    )
-    kernel = functools.partial(_paged_decode_wide_kernel,
-                               page_size=page_size,
-                               scale=1.0 / np.sqrt(D))
-    o = pl.pallas_call(
-        kernel,
-        # the name a trace reduction finds the kernel by: the engine's
-        # decode step is the Q = 1 case
-        name=("paged_decode_attention" if Q == 1
-              else "paged_decode_attention_wide"),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Q, D2), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(pt, nb, _layer_index(layer), qr, pool)
-    return o[..., D:].transpose(0, 2, 1, 3)  # the V lanes
+    nb = _per_seq_n_valid(n_base, q.shape[0])
+    live = jnp.asarray(page_table, jnp.int32)[:, 0] != 0
+    return _paged_decode_call(q, pool, page_table,
+                              jnp.where(live, nb + q.shape[1], 0), layer,
+                              interpret)
 
 
 def paged_decode_attention(q, pool, page_table, n_valid, layer=0,
                            interpret=None):
-    """Single-query attention over a paged KV cache — the Q = 1 case of
-    paged_decode_attention_wide.
+    """Single-query attention over a paged KV cache — the kernel above
+    with Q = 1, under the name a trace reduction finds the engine's
+    decode step by.
 
     q: (B, H, D) — one query per decode slot; pool:
     (L, H, num_pages, page_size, 2*D), read in layer `layer`;
     page_table: (B, P_max) int32; n_valid: (B,) int32 (or scalar) —
     tokens live per slot INCLUDING the one just written; 0 marks a dead
-    slot (its output is finite garbage from the null page that the
-    caller discards). Returns (B, H, D)."""
-    nv = _per_seq_n_valid(n_valid, q.shape[0])
-    o = paged_decode_attention_wide(q[:, None], pool, page_table,
-                                    jnp.maximum(nv - 1, 0), layer,
-                                    interpret=interpret)
-    return o[:, 0]
+    slot, which costs no copy and no loop step (its output is zeros that
+    the caller discards). Returns (B, H, D)."""
+    return _paged_decode_call(q[:, None], pool, page_table, n_valid, layer,
+                              interpret)[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,9 @@ one. `pages.PageAllocator` owns the global KV page pool; `engine
 .ServingEngine` runs vLLM/Orca-style iteration-level scheduling: a fixed
 set of decode slots advance one token per step in ONE compiled program
 (`models.transformer.decode_step_paged` over
-`ops.pallas_kernels.paged_decode_attention`), requests admit into free
+`ops.pallas_kernels.paged_decode_attention`, which gathers each slot's
+live pages from the pool in HBM a 128-token block at a time, every head
+in one loop step), requests admit into free
 slots with bucketed prefill and evict on EOS/length with immediate page
 recycling. Every shape is static, so the steady state performs zero
 retraces — gated by telemetry.compilereg and warmed by compile_cache.

@@ -313,6 +313,9 @@ class ServingEngine:
         # tokens the decode steps attended, by the model's kinds of cache
         # (what a kernel's least bytes are worked out from)
         self._attended = dict.fromkeys(self.model.attended(()), 0)
+        # and the tokens they fetched for it: whole blocks, tails masked
+        self._fetched = dict.fromkeys(
+            self.model.fetched((), self.page_size), 0)
         # lever counters (host source of truth; mirrored to telemetry)
         self._prefix_lookups = 0
         self._prefix_hits = 0
@@ -1109,6 +1112,8 @@ class ServingEngine:
         depths = self._positions[live_slots] + 1
         for kind, n in self.model.attended(depths).items():
             self._attended[kind] += n
+        for kind, n in self.model.fetched(depths, self.page_size).items():
+            self._fetched[kind] += n
         with self._h2d:
             args = (jnp.asarray(self._next_tok),
                     jnp.asarray(self._positions), jnp.asarray(self._tables))
@@ -1306,7 +1311,9 @@ class ServingEngine:
         slot's context now) and reserved (handed out at admission), what
         the model keeps per slot beside it (ring pages, state bytes), and
         the tokens the decode steps have attended so far by kind, summed
-        over the layers that read them."""
+        over the layers that read them, beside the tokens their kernels
+        fetched to attend those (a block is fetched whole and its tail
+        masked: attended / fetched is the fill share)."""
         live = [self.allocator.pages_needed(int(self._positions[s]))
                 for s in self._decoding_slots()]
         return {
@@ -1315,6 +1322,7 @@ class ServingEngine:
                      "capacity": self.allocator.capacity},
             "kinds": self.model.cache_kinds(self.page_size),
             "attended_tokens": dict(self._attended),
+            "fetched_tokens": dict(self._fetched),
         }
 
     @property

@@ -88,6 +88,20 @@ def _cases():
                 f"paged_kv_write-{dn}-Q{q}",
                 functools.partial(_write, q),
                 pool + [((SLOTS, q, H, D), dt)] * 2 + tail + tail[1:]))
+    # the block walk at the benchmark's geometry (gpt2_xl: 16 slots, 25
+    # heads of 64, 1025 pages of 16, a table 64 wide, bf16 pool, float32
+    # queries), the layer index traced as the layer loop passes it; and
+    # over a pool no VMEM could map, 8193 pages a head
+    for name, q_rows, n_pages in (("xl-Q1", 1, 1025), ("xl-Q8", 8, 1025),
+                                  ("xl-8193pages-Q1", 1, 8193)):
+        out.append((
+            f"paged_decode_attention_wide-{name}",
+            lambda q, pool, table, n_base, layer: (
+                pk.paged_decode_attention_wide(q, pool, table, n_base,
+                                               layer, interpret=False)),
+            [((16, q_rows, 25, 64), jnp.float32),
+             ((2, 25, n_pages, 16, 128), jnp.bfloat16),
+             ((16, 64), I32), ((16,), I32), ((), I32)]))
     # grouped differential attention (models.sambay) at the benchmark's
     # geometry: 16 slots, 10 K/V pairs of 64, a shared pool of 7169 pages
     # (the VMEM limit's reach) and the window layers' rings of 33

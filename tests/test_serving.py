@@ -123,22 +123,98 @@ def test_paged_decode_wide_matches_dense_per_row(Q):
         np.testing.assert_allclose(got[:, i], want, rtol=2e-5, atol=2e-5)
 
 
+# (page size, table width, heads, VMEM a grid step may take, heads it then
+# takes): the last block cut short by the table; a table narrower than one
+# block; two head groups; a head count no group divides, down to one head
+# a grid step
+_WALKS = {"blocks": (16, 20, 3, None, 3), "narrow": (16, 3, 2, None, 2),
+          "groups": (8, 20, 6, 160 * 1024, 3),
+          "prime": (8, 20, 5, 80 * 1024, 1)}
+
+
+@pytest.mark.parametrize("Q", [1, 5])
+@pytest.mark.parametrize("walk", list(_WALKS))
+def test_paged_decode_walks_blocks(walk, Q, monkeypatch):
+    """The block walk against the dense oracle: depths of nothing (a dead
+    slot), one token, exactly one block, one block and a token, and all
+    the table holds, and past it (the wide rows that overrun are
+    dropped, the rows before them whole); a permuted table, a layer that
+    is not the first."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+
+    ps, W, H, vmem, heads = _WALKS[walk]
+    D, B = 16, 6
+    block = pk.paged_block_tokens(ps)
+    assert block == 128
+    if vmem is not None:
+        monkeypatch.setattr(pk, "_PAGED_BLOCK_VMEM_BYTES", vmem)
+    assert pk._paged_head_group(H, Q, block, 2 * D, jnp.float32) == heads
+    rng = np.random.RandomState(17)
+    P = B * W + 1
+    k_pages = rng.randn(P, ps, H, D).astype(np.float32)
+    v_pages = rng.randn(P, ps, H, D).astype(np.float32)
+    table = (1 + rng.permutation(B * W)).reshape(B, W).astype(np.int32)
+    cap = W * ps
+    # what the LAST query row attends, per slot
+    depth = np.array([0, Q, min(block, cap), min(block + 1, cap - 1), cap,
+                      cap + Q - 2], np.int32)
+    table[0] = 0  # the dead slot owns nothing
+    pool = _pool(k_pages, v_pages, layers=3, layer=2)
+    q = rng.randn(B, Q, H, D).astype(np.float32)
+    if Q == 1:
+        got = np.asarray(paged_decode_attention(
+            jnp.asarray(q[:, 0]), pool, jnp.asarray(table),
+            jnp.asarray(depth), layer=2, interpret=True))[:, None]
+    else:
+        got = np.asarray(paged_decode_attention_wide(
+            jnp.asarray(q), pool, jnp.asarray(table),
+            jnp.asarray(np.maximum(depth - Q, 0)), layer=2, interpret=True))
+    kc, vc = _gather_dense(k_pages, v_pages, table, ps)
+    for i in range(Q):
+        row_depth = depth - (Q - 1 - i)
+        want = np.asarray(dense_decode_attention(
+            jnp.asarray(q[:, i]), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(np.maximum(row_depth, 0))))
+        held = (row_depth > 0) & (row_depth <= cap)
+        assert held[1:5].all()
+        np.testing.assert_allclose(got[held, i], want[held], rtol=2e-5,
+                                   atol=2e-5)
+    assert np.all(np.isfinite(got))
+    # a dead slot costs no copy and no loop step, and reads as zeros
+    assert np.all(got[0] == 0)
+
+
 def test_paged_pool_too_large_for_vmem_raises():
     """A pool whose per-head block cannot sit in VMEM is an error naming
-    the largest pool that fits — never a silent dense fallback."""
+    the largest pool that fits — never a silent dense fallback — for the
+    kernel that maps it there, paged_diff_attention. paged_decode_attention
+    leaves the pool in HBM and takes one of any size."""
     from incubator_mxnet_tpu.ops import pallas_kernels as pk
 
     H, D, ps = 2, 64, 16
     fits = (pk.PAGED_VMEM_LIMIT_BYTES - pk._PAGED_VMEM_RESERVE_BYTES) \
         // (pk.paged_pool_vmem_bytes(1, ps, D, jnp.bfloat16))
+    assert fits == 7680
     pool = jax.ShapeDtypeStruct((1, H, fits + 1, ps, 2 * D), jnp.bfloat16)
-    q = jax.ShapeDtypeStruct((1, H, D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, 1, 4, D), jnp.float32)
     pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
     nv = jax.ShapeDtypeStruct((1,), jnp.int32)
     with pytest.raises(ValueError, match=f"at most {fits} such pages"):
-        jax.eval_shape(paged_decode_attention, q, pool, pt, nv)
+        jax.eval_shape(pk.paged_diff_attention, q, pool, pt, nv)
     ok = jax.ShapeDtypeStruct((1, H, fits, ps, 2 * D), jnp.bfloat16)
-    jax.eval_shape(paged_decode_attention, q, ok, pt, nv)
+    jax.eval_shape(pk.paged_diff_attention, q, ok, pt, nv)
+
+
+def test_paged_decode_takes_a_pool_past_the_vmem_limit():
+    """8 x 7680 pages a head: the walk gathers a slot's pages from HBM, so
+    the pool's size is no concern of the kernel's."""
+    H, D, ps = 2, 64, 16
+    pool = jax.ShapeDtypeStruct((1, H, 8 * 7680, ps, 2 * D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, H, D), jnp.bfloat16)
+    pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
+    nv = jax.ShapeDtypeStruct((1,), jnp.int32)
+    out = jax.eval_shape(paged_decode_attention, q, pool, pt, nv)
+    assert out.shape == (1, H, D)
 
 
 # -- the carried pool: paged programs against the dense cache ------------------
@@ -925,6 +1001,33 @@ def test_engine_debug_snapshot_v2_lever_sections():
     chunked = snap["chunked_prefill"]
     assert chunked["chunk"] == 4 and chunked["chunks_total"] > 0
     assert snap["tokens"]["spec_rejected"] >= 0
+
+
+def test_cache_stats_count_fetched_tokens_by_the_kernels_block():
+    """A decode step attends a slot's depth and fetches it in whole
+    blocks of the kernel's own size: the fill share /debug/engine shows."""
+    from incubator_mxnet_tpu.ops.pallas_kernels import paged_block_tokens
+
+    cfg = _small_cfg(max_len=192)
+    eng = ServingEngine(tfm.init_params(cfg, seed=3), cfg, slots=2,
+                        page_size=8)
+    block = paged_block_tokens(eng.page_size)
+    assert block == 128
+    asked = [(120, 12), (5, 6)]  # the first crosses into a second block
+    rng = np.random.RandomState(4)
+    for n, new in asked:
+        eng.submit(rng.randint(1, cfg.vocab, n).astype(np.int32), new)
+    eng.run()
+    depths = [d for n, new in asked for d in range(n + 1, n + new)]
+    stats = eng.cache_stats()
+    assert stats["attended_tokens"] == {
+        "paged_kv": cfg.n_layers * sum(depths)}
+    assert stats["fetched_tokens"] == {
+        "paged_kv": cfg.n_layers * sum(-(-d // block) * block
+                                       for d in depths)}
+    assert max(depths) > block
+    assert eng.debug_snapshot()["cache"]["fetched_tokens"] == (
+        stats["fetched_tokens"])
 
 
 # -- cancel/eviction race hardening ------------------------------------------
