@@ -12,7 +12,7 @@ run_unit() {
     # nightly-class files run (with the big cases enabled) in the
     # nightly tier — keep each test out of exactly one tier
     python -m pytest tests/ -q -x --ignore=tests/test_dist.py \
-        --ignore=tests/test_examples.py \
+        --ignore-glob='tests/test_examples_*.py' \
         --ignore=tests/test_large_array.py \
         --ignore=tests/test_checkpoint_compat.py
 }
@@ -24,7 +24,7 @@ run_dist() {
 
 run_examples() {
     echo "=== examples tier (toy-scale end-to-end) ==="
-    python -m pytest tests/test_examples.py -q
+    python -m pytest tests/test_examples_*.py -q
 }
 
 run_suite() {
@@ -590,6 +590,7 @@ run_serving() {
     # engine smoke: kernel equivalence, allocator, token-identity vs
     # generate(), and the steady-state zero-retrace assertions
     JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py \
+        tests/test_serving_engine.py \
         tests/test_serving_observability.py -q
     # seeded mixed-length trace through the continuous-batching engine;
     # the gate zero-tolerates steady-state compiles/retraces and dense

@@ -118,6 +118,11 @@ def _stack_keys(params):
     return [k for k in params if k not in _NON_STACKED]
 
 
+def _stacked(params):
+    """The per-layer params: what a layer loop scans over."""
+    return {k: params[k] for k in _stack_keys(params)}
+
+
 # The scopes "norm", "attention" and "ffn" below are for whoever reads the
 # device side: they land in every op's `op_name` metadata (a dumped HLO,
 # TensorBoard's op profile), so a block's time can be sorted by them after a
@@ -200,45 +205,78 @@ def _dense_attention(q, k, v, causal=True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _layer(lp, x, cfg, attn_fn):
-    """One transformer block. lp = per-layer param dict (no leading L axis).
-    x: (B, T, d). Returns (y, aux_loss)."""
+def _embed(params, tokens, pos):
+    """Token rows plus positional rows: tokens (S, T) -> x (S, T, d).
+    `pos` is where the tokens sit: an (S, T) int array, one position per
+    token (one past the table reads row 0: such a row's output is the
+    caller's to discard), or the position of every row's first token, a
+    Python int or a traced scalar."""
+    x, table = params["embed"][tokens], params["pos"]
+    T = tokens.shape[1]
+    if jnp.ndim(pos):
+        return x + table[jnp.where(pos < table.shape[0], pos, 0)]
+    if isinstance(pos, int):
+        return x + table[pos:pos + T][None]
+    return x + lax.dynamic_slice_in_dim(table, pos, T, axis=0)[None]
+
+
+def _block(lp, x, cache, cfg, attend):
+    """The transformer block, written once for every program of this file
+    (the pattern of models.sambay._block). lp = per-layer param dict (no
+    leading L axis); x: (S, T, d). What differs between the programs is
+    the caller's: `attend(q, k, v, cache)`, q/k/v (S, T, H, Dh) each,
+    stores what its cache keeps, reads what its attention needs and
+    returns (a (S, T, H, Dh), the cache after it). Returns (x, cache,
+    router auxiliary loss)."""
+    S, T, d = x.shape
     h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-    q = _split_heads(h @ lp["wq"], cfg.n_heads)
-    k = _split_heads(h @ lp["wk"], cfg.n_heads)
-    v = _split_heads(h @ lp["wv"], cfg.n_heads)
+    # the barrier keeps the head split out of the matmul: fused in, XLA
+    # turns the product into a convolution over heads that wants the
+    # weight transposed, and re-lays-out all three in every layer
+    q, k, v = (
+        _split_heads(lax.optimization_barrier(h @ lp[w]), cfg.n_heads)
+        for w in ("wq", "wk", "wv"))
     with jax.named_scope("attention"):
-        a = attn_fn(q, k, v)
-    B, T, _ = x.shape
-    x = x + a.reshape(B, T, cfg.d_model) @ lp["wo"]
+        a, cache = attend(q, k, v, cache)
+    x = x + a.reshape(S, T, d) @ lp["wo"]
     h = _ln(x, lp["ln2_g"], lp["ln2_b"])
     with jax.named_scope("ffn"):
         if cfg.n_experts:
-            flat = h.reshape(B * T, cfg.d_model)
-            out, aux = moe_ffn(flat, lp["router"], lp["w1"], lp["w2"])
-            return x + out.reshape(B, T, cfg.d_model), aux
-        return (x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"],
-                jnp.zeros((), x.dtype))
+            out, aux = moe_ffn(h.reshape(S * T, d), lp["router"], lp["w1"],
+                               lp["w2"])
+            out = out.reshape(S, T, d)
+        else:
+            out = jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+            aux = jnp.zeros((), x.dtype)
+    return x + out, cache, aux
+
+
+def _cacheless(attn_fn):
+    """_block's `attend` for a program that keeps no cache."""
+    return lambda q, k, v, _: (attn_fn(q, k, v), None)
+
+
+def _logits(params, x):
+    """Final LayerNorm and the product with the tied embedding:
+    (..., d) -> (..., V)."""
+    return _ln(x, params["ln_f_g"], params["ln_f_b"]) @ params["embed"].T
 
 
 def apply(params, tokens, cfg: TransformerConfig, attn_fn=None):
     """Forward pass: tokens (B, T) int32 -> logits (B, T, V). Scans the layer
-    stack (compiler-friendly: one compiled block body)."""
+    stack (compiler-friendly: one compiled block body); it keeps no cache."""
     if attn_fn is None:
         attn_fn = _flash_attention_fn if cfg.use_flash else _dense_attention
-    x = params["embed"][tokens] + params["pos"][: tokens.shape[1]][None]
-
-    stacked = {k: params[k] for k in _stack_keys(params)}
 
     def body(carry, lp):
         x, aux = carry
-        y, a = _layer(lp, x, cfg, attn_fn)
-        return (y, aux + a), None
+        x, _, a = _block(lp, x, None, cfg, _cacheless(attn_fn))
+        return (x, aux + a), None
 
-    (x, aux), _ = lax.scan(body, (x, jnp.zeros((), x.dtype)), stacked)
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    logits = x @ params["embed"].T
-    return logits, aux / max(cfg.n_layers, 1)
+    x = _embed(params, tokens, 0)
+    (x, aux), _ = lax.scan(body, (x, jnp.zeros((), x.dtype)),
+                           _stacked(params))
+    return _logits(params, x), aux / max(cfg.n_layers, 1)
 
 
 def _xent(logits, targets, fused=False):
@@ -288,6 +326,32 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int | None = None
     }
 
 
+def _dense_layers(params, cache, x, pos, cfg, read):
+    """The layer loop of the two dense-cache programs. x: (B, T, d), the
+    tokens at positions pos .. pos + T - 1; each layer stores their K/V
+    rows there in its (B, T_max, H, Dh) cache layers and
+    `read(q, k, v, k_cache, v_cache)` -> (B, T, H, Dh) attends. Returns
+    (x, new_k, new_v)."""
+
+    def attend(q, k, v, kv):
+        # cache dtype follows cfg.dtype; activations may be wider (f32
+        # master weights) — cast at the cache-write boundary
+        kv = tuple(
+            lax.dynamic_update_slice_in_dim(c, rows.astype(c.dtype), pos,
+                                            axis=1)
+            for c, rows in zip(kv, (k, v)))
+        return read(q, k, v, *kv), kv
+
+    def body(x, layer_in):
+        lp, kv = layer_in
+        x, kv, _ = _block(lp, x, kv, cfg, attend)
+        return x, kv
+
+    x, (new_k, new_v) = lax.scan(
+        body, x, (_stacked(params), (cache["k"], cache["v"])))
+    return x, new_k, new_v
+
+
 def decode_step(params, cache, tokens, cfg: TransformerConfig):
     """One token through the stack with cached attention state.
 
@@ -296,85 +360,33 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig):
     time; past capacity, dynamic_update_slice would silently clamp).
     Returns (logits (B, V), new_cache). Attention reads the full static
     cache and masks positions beyond pos (no dynamic shapes)."""
-    B = tokens.shape[0]
+    from ..ops.pallas_kernels import dense_decode_attention, flash_decode
+
     pos = cache["pos"]
-    T_max = cache["k"].shape[2]
-    x = params["embed"][tokens] + jax.lax.dynamic_index_in_dim(
-        params["pos"], pos, axis=0, keepdims=False)  # (B, d)
+    kernel = flash_decode if cfg.use_flash else dense_decode_attention
 
-    stacked = {k: params[k] for k in _stack_keys(params)}
+    def read(q, k, v, k_cache, v_cache):
+        return kernel(q[:, 0], k_cache, v_cache, pos + 1)[:, None]
 
-    def body(x, layer_in):
-        lp, k_cache, v_cache = layer_in
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])  # (B, d)
-        q = (h @ lp["wq"]).reshape(B, cfg.n_heads, -1)
-        k = (h @ lp["wk"]).reshape(B, cfg.n_heads, -1)
-        v = (h @ lp["wv"]).reshape(B, cfg.n_heads, -1)
-        k_cache = lax.dynamic_update_slice_in_dim(
-            k_cache, k[:, None].astype(k_cache.dtype), pos,
-            axis=1)  # (B, T_max, H, Dh)
-        v_cache = lax.dynamic_update_slice_in_dim(
-            v_cache, v[:, None].astype(v_cache.dtype), pos, axis=1)
-        if cfg.use_flash:
-            from ..ops.pallas_kernels import flash_decode
-
-            a = flash_decode(q, k_cache, v_cache, pos + 1)
-        else:
-            from ..ops.pallas_kernels import dense_decode_attention
-
-            a = dense_decode_attention(q, k_cache, v_cache, pos + 1)
-        x = x + a.reshape(B, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts:
-            out, _ = moe_ffn(h, lp["router"], lp["w1"], lp["w2"])
-            x = x + out
-        else:
-            x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
-        return x, (k_cache, v_cache)
-
-    x, (new_k, new_v) = lax.scan(body, x, (stacked, cache["k"], cache["v"]))
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    logits = x @ params["embed"].T
+    x, new_k, new_v = _dense_layers(
+        params, cache, _embed(params, tokens[:, None], pos), pos, cfg, read)
     new_cache = {"k": new_k, "v": new_v, "pos": pos + 1}
-    return logits, new_cache
+    return _logits(params, x[:, 0]), new_cache
 
 
 def prefill(params, cache, prompt, cfg: TransformerConfig):
     """Fill the cache with the whole prompt in ONE batched pass (the
     O(T_p)-sequential decode_step loop would serialize T_p attention
     launches). Returns (cache, last-token logits (B, V))."""
-    B, T_p = prompt.shape
-    x = params["embed"][prompt] + params["pos"][:T_p][None]
-    stacked = {k: params[k] for k in _stack_keys(params)}
 
-    def body(x, layer_in):
-        lp, k_cache, v_cache = layer_in
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        q = _split_heads(h @ lp["wq"], cfg.n_heads)
-        k = _split_heads(h @ lp["wk"], cfg.n_heads)
-        v = _split_heads(h @ lp["wv"], cfg.n_heads)
-        # cache dtype follows cfg.dtype; activations may be wider (f32
-        # master weights) — cast at the cache-write boundary
-        k_cache = lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), 0, axis=1)
-        v_cache = lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), 0, axis=1)
-        a = _dense_attention(q, k, v, causal=True)
-        x = x + a.reshape(B, T_p, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        if cfg.n_experts:
-            flat = h.reshape(B * T_p, cfg.d_model)
-            out, _ = moe_ffn(flat, lp["router"], lp["w1"], lp["w2"])
-            x = x + out.reshape(B, T_p, cfg.d_model)
-        else:
-            x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
-        return x, (k_cache, v_cache)
+    def read(q, k, v, k_cache, v_cache):
+        return _dense_attention(q, k, v, causal=True)
 
-    x, (new_k, new_v) = lax.scan(body, x, (stacked, cache["k"], cache["v"]))
-    h = _ln(x[:, -1], params["ln_f_g"], params["ln_f_b"])
-    logits = h @ params["embed"].T
-    return {"k": new_k, "v": new_v,
-            "pos": jnp.asarray(T_p, jnp.int32)}, logits
+    x, new_k, new_v = _dense_layers(
+        params, cache, _embed(params, prompt, 0), 0, cfg, read)
+    new_cache = {"k": new_k, "v": new_v,
+                 "pos": jnp.asarray(prompt.shape[1], jnp.int32)}
+    return new_cache, _logits(params, x[:, -1])
 
 
 # ---------------------------------------------------------------------------
@@ -419,37 +431,23 @@ def _paged_layers(params, paged, x, start, n_write, page_table, cfg,
     Returns (x, new_paged)."""
     from ..ops.pallas_kernels import paged_kv_write, paged_write_plan
 
-    S, T, _ = x.shape
-    plan = paged_write_plan(page_table, start, n_write, T,
+    plan = paged_write_plan(page_table, start, n_write, x.shape[1],
                             paged["kv"].shape[3])
-    stacked = {k: params[k] for k in _stack_keys(params)}
 
     def body(carry, layer_in):
         x, pool = carry
         lp, l = layer_in
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-        # the barrier keeps the head split out of the matmul: fused in, XLA
-        # turns the product into a convolution over heads that wants the
-        # weight transposed, and re-lays-out all three in every layer
-        q, k, v = (
-            _split_heads(lax.optimization_barrier(h @ lp[w]), cfg.n_heads)
-            for w in ("wq", "wk", "wv"))  # (S, T, H, Dh) each
-        pool = paged_kv_write(pool, l, k, v, plan)
-        with jax.named_scope("attention"):
-            a = attend(q, k, v, pool, l)
-        x = x + a.reshape(S, T, cfg.d_model) @ lp["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-        with jax.named_scope("ffn"):
-            if cfg.n_experts:
-                flat_h = h.reshape(S * T, cfg.d_model)
-                out, _ = moe_ffn(flat_h, lp["router"], lp["w1"], lp["w2"])
-                x = x + out.reshape(S, T, cfg.d_model)
-            else:
-                x = x + jax.nn.gelu(h @ lp["w1"]) @ lp["w2"]
+
+        def write_then_attend(q, k, v, pool):
+            pool = paged_kv_write(pool, l, k, v, plan)
+            return attend(q, k, v, pool, l), pool
+
+        x, pool, _ = _block(lp, x, pool, cfg, write_then_attend)
         return (x, pool), None
 
     layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, pool), _ = lax.scan(body, (x, paged["kv"]), (stacked, layers))
+    (x, pool), _ = lax.scan(body, (x, paged["kv"]),
+                            (_stacked(params), layers))
     return x, {"kv": pool}
 
 
@@ -484,20 +482,17 @@ def prefill_paged(params, paged, prompts, true_lens, page_table,
     place, whole pages at a time (_paged_layers). Returns (new_paged,
     logits (S, V) at each row's LAST REAL token — the first sampled
     continuation token, matching prefill()'s x[:, -1] for full rows."""
-    S, T_b = prompts.shape
-    x = params["embed"][prompts] + params["pos"][:T_b][None]
 
     def attend(q, k, v, pool, l):
         return _dense_attention(q, k, v, causal=True)
 
-    x, paged = _paged_layers(params, paged, x, jnp.zeros_like(true_lens),
-                             true_lens, page_table, cfg, attend)
+    x, paged = _paged_layers(params, paged, _embed(params, prompts, 0),
+                             jnp.zeros_like(true_lens), true_lens,
+                             page_table, cfg, attend)
     last = jnp.maximum(true_lens - 1, 0)  # (S,)
     x_last = jnp.take_along_axis(
         x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]  # (S, d)
-    h = _ln(x_last, params["ln_f_g"], params["ln_f_b"])
-    logits = h @ params["embed"].T
-    return paged, logits
+    return paged, _logits(params, x_last)
 
 
 def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
@@ -527,8 +522,7 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
     page_size = paged["kv"].shape[3]
     pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]  # (S, Q)
     cap = min(page_table.shape[1] * page_size, params["pos"].shape[0])
-    x = params["embed"][tokens] + params["pos"][
-        jnp.where(pos < cap, pos, 0)]  # (S, Q, d)
+    x = _embed(params, tokens, pos)  # (S, Q, d)
     n_write = jnp.clip(cap - start, 0, n_real)
     from ..ops.pallas_kernels import paged_decode_attention_wide
 
@@ -537,9 +531,7 @@ def decode_step_paged_wide(params, paged, tokens, start, n_real, page_table,
 
     x, paged = _paged_layers(params, paged, x, start, n_write, page_table,
                              cfg, attend)
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    logits = x @ params["embed"].T
-    return logits, paged
+    return _logits(params, x), paged
 
 
 class TransformerPrograms:
@@ -846,7 +838,7 @@ def make_pipeline_train_step(mesh: Mesh, cfg: TransformerConfig, lr=0.1, n_micro
         attn = functools.partial(ring_attention, axis_name="sp", causal=True)
 
         def body(h, lp):
-            y, _ = _layer(lp, h, cfg, attn)
+            y, _, _ = _block(lp, h, None, cfg, _cacheless(attn))
             return y, None
 
         y, _ = lax.scan(body, x, stage_params)
@@ -859,14 +851,12 @@ def make_pipeline_train_step(mesh: Mesh, cfg: TransformerConfig, lr=0.1, n_micro
             b, t = tokens.shape
             sp_idx = lax.axis_index("sp")
             pos0 = sp_idx * t  # global position offset of this sequence shard
-            x = p["embed"][tokens] + lax.dynamic_slice_in_dim(p["pos"], pos0, t, axis=0)[None]
-            stage_params = {k: p[k] for k in stack_keys}
+            x = _embed(p, tokens, pos0)
+            stage_params = _stacked(p)
             mb = b // n_micro
             micro = x.reshape(n_micro, mb, t, cfg.d_model)
             out = spmd_pipeline(stage_fn, stage_params, micro, axis_name="pp")
-            h = out.reshape(b, t, cfg.d_model)
-            h = _ln(h, p["ln_f_g"], p["ln_f_b"])
-            logits = h @ p["embed"].T
+            logits = _logits(p, out.reshape(b, t, cfg.d_model))
             losses = _xent(logits, targets, cfg.use_fused_xent)
             # replicated-scalar loss: only the device's own shard contributes,
             # psum over every mesh axis; pp ranks all hold identical outputs so

@@ -3,8 +3,12 @@ import functools
 import logging
 import os
 import random
+import subprocess
+import sys
 
 import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def with_seed(seed=None):
@@ -48,3 +52,33 @@ def build_perl_pkg(tmp_path, repo):
         assert out.returncode == 0, (cmd, out.stdout[-1500:],
                                      out.stderr[-1500:])
     return build, env
+
+
+_EXAMPLE_DRIVER = """
+import sys, runpy
+import jax
+jax.config.update("jax_platforms", "cpu")
+script = sys.argv[1]
+sys.argv = sys.argv[1:]
+runpy.run_path(script, run_name="__main__")
+"""
+
+
+def run_example(example, *args, timeout=420):
+    """Run examples/<example> at toy scale in its own process, on the CPU,
+    and return what it printed (the reference CI runs example scripts the
+    same way, ref: ci/docker/runtime_functions.sh example sections).
+
+    Its callers are tests/test_examples_<family>.py, one file per family
+    of examples, because the driver's `--dist loadfile` hands a whole
+    file to one worker: a file that takes over ~300 s alone is split
+    before it is the wall of tier 1 (ROADMAP.md D13)."""
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _EXAMPLE_DRIVER,
+         os.path.join(REPO, "examples", example), *args],
+        capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
+    assert out.returncode == 0, f"stdout:\n{out.stdout[-3000:]}\nstderr:\n{out.stderr[-3000:]}"
+    # logging-based examples (train_mnist & co) report on stderr
+    return out.stdout + out.stderr

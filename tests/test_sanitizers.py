@@ -225,10 +225,11 @@ def test_engine_full_run_is_quiescent_under_sanitizers(sanitized,
     """ServingEngine with prefix cache, chunked prefill and speculation
     all ON: run() drains through assert_quiescent(), the decode/prefill
     write paths go through note_write, and nothing fires."""
-    # the engine holds its lock through step(); the first step's XLA
-    # compile (~1 s on CPU) is a benign long hold — same allowance as
-    # the tools/sanitize.py harness, a stuck lock still blows past 5 s
-    monkeypatch.setattr(sanitizers, "_hold_ms", 5000.0)
+    # the engine holds its lock through step(); the first step compiles
+    # every lever's program under it, a benign long hold: 2.6-3.4 s alone
+    # on the CPU (the interpret-mode paged kernel), 5.8 s seen beside five
+    # busy xdist workers. A stuck lock never lets go
+    monkeypatch.setattr(sanitizers, "_hold_ms", 30000.0)
     cfg = tfm.TransformerConfig(vocab=32, d_model=16, n_heads=2,
                                 n_layers=1, d_ff=32, max_len=64)
     params = tfm.init_params(cfg, seed=0)

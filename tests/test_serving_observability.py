@@ -70,6 +70,14 @@ def metrics_on(monkeypatch):
     telemetry.REGISTRY.reset()
 
 
+@pytest.fixture
+def compile_table(metrics_on, tmp_path, monkeypatch):
+    """The compile table in the engine's snapshot is fed by compilereg,
+    which only sees programs routed through the persistent compile cache
+    while telemetry is on."""
+    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+
+
 def _load_records(trace_dir):
     _distributed.flush()
     records = []
@@ -315,11 +323,7 @@ def test_engine_attaches_slo_from_env_and_breaches(traced, monkeypatch):
 
 # -- /debug/engine introspection ---------------------------------------------
 
-def test_debug_snapshot_matches_engine_midrun(metrics_on, tmp_path,
-                                              monkeypatch):
-    # the compile table in the snapshot is fed by compilereg, which only
-    # sees programs routed through the persistent compile cache
-    monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+def test_debug_snapshot_matches_engine_midrun(compile_table):
     eng = _tiny_engine(slots=1)
     r0 = eng.submit(_prompt(4), 8)
     r1 = eng.submit(_prompt(5, seed=1), 4)
@@ -371,7 +375,7 @@ def test_debug_endpoint_http(monkeypatch):
         srv.close()
 
 
-def test_serving_top_render():
+def test_serving_top_render(compile_table):
     top = _serving_top()
     eng = _tiny_engine(slots=1)
     eng.submit(_prompt(4), 8)
