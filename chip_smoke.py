@@ -112,6 +112,10 @@ class Sizes:
     page_size: int = 16
     table_width: int = 32
     wide_q: tuple = (4, 16)
+    # paged_diff_attention at phi4_mini_flash's geometry: slots, K/V pairs,
+    # table width, pages of the shared pool (past the 7680 a VMEM-mapped
+    # pool could have), pages of a slot's ring, window
+    diff_geometry: tuple = (16, 10, 448, 8193, 33, 512)
     epilogue_shapes: tuple = ((128 * 56 * 56, 64), (128 * 7 * 7, 2048))
     interpret: bool = False
 
@@ -312,7 +316,8 @@ def _diff_attention_ref(q, pool, table, n_valid, window):
                    keys) / (D ** 0.5)
     s = jnp.where((pos >= 0)[:, None, None, None], s, -1e30)
     out = jnp.einsum("bgerl,gblv->bgerv", jax.nn.softmax(s, -1), value)
-    return out.reshape(B, G, 4, 2 * D)
+    # a dead slot (n_valid 0) reads as zeros
+    return out.reshape(B, G, 4, 2 * D) * (n_valid > 0)[:, None, None, None]
 
 
 def _selective_scan_ref(dt, a, Bm, Cm, A, s0):
@@ -483,6 +488,28 @@ def phase_kernels(sz):
                           interpret=ip), q4, pool, tbl, depth),
                       oracle(_diff_attention_ref, q4.astype(f32),
                              pool[1].astype(f32), tbl, depth, win), dtype)
+
+    # the same kernel as models.sambay's decode step calls it at the
+    # benchmark's geometry: a bfloat16 pool, float32 queries, slots to the
+    # table's full depth beside a shallow and a dead one; the shared cache
+    # by a shuffled table, and rings many times round under the window
+    S, G, W, P, R, win = sz.diff_geometry
+    D, ps = sz.attn_shape[3], sz.page_size
+    q4 = rand((S, G, 4, D), f32)
+    depth = jax.random.randint(next(key), (S,), 1, W * ps + 1)
+    depth = depth.at[0].set(W * ps).at[1].set(1).at[2].set(0)
+    table = (1 + jax.random.permutation(next(key), P - 1)[:S * W]
+             ).reshape(S, W).astype(jnp.int32)
+    rings = (1 + jnp.arange(S * R, dtype=jnp.int32)).reshape(S, R)
+    for tag, tbl, pages, win in (("shared", table.at[2].set(0), P, 0),
+                                 ("ring", rings, S * R + 1, win)):
+        pool = rand((2, 2 * G, pages, ps, 2 * D), jnp.bfloat16)
+        orc.close(f"paged_diff_attention[cell,{tag},{pages} pages]",
+                  pk.paged_diff_attention(q4, pool, tbl, depth, 1, window=win,
+                                          ring=bool(win), interpret=ip),
+                  oracle(_diff_attention_ref,
+                         q4.astype(jnp.bfloat16).astype(f32),
+                         pool[1].astype(f32), tbl, depth, win), jnp.bfloat16)
 
     # selective_scan: a prompt's worth of steps from a given state, and
     # the single step of a decode batch (float32 by contract)

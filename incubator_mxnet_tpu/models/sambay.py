@@ -46,8 +46,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.pallas_kernels import (paged_diff_attention, paged_kv_write,
-                                  paged_ring_write_plan, paged_write_plan,
-                                  selective_scan)
+                                  paged_ring_write_plan, paged_walk_tokens,
+                                  paged_write_plan, selective_scan)
 
 __all__ = ["SambaYConfig", "SambaYPrograms", "init_params", "init_cache",
            "decode_step_paged", "prefill_paged", "param_count"]
@@ -623,6 +623,13 @@ class SambaYPrograms:
                 * cfg.n_self_pairs}
 
     def fetched(self, n_valid, page_size):
-        """Nothing to tell: paged_diff_attention maps a pair of heads'
-        whole pool per grid step and gathers no block."""
-        return {}
+        """Tokens one decode step's kernels fetch to attend those, by
+        cache kind: paged_diff_attention gathers a slot's pages a block
+        at a time, from the first page the window reaches to the page
+        being written, and masks what the first and last block hold
+        besides."""
+        cfg = self.cfg
+        return {"shared_kv": paged_walk_tokens(n_valid, page_size)
+                * (1 + cfg.n_cross_pairs),
+                "window_kv": paged_walk_tokens(n_valid, page_size, cfg.window)
+                * cfg.n_self_pairs}

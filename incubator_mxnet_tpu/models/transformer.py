@@ -588,11 +588,10 @@ class TransformerPrograms:
         """Tokens one decode step's kernels fetch to attend those, by
         cache kind: paged_decode_attention gathers a slot's pages a
         block at a time and masks the last block's tail."""
-        from ..ops.pallas_kernels import paged_block_tokens
+        from ..ops.pallas_kernels import paged_walk_tokens
 
-        block = paged_block_tokens(page_size)
-        blocks = -(-np.asarray(n_valid, np.int64) // block)
-        return {"paged_kv": int(blocks.sum()) * block * self.cfg.n_layers}
+        return {"paged_kv": paged_walk_tokens(n_valid, page_size)
+                * self.cfg.n_layers}
 
 
 def _filter_logits(logits, top_k=0, top_p=0.0):

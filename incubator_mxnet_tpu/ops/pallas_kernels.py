@@ -473,22 +473,27 @@ def _online_softmax_update(q, k, v, live, carry, scale):
 
 
 def _online_softmax_accumulate(s, v, live, carry):
-    """The update above from the scaled scores s (Q, block) on."""
+    """The update above from the scaled scores s (Q, block) on; with a
+    leading axis of heads on everything, (Hg, Q, block) against
+    (Hg, block, d), it is Hg such updates in one."""
     o, m, l = carry
     s = jnp.where(live, s, _NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-    o_new = o * alpha + jnp.dot(p.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32)
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_new = o * alpha + jnp.matmul(p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)
     return o_new, m_new, l_new
 
 
-def _online_softmax_init(n_q, d):
-    return (jnp.zeros((n_q, d), jnp.float32),
-            jnp.full((n_q, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((n_q, 1), jnp.float32))
+def _online_softmax_init(*shape):
+    """The carry before the first block, for an accumulator of `shape`
+    ((Q, d), or (Hg, Q, d))."""
+    stat = shape[:-1] + (1,)
+    return (jnp.zeros(shape, jnp.float32),
+            jnp.full(stat, _NEG_INF, jnp.float32),
+            jnp.zeros(stat, jnp.float32))
 
 
 def _online_softmax_finish(carry, dtype):
@@ -780,45 +785,16 @@ def flash_decode(q, k_cache, v_cache, n_valid, block_k=DECODE_BLOCK,
 # a page of a RUN of heads, pool[l, h0:h0+Hg, page], is Hg such runs of
 # whole rows: one strided DMA.
 #
-# paged_decode_attention(_wide) leaves the pool in HBM and walks a slot's
-# table in BLOCKS (_paged_decode_kernel): one grid step per slot, all its
-# heads at once; a block of 128 tokens' pages gathered into VMEM by one copy
-# per page, double-buffered; one loop step per block for every head. Only
-# the slot's live pages move, and the pool may be of any size.
-# paged_diff_attention (models.sambay) still maps a pair of heads' whole
-# pool into VMEM per grid step, and is held to PAGED_VMEM_LIMIT_BYTES.
+# Every attention kernel over the pool leaves it in HBM and walks a slot's
+# table in BLOCKS (_paged_block_walk): one grid step per slot, all its heads
+# at once; a block of 128 tokens' pages gathered into VMEM by one copy per
+# page, double-buffered; one loop step per block for every head. Only the
+# slot's live pages move, and the pool may be of any size.
+# paged_decode_attention(_wide) scores Q rows per head (_paged_decode_kernel),
+# paged_diff_attention (models.sambay) 4 rows per pair of heads, from the
+# first page its window reaches, through a table or a ring
+# (_paged_diff_kernel): two small bodies on the one walk.
 # ---------------------------------------------------------------------------
-
-# Scoped VMEM paged_diff_attention asks Mosaic for. Two heads' pool block,
-# single-buffered (the bytes of one head double-buffered), must fit under
-# it; a pool that does not is an error (_check_pool_fits_vmem), never a
-# dense fallback.
-PAGED_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
-_PAGED_VMEM_RESERVE_BYTES = 4 * 1024 * 1024  # q/o blocks, carries, spills
-
-
-def paged_pool_vmem_bytes(num_pages, page_size, head_dim, dtype):
-    """VMEM the pipeline holds for one head of a paged pool: two buffers
-    of the fused K|V block, every page padded to the dtype's native
-    (sublane, 128-lane) tile."""
-    itemsize = jnp.dtype(dtype).itemsize
-    sublane = 8 * 4 // itemsize
-    rows = -(-page_size // sublane) * sublane
-    lanes = -(-2 * head_dim // 128) * 128
-    return 2 * num_pages * rows * lanes * itemsize
-
-
-def _check_pool_fits_vmem(num_pages, page_size, head_dim, dtype):
-    budget = PAGED_VMEM_LIMIT_BYTES - _PAGED_VMEM_RESERVE_BYTES
-    need = paged_pool_vmem_bytes(num_pages, page_size, head_dim, dtype)
-    if need > budget:
-        per_page = need // num_pages
-        raise ValueError(
-            f"paged KV pool of {num_pages} pages (page_size={page_size}, "
-            f"head_dim={head_dim}, {jnp.dtype(dtype).name}) needs "
-            f"{need} bytes of VMEM per head; paged_diff_attention maps "
-            f"a head's whole pool into VMEM and fits at most "
-            f"{budget // per_page} such pages ({budget} bytes)")
 
 
 def _layer_index(layer):
@@ -938,6 +914,19 @@ def paged_block_tokens(page_size):
     return max(PAGED_BLOCK_TOKENS // page_size, 1) * page_size
 
 
+def paged_walk_tokens(n_valid, page_size, window=0):
+    """Tokens the block walk fetches for slots `n_valid` deep (host
+    integers, (S,)): whole blocks from the first page the window reaches
+    (page 0 without one) to the last live page, summed over the slots.
+    The host's count of what the kernels below gather, to set beside what
+    they attend."""
+    n = np.asarray(n_valid, np.int64)
+    per_block = paged_block_tokens(page_size) // page_size
+    first = np.maximum(n - window, 0) // page_size if window else 0
+    pages = -(-n // page_size) - first
+    return int((-(-pages // per_block)).sum()) * per_block * page_size
+
+
 def _paged_head_group(n_heads, n_q, block_tokens, row_lanes, dtype):
     """Heads per grid step: the largest divisor of n_heads whose two block
     buffers and float32 working rows (scores, probabilities and the
@@ -950,6 +939,73 @@ def _paged_head_group(n_heads, n_q, block_tokens, row_lanes, dtype):
                if n_heads % g == 0)
 
 
+def _paged_block_walk(pt_ref, pool_ref, buf, sem, b, layer, h0, first,
+                      n_pages, *, page_size, ring=False):
+    """The walk both paged attention kernels make: slot b's `n_pages`
+    table entries from absolute page `first` on (None: from page 0, with
+    no arithmetic for it), in layer `layer`, heads h0 .. h0 + Hg, block
+    by block.
+
+    pt_ref (B, W) is the scalar-prefetched page table, a ring under `ring`
+    (absolute page a in column a % W, else in column a); pool_ref the whole
+    pool where it lies in HBM, (L, H, num_pages, page_size, 2*d); buf
+    (2, Hg, block, 2*d) and sem (2,) the two block buffers and their DMA
+    semaphores.
+
+    A block is `pages` consecutive entries, gathered by one strided copy
+    per page that covers all Hg heads (pool[l, h0:h0+Hg, page] is
+    (Hg, page_size, 2*d) with whole rows: Hg runs of one page each), block
+    j + 1 in flight while block j is computed. Entries past the walk's
+    last page are not trusted: a block's tail is the null page 0, finite,
+    and the caller masks it.
+
+    Returns (start, run): start() sets block 0 going (whatever the caller
+    does next hides behind it); run(step, carry) loops over the blocks,
+    carry = step(j, rows, carry) with rows (Hg, block, 2*d) the j-th. No
+    page means no copy and no loop step."""
+    width = pt_ref.shape[1]
+    hg = buf.shape[1]
+    pages = buf.shape[2] // page_size
+    n_blocks = (n_pages + pages - 1) // pages
+
+    def copies(j, slot):
+        out = []
+        for c in range(pages):
+            i = j * pages + c
+            live = i < n_pages
+            a = i if first is None else first + i
+            col = a % width if ring else jnp.minimum(a, width - 1)
+            page = jnp.where(live, pt_ref[b, col], 0)
+            out.append(pltpu.make_async_copy(
+                pool_ref.at[layer, pl.ds(h0, hg), page],
+                buf.at[slot, :, pl.ds(c * page_size, page_size)],
+                sem.at[slot]))
+        return out
+
+    def start():
+        @pl.when(n_blocks > 0)
+        def _():
+            for c in copies(0, 0):
+                c.start()
+
+    def run(step, carry):
+        def body(j, carry):
+            slot = j % 2
+
+            @pl.when(j + 1 < n_blocks)
+            def _():
+                for c in copies(j + 1, 1 - slot):
+                    c.start()
+
+            for c in copies(j, slot):
+                c.wait()
+            return step(j, buf[slot], carry)
+
+        return jax.lax.fori_loop(0, n_blocks, body, carry)
+
+    return start, run
+
+
 def _paged_decode_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref,
                          buf, sem, *, page_size, scale):
     """One (b, g) grid step: slot b, head group g, Q query rows at
@@ -958,125 +1014,145 @@ def _paged_decode_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref,
     the call; nv == 0 is a dead slot, which fetches and computes nothing).
 
     pt_ref (B, W), nv_ref (B,) and l_ref (1,) are scalar-prefetch refs
-    (SMEM: control flow and the DMA's page indices). pool_ref is the whole
-    pool where it lies in HBM, (L, H, num_pages, page_size, 2*d), K|V per
-    row. q_ref is (Hg, Q, d); o_ref (Hg, Q, 2*d), whose V lanes are the
-    output. buf (2, Hg, block, 2*d) and sem (2,) are the two block buffers
-    and their DMA semaphores.
+    (SMEM: control flow and the DMA's page indices). q_ref is (Hg, Q, d);
+    o_ref (Hg, Q, 2*d), whose V lanes are the output; pool_ref, buf and
+    sem are _paged_block_walk's.
 
-    The walk goes block by block: a block is `pages` consecutive table
-    entries, gathered by one strided copy per page that covers all Hg
-    heads (pool[l, h0:h0+Hg, page] is (Hg, page_size, 2*d) with whole
-    rows: Hg runs of one page each), block j + 1 in flight while block j
-    is computed. One loop step scores all Hg heads against the block's
-    128 K rows in one batched product, updates the online softmax over
-    full-lane rows, and takes p . rows in another: Hg independent chains
-    per step, so the MXUs and the DMA overlap across heads."""
+    One loop step scores all Hg heads against the block's 128 K rows in
+    one batched product, updates the online softmax over full-lane rows,
+    and takes p . rows in another: Hg independent chains per step, so the
+    MXUs and the DMA overlap across heads."""
     b, g = pl.program_id(0), pl.program_id(1)
     hg, n_q, d = q_ref.shape
-    width = pt_ref.shape[1]
-    pages = buf.shape[2] // page_size
-    span = pages * page_size
-    layer, h0 = l_ref[0], g * hg
+    span = buf.shape[2]
     # positions past the table's capacity hold nothing (speculative
     # overrun; those rows' outputs are discarded): they are never live,
     # and the table is never indexed out of bounds
-    cap = width * page_size
+    cap = pt_ref.shape[1] * page_size
+    layer, h0 = l_ref[0], g * hg
     n_pages = (jnp.minimum(nv_ref[b], cap) + page_size - 1) // page_size
-    n_blocks = (n_pages + pages - 1) // pages
-
-    def copies(j, slot):
-        # entries past the slot's last live page are not trusted: the
-        # block's tail is the null page 0, finite and masked
-        out = []
-        for c in range(pages):
-            col = j * pages + c
-            page = jnp.where(col < n_pages,
-                             pt_ref[b, jnp.minimum(col, width - 1)], 0)
-            out.append(pltpu.make_async_copy(
-                pool_ref.at[layer, pl.ds(h0, hg), page],
-                buf.at[slot, :, pl.ds(c * page_size, page_size)],
-                sem.at[slot]))
-        return out
-
-    @pl.when(n_blocks > 0)
-    def _():
-        for c in copies(0, 0):
-            c.start()
-
+    start, run = _paged_block_walk(pt_ref, pool_ref, buf, sem, b, layer, h0,
+                                   None, n_pages, page_size=page_size)
+    start()
     q = q_ref[...]
     row = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 2)
     limit = jnp.minimum(nv_ref[b] - (n_q - 1) + row, cap)
 
-    def body(j, carry):
-        slot = j % 2
-
-        @pl.when(j + 1 < n_blocks)
-        def _():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
-
-        for c in copies(j, slot):
-            c.wait()
-        rows = buf[slot]  # (Hg, span, 2*d)
+    def step(j, rows, carry):
         s = jax.lax.dot_general(
             q, rows[:, :, :d], (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale  # (Hg, Q, span)
-        o, m, l = carry
-        s = jnp.where(j * span + col < limit, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=2, keepdims=True)
-        o_new = o * alpha + jax.lax.dot_general(
-            p.astype(rows.dtype), rows, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+        return _online_softmax_accumulate(s, rows, j * span + col < limit,
+                                          carry)
 
-    carry = jax.lax.fori_loop(
-        0, n_blocks, body,
-        (jnp.zeros(o_ref.shape, jnp.float32),
-         jnp.full((hg, n_q, 1), _NEG_INF, jnp.float32),
-         jnp.zeros((hg, n_q, 1), jnp.float32)))
+    carry = run(step, _online_softmax_init(*o_ref.shape))
     o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
+
+
+def _paged_diff_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref, buf,
+                       sem, *, page_size, scale, window, ring):
+    """One (b, g) grid step: slot b, Gg pairs of K/V heads and the 4 query
+    rows of each, over the slot's n cached tokens (the query's own
+    included), the last `window` of them when there is a window; n == 0
+    is a dead slot, which fetches and computes nothing.
+
+    Heads 2g and 2g+1 of the pool hold k1_g|v[2g] and k2_g|v[2g+1]; query
+    rows 0-1 (q1 of query pairs 2g, 2g+1) are scored against k1_g, rows
+    2-3 (their q2) against k2_g, and all four softmaxes weight the same
+    value V_g = [v[2g] | v[2g+1]]: the two heads' rows of a token side by
+    side, (block, 4*d), whose lanes [d, 2d) and [3d, 4d) carry the output
+    (the K lanes ride along as in the kernel above).
+
+    q_ref is (Gg, 4, d), o_ref (Gg, 4, 4*d); the rest as in
+    _paged_decode_kernel, buf holding the 2*Gg heads. The walk starts at
+    the first page the window reaches."""
+    b, g = pl.program_id(0), pl.program_id(1)
+    gg, _, d = q_ref.shape
+    span = buf.shape[2]
+    n = nv_ref[b]
+    lo_tok = jnp.maximum(n - window, 0) if window else 0
+    first = lo_tok // page_size
+    n_pages = (n + page_size - 1) // page_size - first
+    start, run = _paged_block_walk(pt_ref, pool_ref, buf, sem, b, l_ref[0],
+                                   g * 2 * gg, first, n_pages,
+                                   page_size=page_size, ring=ring)
+    start()
+    q = q_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, 4, span), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 4, span), 2)
+
+    def step(j, rows, carry):
+        rows = rows.reshape(gg, 2, span, 2 * d)
+        r1, r2 = rows[:, 0], rows[:, 1]
+        s1, s2 = (jax.lax.dot_general(
+            q, r[:, :, :d], (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) for r in (r1, r2))
+        idx = first * page_size + j * span + col  # (1, 4, span)
+        return _online_softmax_accumulate(
+            jnp.where(row < 2, s1, s2) * scale,
+            jnp.concatenate([r1, r2], axis=2), (idx >= lo_tok) & (idx < n),
+            carry)
+
+    carry = run(step, _online_softmax_init(*o_ref.shape))
+    o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
+
+
+def _paged_walk_scalars(page_table, n_valid, layer, batch):
+    """The walk's scalar-prefetch operands (SMEM: control flow and the
+    copies' page indices): the table, the count per slot, the layer."""
+    return (jnp.asarray(page_table, jnp.int32),
+            _per_seq_n_valid(n_valid, batch), _layer_index(layer))
+
+
+def _paged_walk_call(kernel, name, scalars, q, pool, out_dtype, interpret):
+    """Launch one of the walk's kernels. q (B, N, Q, D): Q query rows for
+    each of N heads, or groups of heads, of pool
+    (L, H, num_pages, page_size, 2*D), H // N K/V heads a group; scalars:
+    _paged_walk_scalars'. One grid step per slot and run of groups
+    (_paged_head_group). Returns (B, N, Q, (H // N) * 2*D): a group's
+    heads' lanes side by side."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    B, N, Q, D = q.shape
+    H, page_size, D2 = pool.shape[1], pool.shape[3], pool.shape[4]
+    lanes = H // N * D2
+    span = paged_block_tokens(page_size)
+    ng = _paged_head_group(N, Q, span, lanes, pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, N // ng),
+        in_specs=[
+            pl.BlockSpec((None, ng, Q, D),
+                         lambda b, g, *refs: (b, g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((None, ng, Q, lanes),
+                               lambda b, g, *refs: (b, g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, H // N * ng, span, D2), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, page_size=page_size,
+                          scale=1.0 / np.sqrt(D)),
+        name=name,  # what a trace reduction finds the kernel by
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, N, Q, lanes), out_dtype),
+        interpret=interpret,
+    )(*scalars, q, pool)
 
 
 def _paged_decode_call(q, pool, page_table, n_last, layer, interpret):
     """q (B, Q, H, D) over pool (L, H, num_pages, page_size, 2*D) in layer
     `layer`; n_last (B,): tokens the last query row of each slot attends.
     Returns (B, Q, H, D)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    B, Q, H, D = q.shape
-    page_size, D2 = pool.shape[3:]
-    span = paged_block_tokens(page_size)
-    hg = _paged_head_group(H, Q, span, D2, pool.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, H // hg),
-        in_specs=[
-            pl.BlockSpec((None, hg, Q, D),
-                         lambda b, g, *refs: (b, g, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((None, hg, Q, D2),
-                               lambda b, g, *refs: (b, g, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, hg, span, D2), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    o = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page_size=page_size,
-                          scale=1.0 / np.sqrt(D)),
-        # the name a trace reduction finds the kernel by: the engine's
-        # decode step is the Q = 1 case
-        name=("paged_decode_attention" if Q == 1
-              else "paged_decode_attention_wide"),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Q, D2), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), _per_seq_n_valid(n_last, B),
-      _layer_index(layer), q.transpose(0, 2, 1, 3), pool)
+    B, Q, _, D = q.shape
+    o = _paged_walk_call(
+        _paged_decode_kernel,
+        # the engine's decode step is the Q = 1 case
+        "paged_decode_attention" if Q == 1 else "paged_decode_attention_wide",
+        _paged_walk_scalars(page_table, n_last, layer, B),
+        q.transpose(0, 2, 1, 3), pool, q.dtype, interpret)
     return o[..., D:].transpose(0, 2, 1, 3)  # the V lanes
 
 
@@ -1130,121 +1206,42 @@ def paged_decode_attention(q, pool, page_table, n_valid, layer=0,
 
 
 # ---------------------------------------------------------------------------
-# Grouped differential attention over the paged pool (models.sambay): 2 K/V
-# heads share one grid step with the 4 query heads that read them. Heads
-# 2g and 2g+1 of the pool hold k1_g|v[2g] and k2_g|v[2g+1]; query rows 0-1
-# (q1 of pairs 2g, 2g+1) are scored against k1_g, rows 2-3 (their q2)
-# against k2_g, and all four softmaxes weight the same 128-wide value
-# V_g = [v[2g] | v[2g+1]]: the two rows of a page side by side, (page, 4*D),
-# whose lanes [D, 2D) and [3D, 4D) carry the output (the K lanes ride along
-# as in the kernels above). The walk starts at the first page the window
-# reaches, and with `ring` the table is a ring of pages indexed modulo its
-# width: absolute page a lives in column a % width.
-#
-# One step maps both heads' whole pool, single-buffered: the same VMEM as
-# one head double-buffered, so _check_pool_fits_vmem's limit is unchanged.
+# Grouped differential attention over the paged pool (models.sambay): the
+# same block walk, the 4 query heads of each pair of K/V heads scored as
+# _paged_diff_kernel says. With `window` the walk starts at the first page
+# the window reaches, and with `ring` the table is a ring of pages indexed
+# modulo its width: absolute page a lives in column a % width.
 # ---------------------------------------------------------------------------
 
 
-def _paged_diff_kernel(pt_ref, nv_ref, l_ref, q_ref, kv_ref, o_ref, *,
-                       page_size, scale, window, ring, pages_per_step):
-    """One (g, b) grid step: 4 query rows of slot b over its n_valid
-    cached tokens (the query's own included), the last `window` of them
-    when there is a window. pt_ref (B, W), nv_ref (B,), l_ref (1,) are
-    scalar-prefetch refs; kv_ref is (2, num_pages, page_size, 2*d), q_ref
-    (4, d), o_ref (4, 4*d)."""
-    b = pl.program_id(1)
-    q = q_ref[...]
-    n = nv_ref[b]
-    d = q.shape[1]
-    width = pt_ref.shape[1]
-    span = pages_per_step * page_size
-    lo_tok = jnp.maximum(n - window, 0) if window else 0
-    first = lo_tok // page_size
-    last = jnp.maximum(n - 1, 0) // page_size
-    row = jax.lax.broadcasted_iota(jnp.int32, (4, span), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (4, span), 1)
-
-    def body(i, carry):
-        a0 = first + i * pages_per_step
-        k1, k2, vals = [], [], []
-        for c in range(pages_per_step):
-            a = a0 + c
-            page = pt_ref[b, a % width if ring
-                          else jnp.minimum(a, width - 1)]
-            r1, r2 = kv_ref[0, page], kv_ref[1, page]
-            k1.append(r1[:, :d])
-            k2.append(r2[:, :d])
-            vals.append(jnp.concatenate([r1, r2], axis=1))
-        k1, k2, vals = (x[0] if len(x) == 1 else jnp.concatenate(x, axis=0)
-                        for x in (k1, k2, vals))
-        s1, s2 = (jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-                  for k in (k1, k2))
-        idx = a0 * page_size + col
-        live = (idx >= lo_tok) & (idx < n)
-        return _online_softmax_accumulate(
-            jnp.where(row < 2, s1, s2) * scale, vals, live, carry)
-
-    steps = (last - first + pages_per_step) // pages_per_step
-    carry = jax.lax.fori_loop(0, steps, body,
-                              _online_softmax_init(4, o_ref.shape[1]))
-    o_ref[...] = _online_softmax_finish(carry, o_ref.dtype)
-
-
 def paged_diff_attention(q, pool, page_table, n_valid, layer=0, *,
-                         window=0, ring=False, pages_per_step=4,
-                         interpret=None):
+                         window=0, ring=False, interpret=None):
     """Single-token grouped differential attention over a paged cache.
 
     q: (B, G, 4, D) — for K/V group g the query heads that read it, rows
     0-1 scored against head 2g's keys and rows 2-3 against head 2g+1's;
     pool: (L, 2*G, num_pages, page_size, 2*D), K|V fused per row, read in
-    layer `layer`; page_table: (B, W) int32; n_valid: (B,) tokens cached
-    per slot INCLUDING the query's own (0: a dead slot, whose output is
-    finite garbage the caller discards).
+    layer `layer` where it lies in HBM (a pool of any size); page_table:
+    (B, W) int32; n_valid: (B,) tokens cached per slot INCLUDING the
+    query's own (0: a dead slot, which costs no copy and no loop step and
+    reads as zeros).
     `window` > 0 keeps the last `window` tokens only; `ring` says
     page_table is a ring (absolute page a in column a % W) instead of a
     table that grows with the context.
 
     Returns (B, G, 4, 2*D): each row's softmax over its keys applied to
     [v[2g] | v[2g+1]], float32."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     B, G, R, D = q.shape
-    H, num_pages, page_size, D2 = pool.shape[1:]
+    H, D2 = pool.shape[1], pool.shape[4]
     if R != 4 or H != 2 * G or D2 != 2 * D:
         raise ValueError(f"q {q.shape} does not group over pool {pool.shape}")
-    # two heads, one buffer: the bytes of one head double-buffered
-    _check_pool_fits_vmem(num_pages, page_size, D, pool.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(G, B),
-        in_specs=[
-            pl.BlockSpec((None, None, 4, D),
-                         lambda g, b, *refs: (b, g, 0, 0)),
-            pl.BlockSpec((None, 2, num_pages, page_size, D2),
-                         lambda g, b, pt, nv, l: (l[0], g, 0, 0, 0),
-                         pipeline_mode=pl.Buffered(1)),
-        ],
-        out_specs=pl.BlockSpec((None, None, 4, 2 * D2),
-                               lambda g, b, *refs: (b, g, 0, 0)),
-    )
-    kernel = functools.partial(
-        _paged_diff_kernel, page_size=page_size, scale=1.0 / np.sqrt(D),
-        window=int(window), ring=bool(ring),
-        pages_per_step=int(pages_per_step))
-    o = pl.pallas_call(
-        kernel,
+    o = _paged_walk_call(
+        functools.partial(_paged_diff_kernel, window=int(window),
+                          ring=bool(ring)),
         # a trace reduction tells the two uses apart by name
-        name="paged_diff_attention_ring" if ring else "paged_diff_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, 4, 2 * D2), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=PAGED_VMEM_LIMIT_BYTES),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), _per_seq_n_valid(n_valid, B),
-      _layer_index(layer), q.astype(pool.dtype), pool)
+        "paged_diff_attention_ring" if ring else "paged_diff_attention",
+        _paged_walk_scalars(page_table, n_valid, layer, B),
+        q.astype(pool.dtype), pool, jnp.float32, interpret)
     return jnp.concatenate([o[..., D:D2], o[..., D2 + D:]], axis=-1)
 
 

@@ -103,11 +103,15 @@ def _cases():
              ((2, 25, n_pages, 16, 128), jnp.bfloat16),
              ((16, 64), I32), ((16,), I32), ((), I32)]))
     # grouped differential attention (models.sambay) at the benchmark's
-    # geometry: 16 slots, 10 K/V pairs of 64, a shared pool of 7169 pages
-    # (the VMEM limit's reach) and the window layers' rings of 33
+    # geometry: 16 slots, 10 K/V pairs of 64, the shared pool of 7169 pages
+    # and the window layers' rings of 33; and each over a pool no VMEM
+    # could map, 16385 pages a head
     for name, pool, width, kw in (
             ("shared", (1, 20, 7169, 16, 128), 448, {}),
             ("ring", (8, 20, 529, 16, 128), 33,
+             {"window": 512, "ring": True}),
+            ("shared-16385pages", (1, 20, 16385, 16, 128), 1024, {}),
+            ("ring-16385pages", (8, 20, 16385, 16, 128), 33,
              {"window": 512, "ring": True})):
         out.append((
             f"paged_diff_attention-{name}",

@@ -160,6 +160,35 @@ def test_engine_serves_it_by_the_same_entry_points(params):
                              for p, new in asked)
     assert eng.debug_snapshot()["cache"]["attended_tokens"] == (
         stats["attended_tokens"])
+    # and fetched them in whole blocks (32 pages of 4 here: no slot gets
+    # past its first), so the fill share is depth / 128 and window / 128
+    steps = 2 * sum(new - 1 for _, new in asked)
+    assert stats["fetched_tokens"] == {"shared_kv": 128 * steps,
+                                       "window_kv": 128 * steps}
+    assert eng.debug_snapshot()["cache"]["fetched_tokens"] == (
+        stats["fetched_tokens"])
+
+
+def test_fetched_tokens_are_the_walks_whole_blocks():
+    """What cache_stats() adds per decode step, against hand-made depths
+    at the benchmark's geometry (pages of 16, a block of 8, window 512,
+    eight layers reading each kind): the shared cache from page 0, a ring
+    from the first page its window reaches."""
+    cfg = sambay.SambaYConfig(window=512, n_layers=32)
+    prog = cfg.paged_programs()
+    assert (1 + cfg.n_cross_pairs, cfg.n_self_pairs) == (8, 8)
+    for depth, shared, ring in (
+            (1, 1, 1), (128, 1, 1), (129, 2, 2), (512, 4, 4),
+            (513, 5, 5),      # tokens 1..512: pages 0..32, 33 pages
+            (528, 5, 4),      # tokens 16..527: pages 1..32, 32 pages
+            (3500, 28, 5), (7168, 56, 4)):
+        assert prog.fetched([depth], 16) == {
+            "shared_kv": 8 * 128 * shared, "window_kv": 8 * 128 * ring}
+    assert prog.fetched([1, 513, 3500], 16) == {
+        "shared_kv": 8 * 128 * (1 + 5 + 28), "window_kv": 8 * 128 * 11}
+    assert prog.fetched((), 16) == {"shared_kv": 0, "window_kv": 0}
+    # a page as long as a block is a block by itself
+    assert prog.fetched([300], 256)["shared_kv"] == 8 * 256 * 2
 
 
 def test_engine_programs_leave_each_tokens_row_on_the_device(params):
@@ -263,27 +292,50 @@ def _dense(q, pool, table, n_valid, layer, window, ring):
     return out
 
 
-@pytest.mark.parametrize("groups,window,ring,width,per_step,depths", [
-    (1, 0, False, 6, 1, (1, 7, 23)),      # the pair: two keys, one value
-    (3, 0, False, 6, 1, (4, 5, 24)),      # groups of query heads per K/V pair
-    (2, 5, False, 6, 2, (3, 5, 22)),      # a window: the walk's first page
-    (2, 8, True, 3, 1, (9, 13, 100)),     # the ring: pages modulo its width
-    (2, 6, True, 3, 4, (1, 12, 57)),      # several pages per loop step
-], ids=["pair", "group", "window", "ring", "pages_per_step"])
+# at page 4 a block is 32 pages, 128 tokens: the wide tables span three
+# blocks and more
+@pytest.mark.parametrize("groups,window,ring,width,depths,dtype", [
+    (1, 0, False, 6, (1, 7, 23), "float32"),   # the pair: two keys, one value
+    (3, 0, False, 6, (4, 5, 24), "float32"),   # groups of query heads per pair
+    (2, 5, False, 6, (3, 5, 22), "float32"),   # a window: the first page
+    (2, 8, True, 3, (9, 13, 100), "float32"),  # the ring: pages modulo width
+    (2, 6, True, 3, (1, 12, 57), "float32"),   # the ring from its first token
+    (2, 0, False, 100, (128, 256, 384), "float32"),  # whole blocks exactly
+    (2, 0, False, 100, (129, 300, 0, 399), "float32"),  # a dead slot beside
+    # a window whose first token lies mid-block and mid-page
+    (2, 150, False, 100, (151, 215, 398), "float32"),
+    # a ring of 40 pages under a window of 150: the walk wraps inside a
+    # block (pages 38, 39, 0, 1, ...), and far past the first wrap
+    (2, 150, True, 40, (158, 407, 0, 1201), "float32"),
+    # as the engine sends them: a bfloat16 pool, float32 queries
+    (2, 0, False, 100, (130, 257, 399), "bfloat16"),
+    (2, 150, True, 40, (158, 407, 1201), "bfloat16"),
+], ids=["pair", "group", "window", "ring", "ring_short", "block_multiple",
+        "dead_slot", "window_mid_block", "ring_wraps_in_block", "bf16_pool",
+        "bf16_ring"])
 def test_paged_diff_attention_matches_dense(groups, window, ring, width,
-                                            per_step, depths):
+                                            depths, dtype):
     rng = np.random.default_rng(groups + window)
     B, D, page = len(depths), 8, 4
-    pool = rng.normal(size=(2, 2 * groups, 1 + B * width, page, 2 * D)
-                      ).astype(np.float32)
+    pool = jnp.asarray(
+        rng.normal(size=(2, 2 * groups, 1 + B * width, page, 2 * D)
+                   ).astype(np.float32), dtype)
     table = 1 + np.arange(B * width, dtype=np.int32).reshape(B, width)
     q = rng.normal(size=(B, groups, 4, D)).astype(np.float32)
     n_valid = np.asarray(depths, np.int32)
-    got = pk.paged_diff_attention(
-        jnp.asarray(q), jnp.asarray(pool), table, n_valid, 1, window=window,
-        ring=ring, pages_per_step=per_step)
-    want = _dense(q, pool, table, n_valid, 1, window, ring)
-    assert np.abs(np.asarray(got) - want).max() < 1e-5
+    got = np.asarray(pk.paged_diff_attention(
+        jnp.asarray(q), pool, table, n_valid, 1, window=window, ring=ring))
+    # the oracle reads what the kernel reads: the pool's rounding of the
+    # rows and of the queries
+    cast = lambda x: np.asarray(jnp.asarray(x, dtype).astype(jnp.float32))
+    live = n_valid > 0
+    want = _dense(cast(q)[live], cast(pool), table[live], n_valid[live], 1,
+                  window, ring)
+    # bfloat16 probabilities in the second product, as the kernel casts them
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert np.abs(got[live] - want).max() < tol
+    # a dead slot costs no copy and no loop step, and reads as zeros
+    assert np.all(got[~live] == 0)
 
 
 @pytest.mark.parametrize("start,n_write,n_rows", [

@@ -186,27 +186,6 @@ def test_paged_decode_walks_blocks(walk, Q, monkeypatch):
     assert np.all(got[0] == 0)
 
 
-def test_paged_pool_too_large_for_vmem_raises():
-    """A pool whose per-head block cannot sit in VMEM is an error naming
-    the largest pool that fits — never a silent dense fallback — for the
-    kernel that maps it there, paged_diff_attention. paged_decode_attention
-    leaves the pool in HBM and takes one of any size."""
-    from incubator_mxnet_tpu.ops import pallas_kernels as pk
-
-    H, D, ps = 2, 64, 16
-    fits = (pk.PAGED_VMEM_LIMIT_BYTES - pk._PAGED_VMEM_RESERVE_BYTES) \
-        // (pk.paged_pool_vmem_bytes(1, ps, D, jnp.bfloat16))
-    assert fits == 7680
-    pool = jax.ShapeDtypeStruct((1, H, fits + 1, ps, 2 * D), jnp.bfloat16)
-    q = jax.ShapeDtypeStruct((1, 1, 4, D), jnp.float32)
-    pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
-    nv = jax.ShapeDtypeStruct((1,), jnp.int32)
-    with pytest.raises(ValueError, match=f"at most {fits} such pages"):
-        jax.eval_shape(pk.paged_diff_attention, q, pool, pt, nv)
-    ok = jax.ShapeDtypeStruct((1, H, fits, ps, 2 * D), jnp.bfloat16)
-    jax.eval_shape(pk.paged_diff_attention, q, ok, pt, nv)
-
-
 def test_paged_decode_takes_a_pool_past_the_vmem_limit():
     """8 x 7680 pages a head: the walk gathers a slot's pages from HBM, so
     the pool's size is no concern of the kernel's."""
@@ -217,6 +196,20 @@ def test_paged_decode_takes_a_pool_past_the_vmem_limit():
     nv = jax.ShapeDtypeStruct((1,), jnp.int32)
     out = jax.eval_shape(paged_decode_attention, q, pool, pt, nv)
     assert out.shape == (1, H, D)
+
+
+def test_paged_diff_takes_a_pool_past_the_vmem_limit():
+    """The same for paged_diff_attention, which mapped a pair of heads'
+    whole pool into VMEM until PR 31 and refused more than 7680 pages."""
+    from incubator_mxnet_tpu.ops.pallas_kernels import paged_diff_attention
+
+    H, D, ps = 2, 64, 16
+    pool = jax.ShapeDtypeStruct((1, H, 8 * 7680, ps, 2 * D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, H // 2, 4, D), jnp.float32)
+    pt = jax.ShapeDtypeStruct((1, 4), jnp.int32)
+    nv = jax.ShapeDtypeStruct((1,), jnp.int32)
+    out = jax.eval_shape(paged_diff_attention, q, pool, pt, nv)
+    assert out.shape == (1, H // 2, 4, 2 * D)
 
 
 # -- the carried pool: paged programs against the dense cache ------------------
