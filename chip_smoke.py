@@ -116,6 +116,12 @@ class Sizes:
     # table width, pages of the shared pool (past the 7680 a VMEM-mapped
     # pool could have), pages of a slot's ring, window
     diff_geometry: tuple = (16, 10, 448, 8193, 33, 512)
+    # grouped-query attention and the head-wise recurrence at
+    # falcon_h1_34b's geometry: slots, K/V heads, query heads a K/V head,
+    # head width, table width, pages of the pool; Mamba heads, their
+    # width, the state's, groups
+    hybrid_geometry: tuple = (32, 4, 5, 128, 256, 8193, 32, 128, 256, 2)
+    ssd_chunk: int = 128
     epilogue_shapes: tuple = ((128 * 56 * 56, 64), (128 * 7 * 7, 2048))
     interpret: bool = False
 
@@ -335,6 +341,42 @@ def _selective_scan_ref(dt, a, Bm, Cm, A, s0):
     return y.swapaxes(0, 1), sT
 
 
+def _ssd_ref(x, dt, A, B, C, s0):
+    """Mamba-2's recurrence row by row: x (S, T, H, P), dt (S, T, H),
+    A (H,), B, C (S, T, G, N), s0 (S, H, P, N). Returns (y, the state)."""
+    import jax
+    import jax.numpy as jnp
+
+    hg = x.shape[2] // B.shape[2]
+
+    def token(s, xs):
+        x_t, dt_t, b_t, c_t = xs
+        b_t, c_t = (jnp.repeat(a, hg, axis=1) for a in (b_t, c_t))
+        s = (jnp.exp(dt_t * A)[..., None, None] * s
+             + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None])
+        return s, jnp.einsum("shpn,shn->shp", s, c_t)
+
+    sT, y = jax.lax.scan(token, s0, tuple(
+        a.swapaxes(0, 1) for a in (x, dt, B, C)))
+    return y.swapaxes(0, 1), sT
+
+
+def _gqa_decode_ref(q, pool, table, n_valid):
+    """q (B, H * g, D) over one layer's pool (H, P, ps, 2D), query head j
+    reading K/V head j // g; a dead slot reads as zeros."""
+    import jax
+    import jax.numpy as jnp
+
+    H, D = pool.shape[0], q.shape[-1]
+    k, v = (_gather_pages(a, table) for a in (pool[..., :D], pool[..., D:]))
+    s = jnp.einsum("bhgd,bthd->bhgt", q.reshape(q.shape[0], H, -1, D),
+                   k) / (D ** 0.5)
+    s = jnp.where(jnp.arange(k.shape[1])[None, None, None]
+                  < n_valid[:, None, None, None], s, -1e30)
+    out = jnp.einsum("bhgt,bthd->bhgd", jax.nn.softmax(s, -1), v)
+    return out.reshape(q.shape) * (n_valid > 0)[:, None, None]
+
+
 def _gather_pages(pool, table):
     """(H, P, ps, D) pool + (B, W) table -> dense (B, W*ps, H, D) cache."""
     g = pool[:, table]                       # (H, B, W, ps, D)
@@ -351,7 +393,7 @@ def phase_kernels(sz):
     ip = sz.interpret
     f32 = jnp.float32
     orc = _Oracle()
-    key = iter(jax.random.split(jax.random.PRNGKey(0), 128))
+    key = iter(jax.random.split(jax.random.PRNGKey(0), 192))
 
     def rand(shape, dtype, scale=1.0):
         return (jax.random.normal(next(key), shape, f32) * scale).astype(dtype)
@@ -523,6 +565,53 @@ def phase_kernels(sz):
         orc.close(f"selective_scan[S={S},T={T}]",
                   pk.selective_scan(*args, interpret=ip),
                   oracle(_selective_scan_ref, *args), f32)
+
+    # models.falcon_h1's decode step at the benchmark's geometry: five
+    # float32 query rows a K/V head of 128 over a bfloat16 pool, K|V fused
+    # in 256 lanes, slots to the table's full depth beside a shallow and a
+    # dead one; every live slot's (heads, 128, 256) state through one
+    # layer's recurrence in place, a dead slot's untouched; and a prompt's
+    # chunked scan against the row-by-row one, its true length mid-chunk
+    S, Hkv, g, D, W, P, Hs, Pd, N, G = sz.hybrid_geometry
+    ps = sz.page_size
+    q = rand((S, Hkv * g, D), f32)
+    pool = rand((2, Hkv, P, ps, 2 * D), jnp.bfloat16)
+    depth = jax.random.randint(next(key), (S,), 1, W * ps + 1)
+    depth = depth.at[0].set(W * ps).at[1].set(1).at[2].set(0)
+    table = (1 + jax.random.permutation(next(key), P - 1)[:S * W]
+             ).reshape(S, W).astype(jnp.int32).at[2].set(0)
+    orc.close(f"paged_decode_attention[cell,{g} rows x {D},{P} pages]",
+              pk.paged_decode_attention(q, pool, table, depth, 1,
+                                        interpret=ip),
+              oracle(_gqa_decode_ref, q, pool[1].astype(f32), table, depth),
+              jnp.bfloat16)
+    A = -jnp.exp(rand((Hs,), f32))
+
+    def rows(T):
+        return (rand((S, T, Hs, Pd), f32),
+                jax.nn.softplus(rand((S, T, Hs), f32) - 3.0), A,
+                rand((S, T, G, N), f32), rand((S, T, G, N), f32))
+
+    # dead slots before the first live one and between live ones
+    state, live = rand((2, S, Hs, Pd, N), f32), (depth > 0).at[0].set(False)
+    step = rows(1)
+    want_y, want_s = oracle(_ssd_ref, *step, state[1])
+    keep = live[:, None, None, None]
+    orc.close("ssd_state_update[cell]",
+              pk.ssd_state_update(state, 1, live, *(
+                  a if a.ndim == 1 else a[:, 0] for a in step),
+                  interpret=ip),
+              (jnp.where(keep[..., 0], want_y[:, 0], 0.0),
+               state.at[1].set(jnp.where(keep, want_s, state[1]))), f32)
+    prompt = tuple(a[:2] if a.ndim > 1 else a
+                   for a in rows(2 * sz.ssd_chunk))
+    real = jnp.asarray([sz.ssd_chunk + 5, 2 * sz.ssd_chunk])
+    prompt = (prompt[0], jnp.where(
+        jnp.arange(2 * sz.ssd_chunk)[None, :, None] < real[:, None, None],
+        prompt[1], 0.0)) + prompt[2:]
+    orc.close(f"ssd_chunk_scan[T={2 * sz.ssd_chunk}]",
+              pk.ssd_chunk_scan(*prompt, sz.ssd_chunk),
+              oracle(_ssd_ref, *prompt, jnp.zeros((2, Hs, Pd, N), f32)), f32)
 
     # bn_act_epilogue at ResNet-50's first and last stage, bf16 (no dots)
     for r, c in sz.epilogue_shapes:

@@ -568,6 +568,10 @@ class SambaYPrograms:
     # fixed-size state that a lever would have to snapshot and restore:
     # the engine refuses prefix cache, chunked prefill and speculation
     recurrent_state = True
+    # the engine reads each decode step before it dispatches the next:
+    # benchmark/jobs/serve_hybrid.py's check reads the row of the token
+    # just delivered from the cache after every step() (ROADMAP S7)
+    decode_ahead = False
 
     def __init__(self, cfg):
         self.cfg = cfg

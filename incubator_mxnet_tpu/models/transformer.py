@@ -544,6 +544,9 @@ class TransformerPrograms:
 
     # no fixed-size state: every lever's rollback is a page-table write
     recurrent_state = False
+    # the engine reads each decode step before it dispatches the next
+    # (serving/engine.py `_runs_ahead`; ROADMAP S7 decides it for this model)
+    decode_ahead = False
 
     def __init__(self, cfg):
         self.cfg = cfg

@@ -23,7 +23,8 @@ __all__ = ["flash_attention", "softmax_xent", "flash_decode",
            "dense_decode_attention", "paged_decode_attention",
            "paged_decode_attention_wide", "paged_kv_write",
            "paged_write_plan", "paged_diff_attention",
-           "paged_ring_write_plan", "selective_scan",
+           "paged_ring_write_plan", "selective_scan", "ssd_state_update",
+           "ssd_chunk_scan",
            "bn_act_epilogue",
            "DECODE_BLOCK", "DENSE_FALLBACKS_TOTAL"]
 
@@ -1007,11 +1008,14 @@ def _paged_block_walk(pt_ref, pool_ref, buf, sem, b, layer, h0, first,
 
 
 def _paged_decode_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref,
-                         buf, sem, *, page_size, scale):
+                         buf, sem, *, page_size, scale, group=1):
     """One (b, g) grid step: slot b, head group g, Q query rows at
     consecutive positions whose LAST row attends nv tokens (row i attends
     idx < nv - (Q - 1 - i): the paged prefix plus causal masking within
     the call; nv == 0 is a dead slot, which fetches and computes nothing).
+    With `group` > 1 a K/V head serves `group` query heads (grouped-query
+    attention): its rows are position-major, row r the query of position
+    r // group, and the Q above is their number of positions.
 
     pt_ref (B, W), nv_ref (B,) and l_ref (1,) are scalar-prefetch refs
     (SMEM: control flow and the DMA's page indices). q_ref is (Hg, Q, d);
@@ -1037,7 +1041,9 @@ def _paged_decode_kernel(pt_ref, nv_ref, l_ref, q_ref, pool_ref, o_ref,
     q = q_ref[...]
     row = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, span), 2)
-    limit = jnp.minimum(nv_ref[b] - (n_q - 1) + row, cap)
+    if group > 1:
+        row = row // group
+    limit = jnp.minimum(nv_ref[b] - (n_q // group - 1) + row, cap)
 
     def step(j, rows, carry):
         s = jax.lax.dot_general(
@@ -1143,17 +1149,30 @@ def _paged_walk_call(kernel, name, scalars, q, pool, out_dtype, interpret):
 
 
 def _paged_decode_call(q, pool, page_table, n_last, layer, interpret):
-    """q (B, Q, H, D) over pool (L, H, num_pages, page_size, 2*D) in layer
-    `layer`; n_last (B,): tokens the last query row of each slot attends.
-    Returns (B, Q, H, D)."""
-    B, Q, _, D = q.shape
+    """q (B, Q, Hq, D) over pool (L, H, num_pages, page_size, 2*D) in layer
+    `layer`, query head j reading K/V head j // (Hq // H); n_last (B,):
+    tokens the last query row of each slot attends. Returns (B, Q, Hq, D)."""
+    B, Q, Hq, D = q.shape
+    H = pool.shape[1]
+    group = Hq // H
+    if Hq != H * group:
+        raise ValueError(f"{Hq} query heads do not group over {H} K/V heads")
+    scalars = _paged_walk_scalars(page_table, n_last, layer, B)
+    kernel, rows = _paged_decode_kernel, q.transpose(0, 2, 1, 3)
+    if group > 1:
+        # a K/V head's rows: its queries at position 0, then position 1, ...
+        kernel = functools.partial(kernel, group=group)
+        rows = (q.reshape(B, Q, H, group, D).transpose(0, 2, 1, 3, 4)
+                .reshape(B, H, Q * group, D))
     o = _paged_walk_call(
-        _paged_decode_kernel,
+        kernel,
         # the engine's decode step is the Q = 1 case
         "paged_decode_attention" if Q == 1 else "paged_decode_attention_wide",
-        _paged_walk_scalars(page_table, n_last, layer, B),
-        q.transpose(0, 2, 1, 3), pool, q.dtype, interpret)
-    return o[..., D:].transpose(0, 2, 1, 3)  # the V lanes
+        scalars, rows, pool, q.dtype, interpret)[..., D:]  # the V lanes
+    if group > 1:
+        return (o.reshape(B, H, Q, group, D).transpose(0, 2, 1, 3, 4)
+                .reshape(B, Q, Hq, D))
+    return o.transpose(0, 2, 1, 3)
 
 
 def paged_decode_attention_wide(q, pool, page_table, n_base, layer=0,
@@ -1195,12 +1214,15 @@ def paged_decode_attention(q, pool, page_table, n_valid, layer=0,
     with Q = 1, under the name a trace reduction finds the engine's
     decode step by.
 
-    q: (B, H, D) — one query per decode slot; pool:
-    (L, H, num_pages, page_size, 2*D), read in layer `layer`;
+    q: (B, Hq, D) — one query per decode slot and query head; pool:
+    (L, H, num_pages, page_size, 2*D), read in layer `layer`, Hq a
+    multiple of H (grouped-query attention: query head j reads K/V head
+    j // (Hq // H), a K/V head's Hq // H query rows scored in one
+    product, its rows fetched once);
     page_table: (B, P_max) int32; n_valid: (B,) int32 (or scalar) —
     tokens live per slot INCLUDING the one just written; 0 marks a dead
     slot, which costs no copy and no loop step (its output is zeros that
-    the caller discards). Returns (B, H, D)."""
+    the caller discards). Returns (B, Hq, D)."""
     return _paged_decode_call(q[:, None], pool, page_table, n_valid, layer,
                               interpret)[:, 0]
 
@@ -1352,3 +1374,185 @@ def selective_scan(dt, a, B, C, A, s0, interpret=None):
     )(dt.astype(f32), a.astype(f32), B.astype(f32)[..., None],
       C.astype(f32)[..., None], A.astype(f32), s0.astype(f32))
     return y, sT
+
+
+# ---------------------------------------------------------------------------
+# State-space duality (Mamba-2's recurrence): one scalar decay per head,
+# B_t and C_t shared by a group of heads, a state S (H, P, N) per sequence,
+# float32:  S_t = exp(dt_t A) S_{t-1} + (dt_t x_t) (outer) B_t,  y_t = S_t C_t.
+# With P = 128 and N = 256 the state is 4 MB a slot and layer, as many bytes
+# as 2048 tokens of K/V: a decode step is the state in and out
+# (ssd_state_update, in place in the cache's one array for every layer), and
+# a prompt goes through it in chunks as matrix products (ssd_chunk_scan).
+# ---------------------------------------------------------------------------
+
+SSD_HEAD_BLOCK = 8
+
+
+def _ssd_state_kernel(l_ref, src_ref, live_ref, cols_ref, b_ref, c_ref,
+                      s_ref, y_ref, out_ref):
+    """One (slot, head block) grid step. s_ref, out_ref (hb, P, N): the
+    block's heads of the slot's state, in and out through one buffer;
+    cols_ref (2, P, H): exp(dt A) and dt x with the heads on the lanes, so
+    that a head's column broadcasts over the state's lanes; b_ref, c_ref
+    (1, N): the block's group's rows; y_ref (P, H), revisited by the
+    slot's head blocks.
+
+    A dead slot moves nothing: all its steps point at ONE block of a live
+    slot (src_ref; ssd_state_update says which), the block of the step
+    before or after them, so the pipeline neither fetches nor stores for
+    them, and they leave the output buffer as the live step left it, or
+    will find it. Only before the first live slot is there no such
+    buffer yet: there the block goes out as it came in."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    hb = s_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, y_ref.shape, 1)
+    live = live_ref[s] != 0
+
+    @pl.when(j == 0)
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(jnp.logical_not(live) & (src_ref[s] >= s))
+    def _():
+        out_ref[...] = s_ref[...]
+
+    @pl.when(live)
+    def _():
+        decay, drive = cols_ref[0], cols_ref[1]
+        b, c = b_ref[...], c_ref[...]
+        y = y_ref[...]
+        for i in range(hb):
+            mine = lane == j * hb + i
+
+            def column(a):
+                return jnp.sum(jnp.where(mine, a, 0.0), axis=1,
+                               keepdims=True)                     # (P, 1)
+
+            new = column(decay) * s_ref[i] + column(drive) * b    # (P, N)
+            out_ref[i] = new
+            y = jnp.where(mine, jnp.sum(new * c, axis=1, keepdims=True), y)
+        y_ref[...] = y
+
+
+def ssd_state_update(state, layer, live, x, dt, A, B, C, interpret=None):
+    """One token of every live sequence through one layer's recurrence.
+
+    state: (L, S, H, P, N) float32, the cache's states of every layer,
+    read and written in layer `layer` (int32 scalar, traced in the layer
+    loop) in place (input_output_aliases: donate it and carry it);
+    live: (S,) bool — a dead slot's state moves neither in nor out, and
+    its y is zeros; x: (S, H, P); dt: (S, H), after the softplus;
+    A: (H,), negative; B, C: (S, G, N), head h reading group h // (H // G).
+
+    Returns (y (S, H, P) float32 = S_new C, the state)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    _, S, H, P, N = state.shape
+    G = B.shape[1]
+    hb = min(SSD_HEAD_BLOCK, H // G)
+    if H % G or (H // G) % hb:
+        raise ValueError(f"{H} heads in {G} groups do not split into "
+                         f"blocks of {hb}")
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    decay = jnp.exp(dt * A.astype(f32))                             # (S, H)
+    cols = jnp.stack([jnp.broadcast_to(decay[:, None, :], (S, P, H)),
+                      (dt[..., None] * x.astype(f32)).transpose(0, 2, 1)],
+                     axis=1)                                        # (S,2,P,H)
+    # the one block a dead slot's steps point at: the LAST block of the
+    # live slot before it (the step before: the same block twice in a row
+    # is neither fetched nor stored again), else the FIRST block of the
+    # first live slot (the step after)
+    slot = jnp.arange(S, dtype=jnp.int32)
+    live = live.astype(jnp.int32)
+    before = jax.lax.cummax(jnp.where(live != 0, slot, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    n_blocks = H // hb
+
+    def state_index(s, j, l, src, live):
+        j = jnp.where(live[s] != 0, j,
+                      jnp.where(src[s] < s, n_blocks - 1, 0))
+        return l[0], src[s], j, 0, 0
+
+    state_spec = pl.BlockSpec((None, None, hb, P, N), state_index)
+    group_spec = pl.BlockSpec(
+        (None, None, 1, N),
+        lambda s, j, *refs: (s, j * hb // (H // G), 0, 0))
+    yT, state = pl.pallas_call(
+        _ssd_state_kernel,
+        name="ssd_state_update",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, n_blocks),
+            in_specs=[pl.BlockSpec((None, 2, P, H),
+                                   lambda s, j, *refs: (s, 0, 0, 0)),
+                      group_spec, group_spec, state_spec],
+            out_specs=[pl.BlockSpec((None, P, H),
+                                    lambda s, j, *refs: (s, 0, 0)),
+                       state_spec]),
+        out_shape=[jax.ShapeDtypeStruct((S, P, H), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # operand 6 (after the three scalar-prefetch arrays, the columns
+        # and the groups' rows) is the state, and it is output 1
+        input_output_aliases={6: 1},
+        interpret=interpret,
+    )(_layer_index(layer), src, live, cols,
+      B.astype(f32)[:, :, None], C.astype(f32)[:, :, None], state)
+    return yT.transpose(0, 2, 1), state
+
+
+def ssd_chunk_scan(x, dt, A, B, C, chunk):
+    """A prompt through the recurrence from an empty state, in chunks of
+    `chunk` rows as matrix products (the SSD form of arXiv:2405.21060).
+
+    x: (S, T, H, P); dt: (S, T, H), after the softplus, 0 on a row that
+    is padding (exp(0 A) = 1, 0 B = 0: it leaves the state as it is, so
+    the state returned is the one after the last REAL row); A: (H,),
+    negative; B, C: (S, T, G, N). T is a multiple of `chunk`, or one
+    chunk. With cs the running sum of dt A within a chunk, row i reads
+    the chunk's rows j <= i through ((C B^T) * L) (dt x),
+    L[i, j] = exp(cs_i - cs_j), and the state S the chunk found through
+    exp(cs_i) C_i S; the chunk leaves exp(cs_Q) S
+    + sum_j exp(cs_Q - cs_j) (dt_j x_j) (outer) B_j. Float32 throughout,
+    the products at full precision (on the chip 0.87 ms a 2048-row prompt
+    of 32 heads against 0.56 at the default: PERF.md, PR 32).
+
+    Returns (y (S, T, H, P) = S_t C_t, the state (S, H, P, N) after row
+    T - 1)."""
+    S, T, H, P = x.shape
+    G, N = B.shape[2:]
+    Q = chunk if T % chunk == 0 else T
+    nc, hg = T // Q, H // G
+    f32 = jnp.float32
+    einsum = functools.partial(jnp.einsum, precision="highest",
+                               preferred_element_type=f32)
+    dt = dt.astype(f32)
+    xd = (x.astype(f32) * dt[..., None]).reshape(S, nc, Q, G, hg, P)
+    Bc, Cc = (a.astype(f32).reshape(S, nc, Q, G, N) for a in (B, C))
+    cs = jnp.cumsum((dt * A.astype(f32)).reshape(S, nc, Q, G, hg), axis=2)
+    cs = cs.transpose(0, 1, 3, 4, 2)                       # (S, nc, G, hg, Q)
+
+    # within a chunk
+    back = cs[..., :, None] - cs[..., None, :]             # cs_i - cs_j
+    seen = jnp.tril(jnp.ones((Q, Q), bool))
+    L = jnp.exp(jnp.where(seen, back, -jnp.inf))           # (S,nc,G,hg,Q,Q)
+    CB = einsum("scign,scjgn->scgij", Cc, Bc)
+    y = einsum("scghij,scjghp->scighp", CB[:, :, :, None] * L, xd)
+
+    # what each chunk adds to the state by its end, and the state it finds
+    to_end = jnp.exp(cs[..., -1:] - cs).transpose(0, 1, 4, 2, 3)
+    added = einsum("scjgn,scjghp->scghpn", Bc, xd * to_end[..., None])
+    through = jnp.exp(cs[..., -1])                         # (S, nc, G, hg)
+
+    def carry_on(found, xs):
+        keep, add = xs
+        return keep[..., None, None] * found + add, found
+
+    last, found = jax.lax.scan(
+        carry_on, jnp.zeros((S, G, hg, P, N), f32),
+        (through.swapaxes(0, 1), added.swapaxes(0, 1)))
+    found = found.swapaxes(0, 1)                           # (S,nc,G,hg,P,N)
+    y = y + (einsum("scign,scghpn->scighp", Cc, found)
+             * jnp.exp(cs).transpose(0, 1, 4, 2, 3)[..., None])
+    return y.reshape(S, T, H, P), last.reshape(S, H, P, N)

@@ -24,6 +24,18 @@ Greedy decoding (temperature 0) — token-for-token identical to
 sequential `models.transformer.generate()` per request, which is the
 equivalence CI asserts.
 
+A model whose programs say `decode_ahead` is decoded ONE STEP AHEAD while
+every slot decodes: the step after the one in flight is dispatched (its
+tokens still on the device, its positions one further) before the one in
+flight is read, so the host's turn (the fetch's latency, the bookkeeping,
+the caller's own reading, the next upload and dispatch) runs beside the
+device and not between two of its steps. A step that would end a request
+(its length is known; an `eos_id` is not, so such a request keeps the
+loop synchronous) is never run ahead of, so admissions come exactly when
+they did; the step that starts a run dispatches and returns no token,
+which keeps what the cache holds after `step()` one row apart from what
+was read, never two. Tokens are the synchronous loop's, one for one.
+
 Three OPTIONAL throughput levers stack on this substrate, each
 knob-off byte-identical to the base engine (no extra compiled programs,
 same outputs):
@@ -287,6 +299,9 @@ class ServingEngine:
         # next decode write (-1 = none)
         self._slot_prefill: list[dict | None] = [None] * S
         self._slot_cow_idx = [-1] * S
+        # the decode step dispatched and not yet read, (live slots, tokens
+        # on the device): only for a model that decodes ahead, levers off
+        self._flight = None
         self._queue: deque[Request] = deque()
         self._results: dict[int, RequestResult] = {}
         self._ids = itertools.count()
@@ -1091,34 +1106,74 @@ class ServingEngine:
             _dtrace.record_span(rec)
 
     def _decode_once(self):
-        live_slots = self._decoding_slots()
+        flight, self._flight = self._flight, None
+        live_slots = flight[0] if flight else self._decoding_slots()
         if live_slots:
             with telemetry.span("serving.decode", live=len(live_slots)):
-                self._decode_live(live_slots)
+                if flight is None:
+                    flight = self._launch(live_slots, self._next_tok,
+                                          self._positions)
+                    if self._runs_ahead(live_slots):
+                        # read in the next step, behind the launch of the
+                        # one after it
+                        self._flight = flight
+                        return self.slots_in_use
+                elif self._runs_ahead(live_slots):
+                    ahead = self._positions.copy()
+                    ahead[live_slots] += 1
+                    self._flight = self._launch(live_slots, flight[1], ahead)
+                self._land(*flight)
         return self.slots_in_use
 
-    def _decode_live(self, live_slots):
+    @property
+    def decode_in_flight(self):
+        """Whether a decode step is dispatched and not yet read: the
+        cache then holds what that step left, one token past what
+        `live_tokens()` shows."""
+        return self._flight is not None
+
+    def _runs_ahead(self, live_slots):
+        """Whether the step after the one `live_slots` are in can be
+        dispatched before that one is read: the model's programs say so,
+        every slot decodes (nobody could be admitted in between) and the
+        one in flight ends no request."""
+        if not self.model.decode_ahead or (
+                len(live_slots) < self.slots or self.spec_ngram
+                or self.prefill_chunk or self.prefix_cache is not None):
+            return False
+        return all(
+            self._slot_req[s].eos_id is None and
+            len(self._slot_out[s]) + 1 < self._slot_req[s].max_new_tokens
+            for s in live_slots)
+
+    def _launch(self, live_slots, tokens, positions):
+        """Dispatches one decode step of `live_slots`: `tokens` on the
+        host, or on the device where the step before left them. Returns
+        (live slots, the step's tokens on the device)."""
         if self.prefix_cache is not None:
             for s in live_slots:
                 if self._slot_cow_idx[s] >= 0:
                     self._resolve_cow(s)
         if self._page_san is not None:
-            # the step writes one K/V entry per live slot at _positions[s]
+            # the step writes one K/V entry per live slot at positions[s]
             for s in live_slots:
                 self._page_san.note_write(
                     self._slot_req[s].request_id,
-                    [self._slot_pages[s][int(self._positions[s])
+                    [self._slot_pages[s][int(positions[s])
                                          // self.page_size]])
-        depths = self._positions[live_slots] + 1
+        depths = positions[live_slots] + 1
         for kind, n in self.model.attended(depths).items():
             self._attended[kind] += n
         for kind, n in self.model.fetched(depths, self.page_size).items():
             self._fetched[kind] += n
         with self._h2d:
-            args = (jnp.asarray(self._next_tok),
-                    jnp.asarray(self._positions), jnp.asarray(self._tables))
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(self._tables))
         with self._dispatch:
             tok, self.paged = self._decode(self.params, self.paged, *args)
+        return live_slots, tok
+
+    def _land(self, live_slots, tok):
         with self._fetch:
             tok = np.asarray(tok)
         with self._bookkeep:
@@ -1486,6 +1541,10 @@ class ServingEngine:
                 return True
         for s, req in enumerate(self._slot_req):
             if req is not None and req.request_id == request_id:
+                if self._flight is not None:
+                    # its tokens were made: read them before the slot goes
+                    flight, self._flight = self._flight, None
+                    self._land(*flight)
                 self._finish(s, reason="evicted")
                 self._export_gauges()
                 return True

@@ -15,6 +15,7 @@ TINY = cs.Sizes(
     attn_shape=(1, 2, 32, 16), xent_shape=(16, 128), decode_batch=2,
     decode_len=128, page_size=8, table_width=4, wide_q=(2,),
     diff_geometry=(4, 2, 40, 170, 4, 24),
+    hybrid_geometry=(4, 2, 5, 16, 40, 170, 4, 8, 16, 2), ssd_chunk=8,
     epilogue_shapes=((40, 16),), interpret=True)
 
 
@@ -41,8 +42,9 @@ def test_kernels_phase_compares_every_kernel(clock):
     # per dtype: 4 flash_attention, 2 xent, flash_decode, paged, one wide
     # and the write its rows take, the grouped differential kernel by a
     # table and by a ring; then that kernel twice at the benchmark's
-    # geometry, 2 selective scans and 4 epilogues
-    assert out["compared"] == 2 * 12 + 2 + 2 + 4
+    # geometry, 2 selective scans, the grouped-query decode kernel, the
+    # state update and the chunked scan at theirs, and 4 epilogues
+    assert out["compared"] == 2 * 12 + 2 + 2 + 3 + 4
 
 
 def test_a_kernel_off_its_oracle_fails_the_phase(clock, monkeypatch):

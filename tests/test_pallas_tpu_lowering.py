@@ -126,6 +126,30 @@ def _cases():
             [((S_, T_, 5120), jnp.float32)] * 2
             + [((S_, T_, 16), jnp.float32)] * 2
             + [((16, 5120), jnp.float32), ((S_, 16, 5120), jnp.float32)]))
+    # grouped-query attention at head width 128 and the head-wise
+    # recurrence's decode step at the benchmark's geometry (falcon_h1_34b:
+    # 32 slots, 20 query heads over 4 K/V heads of 128, K|V fused in 256
+    # lanes, 8193 pages of 16, a table 256 wide; a state of (32, 128, 256)
+    # a slot and layer), the layer index traced
+    gqa_pool = [((6, 4, 8193, 16, 256), jnp.bfloat16)]
+    gqa_tail = [((32, 256), I32), ((32,), I32)]
+    out.append((
+        "paged_decode_attention-gqa5x128",
+        lambda q, pool, table, n_valid, layer: pk.paged_decode_attention(
+            q, pool, table, n_valid, layer, interpret=False),
+        [((32, 20, 128), jnp.float32)] + gqa_pool + gqa_tail + [((), I32)]))
+    for name, s_, q in (("decode", 32, 1), ("prompt", 1, 2048)):
+        out.append((
+            f"paged_kv_write-gqa5x128-{name}",
+            functools.partial(_write, q),
+            gqa_pool + [((s_, q, 4, 128), jnp.float32)] * 2
+            + [((s_, 256), I32), ((s_,), I32), ((s_,), I32)]))
+    out.append((
+        "ssd_state_update-S32",
+        functools.partial(pk.ssd_state_update, interpret=False),
+        [((6, 32, 32, 128, 256), jnp.float32), ((), I32), ((32,), jnp.bool_),
+         ((32, 32, 128), jnp.float32), ((32, 32), jnp.float32),
+         ((32,), jnp.float32)] + [((32, 2, 256), jnp.float32)] * 2))
     epi = functools.partial(pk.bn_act_epilogue, interpret=False)
     for r, c in SZ.epilogue_shapes:
         plain = [((r, c), jnp.bfloat16), ((c,), jnp.float32),
@@ -146,8 +170,9 @@ IDS = [c[0] for c in CASES]
 def test_every_public_kernel_has_a_case():
     kernels = {n for n in pk.__all__
                if callable(getattr(pk, n))
+               # plain jax.numpy, no kernel to lower
                and n not in ("dense_decode_attention", "paged_write_plan",
-                             "paged_ring_write_plan")}
+                             "paged_ring_write_plan", "ssd_chunk_scan")}
     covered = {i.split("-")[0] for i in IDS}
     assert kernels == covered, kernels ^ covered
 
