@@ -104,6 +104,8 @@ class Sizes:
                        (130, 5), (17, 20), (200, 11))
     # requests of the float32 engine checked token-for-token vs generate()
     identity_requests: tuple = ((9, 10), (70, 8), (33, 12))
+    # the tied embedding's two ends at gpt2_xl's shape: (decode rows, V, d)
+    head_shape: tuple = (16, 50257, 1600)
     # kernels
     attn_shape: tuple = (2, 8, 512, 64)        # (B, H, T, D)
     xent_shape: tuple = (4096, 32000)          # (rows, vocab)
@@ -240,12 +242,70 @@ def phase_serve(clock, sz):
     fallbacks = (_counter_total(DENSE_FALLBACKS_TOTAL)
                  + _counter_total(tfm.FLASH_DENSE_FALLBACKS_TOTAL))
     check(fallbacks == 0, f"{fallbacks} dense-attention fallback(s) counted")
-    return {"requests": len(wave), "prefill_buckets": buckets,
+    return {**_embedding_ends(sz),
+            "requests": len(wave), "prefill_buckets": buckets,
             "tokens": sum(m for _, m in wave),
             "steady_compiles": steady_compiles,
             "token_identical_requests": len(checked),
             "dense_fallbacks": int(fallbacks),
             "pool_pages": eng.allocator.num_pages}
+
+
+# what float32 rows lose against a bfloat16 table at the default matmul
+# precision, in sigma of a row of logits: the MXU rounds the rows to
+# bfloat16 (measured 0.0084 for 16 rows, 1.2e-6 for one, which is a float32
+# multiply-and-reduce; my chip run, PR 33). The benchmark's check allows 0.05.
+HEAD_SIGMA = 0.02
+
+
+def _embedding_ends(sz):
+    """models.transformer's two uses of the tied embedding at the cell's
+    shape, float32 rows and a bfloat16 table in the layout this backend
+    gives it: _token_rows must be the plain lookup to the bit (16 tokens a
+    decode step, one, a prompt's worth), _logits within HEAD_SIGMA of the
+    same product at precision "highest" (16 rows, and the prefill's one)."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.models import transformer as tfm
+
+    rows, vocab, d = sz.head_shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    params = {
+        "embed": (0.02 * jax.random.normal(ks[0], (vocab, d))
+                  ).astype(jnp.bfloat16),
+        "ln_f_g": (1 + 0.1 * jax.random.normal(ks[1], (d,))
+                   ).astype(jnp.bfloat16),
+        "ln_f_b": (0.1 * jax.random.normal(ks[2], (d,))).astype(jnp.bfloat16),
+    }
+    lookup = jax.jit(tfm._token_rows)
+    for n in (rows, 1, 8 * rows + 1):
+        tokens = jax.random.randint(ks[3], (n,), 0, vocab, jnp.int32)
+        tokens = tokens.at[0].set(vocab - 1)  # the last, short slab
+        same = jnp.array_equal(lookup(params["embed"], tokens),
+                               params["embed"][tokens])
+        check(bool(same), f"_token_rows differs from the plain lookup "
+                          f"for {n} token(s)")
+
+    head = jax.jit(tfm._logits)
+
+    @jax.jit
+    def exact(p, x):
+        return jnp.matmul(tfm._ln(x, p["ln_f_g"], p["ln_f_b"]),
+                          p["embed"].T, precision="highest")
+
+    worst = {}
+    for n in (rows, 1):
+        x = 3.0 * jax.random.normal(ks[4], (n, d), jnp.float32) + 0.5
+        got, want = head(params, x), exact(params, x)
+        sigma = float(jnp.max(jnp.max(jnp.abs(got - want), -1)
+                              / jnp.std(want, -1)))
+        print(f"  head _logits[{n} x {d} . {vocab} x {d}]: {sigma:.2e} sigma "
+              f"of a row from precision=highest (tol {HEAD_SIGMA:.0e})",
+              flush=True)
+        check(sigma <= HEAD_SIGMA, f"_logits of {n} row(s) is {sigma:.3e} "
+                                   f"sigma from precision=highest")
+        worst[n] = sigma
+    return {"head_sigma_rows": worst[rows], "head_sigma_one_row": worst[1]}
 
 
 # ---------------------------------------------------------------------------

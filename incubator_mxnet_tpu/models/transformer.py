@@ -205,13 +205,70 @@ def _dense_attention(q, k, v, causal=True):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+# tokens a slab: the lanes of one TPU tile
+_SLAB = 128
+# the most tokens _token_rows looks up slab by slab. On the v5e the slabs
+# cost 3.4 us a token (16 tokens, 54 us) and the gather's re-layout of
+# GPT-2 XL's table 496 us whatever the count (my chip run, PR 33)
+_SLAB_LOOKUP_MAX_TOKENS = 128
+
+
+def _token_rows(table, tokens):
+    """table[tokens], read where the table lies: table (V, d), tokens
+    (...) int -> (..., d), the same rows as the plain lookup for every
+    index (negative ones wrap, others past the table clamp).
+
+    At the jit boundary the TPU runtime gives an array the layout that
+    pads least, by its shape alone (PERF.md section 6, PR 26). A table
+    whose rows are not whole lanes (d = 1600: 12.5 of them) lies
+    VOCABULARY-minor there, as (d, V). The logits product reads that as
+    it is, but XLA's gather wants rows, and copies all of the table to
+    get them: 161 MB read and written in every decode step to fetch 16
+    rows of GPT-2 XL's. So a lookup of few tokens takes, per token, the
+    slab of 128 tokens its row lies in (whole tiles of that layout) and
+    its row out of the slab: no program copies the table. A lookup of
+    many tokens (a prompt, a training batch) would read more in slabs
+    than the copy moves and stays the plain gather, to the letter; so
+    does a table whose rows are whole lanes, which lies row-major, and
+    one of a slab or less."""
+    V, d = table.shape
+    if (d % _SLAB == 0 or V <= _SLAB
+            or tokens.size > _SLAB_LOOKUP_MAX_TOKENS):
+        return table[tokens]
+    return _slab_rows(table, tokens)
+
+
+@jax.custom_jvp
+def _slab_rows(table, tokens):
+    """_token_rows' lookup by slabs: per token a (128, d) slice of the
+    table at a multiple of 128 (the last one ends with the table), then
+    the token's row of it."""
+    V, d = table.shape
+
+    def row(t):
+        t = jnp.clip(jnp.where(t < 0, t + V, t), 0, V - 1)
+        t0 = jnp.minimum(t // _SLAB * _SLAB, V - _SLAB)
+        slab = lax.dynamic_slice(table, (t0, 0), (_SLAB, d))
+        return lax.dynamic_slice(slab, (t - t0, 0), (1, d))[0]
+
+    return jax.vmap(row)(tokens.reshape(-1)).reshape(*tokens.shape, d)
+
+
+@_slab_rows.defjvp
+def _slab_rows_jvp(primals, tangents):
+    # linear in the table: differentiate the plain lookup, whose transpose
+    # is one scatter-add, not a table-sized update per token
+    table, tokens = primals
+    return _slab_rows(table, tokens), tangents[0][tokens]
+
+
 def _embed(params, tokens, pos):
     """Token rows plus positional rows: tokens (S, T) -> x (S, T, d).
     `pos` is where the tokens sit: an (S, T) int array, one position per
     token (one past the table reads row 0: such a row's output is the
     caller's to discard), or the position of every row's first token, a
     Python int or a traced scalar."""
-    x, table = params["embed"][tokens], params["pos"]
+    x, table = _token_rows(params["embed"], tokens), params["pos"]
     T = tokens.shape[1]
     if jnp.ndim(pos):
         return x + table[jnp.where(pos < table.shape[0], pos, 0)]
@@ -258,7 +315,20 @@ def _cacheless(attn_fn):
 
 def _logits(params, x):
     """Final LayerNorm and the product with the tied embedding:
-    (..., d) -> (..., V)."""
+    (..., d) -> (..., V), float32 rows against the table as it is stored.
+
+    The operand order is XLA's to choose, not this line's: `h @ E.T`,
+    `dot_general(h, E, (((1,), (1,)), ((), ())))` and
+    `dot_general(E, h, ...).T` compile to one and the same convolution
+    over the table where it lies (vocabulary-minor for GPT-2 XL's shape,
+    see _token_rows), with or without the serving engine's argmax fused
+    in as its root, and give the same bits; 16 rows against
+    (50257, 1600) bf16 take 217 us on a v5e, 741 GB/s (my chip run,
+    PR 33). So one statement serves every program, from one decode row to
+    a training batch, and no row count is tested. At the default
+    precision the MXU rounds float32 rows to the table's bfloat16 (0.008
+    sigma of a row against precision="highest"); a single row is a
+    float32 multiply-and-reduce and is not rounded."""
     return _ln(x, params["ln_f_g"], params["ln_f_b"]) @ params["embed"].T
 
 

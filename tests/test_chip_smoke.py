@@ -12,6 +12,7 @@ TINY = cs.Sizes(
     batch=8, image=32, steps=2, scan_k=2,
     vocab=128, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=64,
     requests=((5, 4), (20, 2), (12, 5)), identity_requests=((7, 3), (19, 2)),
+    head_shape=(4, 300, 72),
     attn_shape=(1, 2, 32, 16), xent_shape=(16, 128), decode_batch=2,
     decode_len=128, page_size=8, table_width=4, wide_q=(2,),
     diff_geometry=(4, 2, 40, 170, 4, 24),
@@ -34,6 +35,7 @@ def test_serve_phase(clock):
     out = cs.run_phase("serve", clock, cs.phase_serve, clock, TINY)
     assert out["steady_compiles"] == 0 and out["dense_fallbacks"] == 0
     assert out["token_identical_requests"] == 2
+    assert out["head_sigma_rows"] < 1e-4 > out["head_sigma_one_row"]
     assert out["compile_s"] > 0 and out["run_s"] >= 0
 
 
@@ -55,6 +57,24 @@ def test_a_kernel_off_its_oracle_fails_the_phase(clock, monkeypatch):
                         lambda *a, **k: real(*a, **k) * 1.5)
     with pytest.raises(cs.SmokeFailure, match="flash_decode"):
         cs.phase_kernels(TINY)
+
+
+def test_a_lookup_off_the_plain_one_fails_the_smoke(monkeypatch):
+    from incubator_mxnet_tpu.models import transformer as tfm
+
+    monkeypatch.setattr(tfm, "_token_rows",
+                        lambda table, tokens: table[tokens - 1])
+    with pytest.raises(cs.SmokeFailure, match="_token_rows"):
+        cs._embedding_ends(TINY)
+
+
+def test_a_head_off_full_precision_fails_the_smoke(monkeypatch):
+    from incubator_mxnet_tpu.models import transformer as tfm
+
+    real = tfm._logits
+    monkeypatch.setattr(tfm, "_logits", lambda p, x: real(p, x) * 1.01)
+    with pytest.raises(cs.SmokeFailure, match="_logits"):
+        cs._embedding_ends(TINY)
 
 
 @pytest.mark.slow

@@ -273,13 +273,18 @@ def test_serving_program_carries_the_pool_in_place(program, v5e_device,
     itemsize = jnp.dtype(cfg.dtype).itemsize
     pool_bytes = sum(v.size for v in paged.values()) * itemsize
     layer_bytes = pool_bytes // cfg.n_layers  # one layer's K + V
-    # the tied embedding is re-laid-out for the logits matmul in every
-    # call (161 MB; the parent of PR 26 had that copy too): set it aside
-    embed_bytes = cfg.vocab * cfg.d_model * itemsize
+    # the tied embedding lies vocabulary-minor at the jit boundary, and a
+    # gather of its rows re-lays-out all of it (161 MB): a prompt's 1024
+    # tokens still take that gather; the decode step's 16 take slabs
+    # (models.transformer._token_rows) and no program op may touch the
+    # table but the logits product
+    gathers_table = program != "decode"
+    embed_bytes = gathers_table * cfg.vocab * cfg.d_model * itemsize
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes - embed_bytes < layer_bytes, mem
     big = [m.group(0) for m in re.finditer(_BIG_OP, compiled.as_text())
            if np.prod([int(d) for d in m.group(1).split(",")])
            >= LAYER_K_ELEMS
-           and m.group(1) != f"{cfg.vocab},{cfg.d_model}"]
+           and not (gathers_table
+                    and m.group(1) == f"{cfg.vocab},{cfg.d_model}")]
     assert not big, big
