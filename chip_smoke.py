@@ -11,8 +11,9 @@ finds — and checks what comes out by the repo's own means:
            step(x, y) calls and one scan_steps of K=8.
   serve    the d_model=512 / 8-head / 6-layer / vocab-32000 transformer
            through serving.ServingEngine at its default knobs: a warm-up
-           wave, then eight requests of mixed lengths; greedy tokens of a
-           float32 engine must equal models.transformer.generate().
+           wave, then eight requests of mixed lengths into its eight slots
+           (a few decode steps dispatched one step ahead); greedy tokens of
+           a float32 engine must equal models.transformer.generate().
   kernels  every Pallas kernel of ops/pallas_kernels.py compiled by Mosaic
            (interpret=False, explicitly) against its dense oracle.
   mesh     with more than one chip: the train step again over a 'data'
@@ -100,8 +101,11 @@ class Sizes:
     d_ff: int = 2048
     max_len: int = 512
     # (prompt_len, max_new_tokens) per request of the measured wave
-    requests: tuple = ((5, 12), (23, 7), (40, 16), (64, 3), (90, 9),
-                       (130, 5), (17, 20), (200, 11))
+    # as many as the engine has slots and none under 7 new tokens, so every
+    # slot decodes for a few steps and the engine dispatches them one step
+    # ahead: the chip runs that dispatch here, outside the benchmark
+    requests: tuple = ((5, 12), (23, 7), (40, 16), (64, 8), (90, 9),
+                       (130, 7), (17, 20), (200, 11))
     # requests of the float32 engine checked token-for-token vs generate()
     identity_requests: tuple = ((9, 10), (70, 8), (33, 12))
     # the tied embedding's two ends at gpt2_xl's shape: (decode rows, V, d)
@@ -221,6 +225,17 @@ def phase_serve(clock, sz):
                         f"tokens, asked for {m}")
     check(steady_compiles == 0,
           f"{steady_compiles} program(s) compiled after the warm-up wave")
+    # the wave fills every slot at once: until its shortest request is one
+    # token from its end each decode step goes out before the one in flight
+    # is read, its tokens handed on from the device, and (checked above)
+    # under the executable the warm-up wave compiled
+    stats = eng.cache_stats()
+    ahead, ahead_floor = (stats["decode_steps_ahead"],
+                          min(m for _, m in wave) - 2)
+    check(len(wave) >= eng.slots and ahead >= ahead_floor > 0,
+          f"{ahead} of {stats['decode_steps']} decode steps went out one "
+          f"step ahead, expected {ahead_floor} or more: {len(wave)} "
+          f"requests into {eng.slots} slots")
 
     # end-to-end agreement with the dense path, at a precision where token
     # equality means something: float32 weights, full-precision matmuls,
@@ -246,6 +261,8 @@ def phase_serve(clock, sz):
             "requests": len(wave), "prefill_buckets": buckets,
             "tokens": sum(m for _, m in wave),
             "steady_compiles": steady_compiles,
+            "decode_steps": stats["decode_steps"],
+            "decode_steps_ahead": ahead,
             "token_identical_requests": len(checked),
             "dense_fallbacks": int(fallbacks),
             "pool_pages": eng.allocator.num_pages}

@@ -610,13 +610,20 @@ class TransformerPrograms:
     host arrays a prefill takes, and what the host can say of the cache
     without asking the device. The engine owns slots, queue, page tables
     and admission, and no shape. models.sambay.SambaYPrograms is the
-    second implementer."""
+    second implementer, models.falcon_h1.FalconH1Programs the third."""
 
     # no fixed-size state: every lever's rollback is a page-table write
     recurrent_state = False
-    # the engine reads each decode step before it dispatches the next
-    # (serving/engine.py `_runs_ahead`; ROADMAP S7 decides it for this model)
-    decode_ahead = False
+    # while every slot decodes, no lever is on, no live request has an
+    # `eos_id` and the step in flight ends no request, the engine
+    # dispatches the step after the one in flight before it reads that one
+    # (serving/engine.py `_runs_ahead`, `_launch`): the host's turn, 2.4 ms
+    # around an 8.7 ms step of gpt2_xl, then runs beside the device. A
+    # step() may so return with a decode step dispatched and unread: who
+    # reads `eng.paged` after a step() asks `eng.decode_in_flight` first
+    # (the cache is then one row a slot past `live_tokens()`); tokens,
+    # finish reasons and admission steps are the synchronous loop's
+    decode_ahead = True
 
     def __init__(self, cfg):
         self.cfg = cfg

@@ -24,17 +24,22 @@ Greedy decoding (temperature 0) — token-for-token identical to
 sequential `models.transformer.generate()` per request, which is the
 equivalence CI asserts.
 
-A model whose programs say `decode_ahead` is decoded ONE STEP AHEAD while
-every slot decodes: the step after the one in flight is dispatched (its
-tokens still on the device, its positions one further) before the one in
-flight is read, so the host's turn (the fetch's latency, the bookkeeping,
-the caller's own reading, the next upload and dispatch) runs beside the
-device and not between two of its steps. A step that would end a request
+A model whose programs say `decode_ahead` (models.transformer and
+models.falcon_h1 do) is decoded ONE STEP AHEAD while every slot decodes, no
+lever is on and no live request has an `eos_id`: the step after the one in
+flight is dispatched (its tokens still on the device, its positions one
+further) before the one in flight is read, so the host's turn (the fetch's
+latency, the bookkeeping, the caller's own reading, the next upload and
+dispatch) runs beside the device and not between two of its steps. A step that would end a request
 (its length is known; an `eos_id` is not, so such a request keeps the
 loop synchronous) is never run ahead of, so admissions come exactly when
 they did; the step that starts a run dispatches and returns no token,
 which keeps what the cache holds after `step()` one row apart from what
-was read, never two. Tokens are the synchronous loop's, one for one.
+was read, never two. Tokens are the synchronous loop's, one for one; a
+caller with a free slot, a lever or an `eos_id` gets that loop, call for
+call. `decode_in_flight` says whether a step is dispatched and unread (who
+reads `eng.paged` after a `step()` asks it); `cache_stats()` counts the
+decode steps dispatched and how many of them went ahead.
 
 Three OPTIONAL throughput levers stack on this substrate, each
 knob-off byte-identical to the base engine (no extra compiled programs,
@@ -100,6 +105,7 @@ COW_COPIES = "mxtpu_serving_cow_copies_total"
 PREFILL_CHUNKS = "mxtpu_serving_prefill_chunks_total"
 SPEC_PROPOSED = "mxtpu_spec_proposed_tokens_total"
 SPEC_ACCEPTED = "mxtpu_spec_accepted_tokens_total"
+DECODE_STEPS = "mxtpu_serving_decode_steps_total"
 
 # tail-prefill chunk width when the prefix cache is on but chunked
 # prefill is off: the tail still streams through the wide program (the
@@ -331,6 +337,10 @@ class ServingEngine:
         # and the tokens they fetched for it: whole blocks, tails masked
         self._fetched = dict.fromkeys(
             self.model.fetched((), self.page_size), 0)
+        # dispatches of the decode program, and those of them that went
+        # out before the step ahead of them was read
+        self._decode_steps = 0
+        self._decode_steps_ahead = 0
         # lever counters (host source of truth; mirrored to telemetry)
         self._prefix_lookups = 0
         self._prefix_hits = 0
@@ -1111,17 +1121,14 @@ class ServingEngine:
         if live_slots:
             with telemetry.span("serving.decode", live=len(live_slots)):
                 if flight is None:
-                    flight = self._launch(live_slots, self._next_tok,
-                                          self._positions)
+                    flight = self._launch(live_slots)
                     if self._runs_ahead(live_slots):
                         # read in the next step, behind the launch of the
                         # one after it
                         self._flight = flight
                         return self.slots_in_use
                 elif self._runs_ahead(live_slots):
-                    ahead = self._positions.copy()
-                    ahead[live_slots] += 1
-                    self._flight = self._launch(live_slots, flight[1], ahead)
+                    self._flight = self._launch(live_slots, flown=flight[1])
                 self._land(*flight)
         return self.slots_in_use
 
@@ -1146,10 +1153,16 @@ class ServingEngine:
             len(self._slot_out[s]) + 1 < self._slot_req[s].max_new_tokens
             for s in live_slots)
 
-    def _launch(self, live_slots, tokens, positions):
-        """Dispatches one decode step of `live_slots`: `tokens` on the
-        host, or on the device where the step before left them. Returns
-        (live slots, the step's tokens on the device)."""
+    def _launch(self, live_slots, flown=None):
+        """Dispatches one decode step of `live_slots` from the host's
+        books or, with the tokens `flown` that the step in flight left on
+        the device, the step after that one: those tokens, one position
+        further. Returns (live slots, the step's tokens on the device)."""
+        ahead = flown is not None
+        positions = self._positions
+        if ahead:
+            positions = positions.copy()
+            positions[live_slots] += 1
         if self.prefix_cache is not None:
             for s in live_slots:
                 if self._slot_cow_idx[s] >= 0:
@@ -1166,12 +1179,34 @@ class ServingEngine:
             self._attended[kind] += n
         for kind, n in self.model.fetched(depths, self.page_size).items():
             self._fetched[kind] += n
+        self._decode_steps += 1
+        self._decode_steps_ahead += ahead
+        telemetry.inc(DECODE_STEPS, dispatch="ahead" if ahead else "sync")
         with self._h2d:
-            args = (jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(self._tables))
+            args = (flown if ahead
+                    else self._as_a_step_leaves(self._next_tok),
+                    jnp.asarray(positions), jnp.asarray(self._tables))
         with self._dispatch:
             tok, self.paged = self._decode(self.params, self.paged, *args)
         return live_slots, tok
+
+    def _as_a_step_leaves(self, tokens):
+        """The host's `tokens` on the device, placed as a decode step
+        leaves its own: a jit keys on an argument's commitment and
+        sharding beside its shape, so tokens uploaded one way and handed
+        on from the step before another would be two executables of one
+        program, the second lowered at the first step that goes ahead. A
+        program's outputs are committed to their device when any of its
+        inputs was (weights a caller put there, say) and `jnp.asarray`
+        commits nothing; the pool is an output of the program that ran
+        before this one over the same weights, so it tells which. (A pool
+        laid over several devices keeps `jnp.asarray`: how its program
+        shards a token row is the compiler's choice.)"""
+        pool = jax.tree_util.tree_leaves(self.paged)[0]
+        if pool.committed and isinstance(
+                pool.sharding, jax.sharding.SingleDeviceSharding):
+            return jax.device_put(tokens, pool.sharding)
+        return jnp.asarray(tokens)
 
     def _land(self, live_slots, tok):
         with self._fetch:
@@ -1368,7 +1403,9 @@ class ServingEngine:
         the tokens the decode steps have attended so far by kind, summed
         over the layers that read them, beside the tokens their kernels
         fetched to attend those (a block is fetched whole and its tail
-        masked: attended / fetched is the fill share)."""
+        masked: attended / fetched is the fill share); the decode steps
+        dispatched that attended them, and how many of those went out one
+        step ahead (`_runs_ahead`), before the step in flight was read."""
         live = [self.allocator.pages_needed(int(self._positions[s]))
                 for s in self._decoding_slots()]
         return {
@@ -1378,6 +1415,8 @@ class ServingEngine:
             "kinds": self.model.cache_kinds(self.page_size),
             "attended_tokens": dict(self._attended),
             "fetched_tokens": dict(self._fetched),
+            "decode_steps": self._decode_steps,
+            "decode_steps_ahead": self._decode_steps_ahead,
         }
 
     @property
@@ -1483,6 +1522,9 @@ class ServingEngine:
         return {
             "schema": "mxtpu-serving-engine-debug-v2",
             "steps": self.steps,
+            # a decode step dispatched and unread: the cache is one row a
+            # slot past `position` and `tokens_out` below
+            "decode_in_flight": self._flight is not None,
             "slots": slot_rows,
             "slots_in_use": self.slots_in_use,
             "queue": queued,
@@ -1545,9 +1587,13 @@ class ServingEngine:
                     # its tokens were made: read them before the slot goes
                     flight, self._flight = self._flight, None
                     self._land(*flight)
-                self._finish(s, reason="evicted")
+                # where the step in flight was its last, it has finished
+                # as it would have a step() earlier: nothing left to cancel
+                cancelled = self._slot_req[s] is req
+                if cancelled:
+                    self._finish(s, reason="evicted")
                 self._export_gauges()
-                return True
+                return cancelled
         return False
 
     def _export_gauges(self):

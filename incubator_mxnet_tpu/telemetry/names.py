@@ -238,6 +238,11 @@ METRIC_NAMES = {
         "counter", "Prefill chunks executed by the chunked-prefill "
                    "path (one wide-query program call covers every "
                    "mid-prefill slot's next chunk)."),
+    "mxtpu_serving_decode_steps_total": (
+        "counter", "Dispatches of the batched decode program, by "
+                   "dispatch (sync = the step before was read first, "
+                   "ahead = sent while that step was still in flight, "
+                   "its tokens handed on from the device)."),
     "mxtpu_spec_proposed_tokens_total": (
         "counter", "Draft tokens proposed by the n-gram prompt-lookup "
                    "speculator (excludes the one guaranteed token per "

@@ -11,7 +11,8 @@ import chip_smoke as cs
 TINY = cs.Sizes(
     batch=8, image=32, steps=2, scan_k=2,
     vocab=128, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_len=64,
-    requests=((5, 4), (20, 2), (12, 5)), identity_requests=((7, 3), (19, 2)),
+    requests=((5, 4), (20, 6), (12, 5), (3, 7), (9, 4), (15, 5), (7, 6),
+              (11, 4)), identity_requests=((7, 3), (19, 2)),
     head_shape=(4, 300, 72),
     attn_shape=(1, 2, 32, 16), xent_shape=(16, 128), decode_batch=2,
     decode_len=128, page_size=8, table_width=4, wide_q=(2,),
@@ -34,6 +35,9 @@ def clock():
 def test_serve_phase(clock):
     out = cs.run_phase("serve", clock, cs.phase_serve, clock, TINY)
     assert out["steady_compiles"] == 0 and out["dense_fallbacks"] == 0
+    # eight requests into eight slots, the shortest of 4 tokens: two decode
+    # steps go out ahead, under the executable the warm-up compiled
+    assert out["decode_steps_ahead"] == 2 < out["decode_steps"]
     assert out["token_identical_requests"] == 2
     assert out["head_sigma_rows"] < 1e-4 > out["head_sigma_one_row"]
     assert out["compile_s"] > 0 and out["run_s"] >= 0

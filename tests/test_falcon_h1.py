@@ -171,93 +171,8 @@ def test_continuous_batching_equals_each_request_alone(params):
         "paged_kv", "recurrent", "logits"}
 
 
-def _step_through(eng):
-    """Steps `eng` until it drains. Returns, per step(), whether a decode
-    step was left in flight and how many tokens the callers could read."""
-    flights, readable = [], []
-    while eng.queue_depth or eng.slots_in_use:
-        eng.step()
-        flights.append(eng.decode_in_flight)
-        readable.append(sum(map(len, eng.live_tokens().values()))
-                        + sum(len(r.tokens) for r in eng.results().values()))
-    return flights, readable
-
-
-def test_decode_runs_one_step_ahead_while_every_slot_decodes(params,
-                                                             monkeypatch):
-    """With all four slots decoding and nobody about to end, step() returns
-    with the next decode step dispatched and unread; a step that ends a
-    request is never run ahead of; the run's first step delivers no decode
-    token. Tokens, finish reasons and the cache's books are those of the
-    loop that reads every step before it dispatches the next."""
-    rng = np.random.default_rng(17)
-    asked = [(_prompt(rng, n), new) for n, new in
-             ((5, 9), (16, 30), (14, 12), (9, 40), (3, 17), (21, 6), (8, 25))]
-
-    def serve():
-        eng = _engine(params)
-        rids = [eng.submit(p, new) for p, new in asked]
-        flights, readable = _step_through(eng)
-        return eng, [eng.results()[r] for r in rids], flights, readable
-
-    eng, ahead, flights, readable = serve()
-    monkeypatch.setattr(falcon_h1.FalconH1Programs, "decode_ahead", False)
-    eng_sync, sync, flights_sync, _ = serve()
-    assert not any(flights_sync) and any(flights)
-    assert [(r.tokens, r.finish_reason) for r in ahead] == [
-        (r.tokens, r.finish_reason) for r in sync]
-    assert eng.cache_stats() == eng_sync.cache_stats()
-    assert eng.goodput() == eng_sync.goodput()
-    # a run starts with a step that dispatches and reads nothing: one more
-    # step() per run and no other, so no token is more than one step late
-    starts = sum(b and not a for a, b in zip([False] + flights, flights))
-    assert len(flights) == len(flights_sync) + starts and starts >= 2
-    assert readable[-1] == sum(new for _, new in asked)
-    # the step that ended the last request left nothing in flight
-    assert not flights[-1]
-
-
-def test_decode_ahead_keeps_to_the_loop_for_an_eos_a_free_slot_and_a_cancel(
-        params):
-    rng = np.random.default_rng(19)
-    prompts = [_prompt(rng, n) for n in (6, 11, 4, 9)]
-    alone = _engine(params)
-    want = []
-    for p in prompts:
-        rid = alone.submit(p, 20)
-        want.append(alone.run()[rid].tokens)
-    # a free slot: a request could be admitted, so nothing runs ahead
-    eng = _engine(params)
-    rids = [eng.submit(p, 20) for p in prompts[:3]]
-    flights, _ = _step_through(eng)
-    assert not any(flights)
-    assert [eng.results()[r].tokens for r in rids] == want[:3]
-    # an eos_id can end a request on any token: the loop stays synchronous
-    eng = _engine(params)
-    rids = [eng.submit(p, 20, eos_id=want[i][7] if i == 2 else None)
-            for i, p in enumerate(prompts)]
-    flights, _ = _step_through(eng)
-    assert not any(flights[:8])
-    got = [eng.results()[r] for r in rids]
-    assert got[2].finish_reason == "eos"
-    assert got[2].tokens == want[2][: want[2].index(want[2][7]) + 1]
-    assert [g.tokens for i, g in enumerate(got) if i != 2] == [
-        w for i, w in enumerate(want) if i != 2]
-    # a cancel with a step in flight: that step's tokens are read first,
-    # the neighbours go on as if nothing had happened
-    eng = _engine(params)
-    rids = [eng.submit(p, 20) for p in prompts]
-    for _ in range(6):
-        eng.step()
-    assert eng.decode_in_flight
-    assert eng.cancel(rids[1]) and not eng.decode_in_flight
-    eng.run()
-    got = [eng.results()[r] for r in rids]
-    assert got[1].finish_reason == "evicted"
-    assert got[1].tokens == want[1][: len(got[1].tokens)]
-    assert 5 <= len(got[1].tokens) < 20
-    assert [g.tokens for i, g in enumerate(got) if i != 1] == [
-        w for i, w in enumerate(want) if i != 1]
+# the engine a step ahead of this model (tokens, the flights, an eos_id, a
+# free slot, a cancel): tests/test_serving_engine.py, beside the transformer
 
 
 def test_engine_programs_leave_each_tokens_row_on_the_device(params):

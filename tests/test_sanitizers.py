@@ -251,6 +251,47 @@ def test_engine_full_run_is_quiescent_under_sanitizers(sanitized,
     assert eng.allocator.num_in_use == held
 
 
+def test_engine_a_step_ahead_writes_into_its_own_pages(sanitized,
+                                                       monkeypatch):
+    """Levers off and both slots decoding: the engine dispatches a step
+    before it has read the one in flight, and tells the sanitizer the page
+    of the position that step writes, one past the host's books. Every
+    such page is the slot's own and live, across page boundaries, finishes
+    and admissions, and run() drains quiescent."""
+    monkeypatch.setattr(sanitizers, "_hold_ms", 30000.0)
+    cfg = tfm.TransformerConfig(vocab=32, d_model=16, n_heads=2,
+                                n_layers=1, d_ff=32, max_len=64)
+    eng = ServingEngine(tfm.init_params(cfg, seed=0), cfg, slots=2,
+                        page_size=4, num_pages=40, prefix_cache=0,
+                        prefill_chunk=0, spec_ngram=0)
+    assert eng._page_san is not None
+    written = {}
+    note_write = eng._page_san.note_write
+
+    def spy(owner, pages):
+        (slot,) = [s for s, r in enumerate(eng._slot_req)
+                   if r is not None and r.request_id == owner]
+        (page,) = pages
+        written.setdefault(owner, []).append(
+            eng._slot_pages[slot].index(page))
+        return note_write(owner, pages)
+
+    monkeypatch.setattr(eng._page_san, "note_write", spy)
+    asked = ((3, 14), (6, 9), (2, 11), (5, 7))
+    rng = np.random.RandomState(17)
+    rids = [eng.submit(rng.randint(1, 32, size=(n,)).astype(np.int32), new)
+            for n, new in asked]
+    res = eng.run()
+    assert [len(res[r].tokens) for r in rids] == [new for _, new in asked]
+    assert eng.cache_stats()["decode_steps_ahead"] > 8
+    # a request's decode steps write positions n .. n + new - 2, each into
+    # the page of its own that holds it, however the step went out
+    assert written == {r: [(n + i) // 4 for i in range(new - 1)]
+                       for r, (n, new) in zip(rids, asked)}
+    assert not sanitizers.report(), str(sanitizers.report())
+    assert eng.allocator.num_in_use == 0
+
+
 def test_engine_without_pages_sanitizer_has_no_shadow(monkeypatch):
     monkeypatch.delenv("MXTPU_SANITIZERS", raising=False)
     sanitizers.refresh_from_env()

@@ -588,3 +588,320 @@ def test_cancel_after_finish_is_noop_and_waste_counted_once():
         assert not sanitizers.report()
     finally:
         sanitizers.reset()
+
+
+# -- one decode step ahead ------------------------------------------------------
+
+LEVERS_OFF = {"prefix_cache": 0, "prefill_chunk": 0, "spec_ngram": 0}
+# seven requests through four slots: runs that start, end with a request
+# and start again behind an admission
+ASKED = ((5, 9), (16, 30), (14, 12), (9, 40), (3, 17), (21, 6), (8, 25))
+
+
+@pytest.fixture(scope="module", params=["transformer", "falcon_h1"])
+def ahead(request):
+    """A model whose programs say `decode_ahead`, behind four slots: its
+    programs' class, a maker of engines, and what each of four prompts
+    gets when it is served alone."""
+    import types
+
+    if request.param == "transformer":
+        programs, cfg, page = tfm.TransformerPrograms, _small_cfg(), 8
+        params = tfm.init_params(cfg, seed=3)
+    else:
+        from incubator_mxnet_tpu.models import falcon_h1
+        from test_falcon_h1 import CFG as cfg, PAGE as page
+        programs = falcon_h1.FalconH1Programs
+        params = falcon_h1.init_params(cfg, 3)
+
+    def engine(**kw):
+        return ServingEngine(params, cfg, slots=4, page_size=page,
+                             max_len=64, **{**LEVERS_OFF, **kw})
+
+    rng = np.random.default_rng(19)
+    prompts = [rng.integers(1, cfg.vocab, size=n).astype(np.int32)
+               for n in (6, 11, 4, 9)]
+    alone, want = engine(), []
+    for p in prompts:
+        rid = alone.submit(p, 20)
+        want.append(alone.run()[rid].tokens)
+    return types.SimpleNamespace(programs=programs, engine=engine, cfg=cfg,
+                                 prompts=prompts, want=want)
+
+
+def _step_through(eng, arrivals=()):
+    """Steps `eng` until it drains, submitting `arrivals` (after how many
+    step() calls, prompt, new tokens) on the way. Returns, per step(),
+    whether a decode step was left in flight and how many tokens the
+    callers could read, and per request after how many dispatched decode
+    steps its first token could be read."""
+    arrivals = list(arrivals)
+    flights, readable, admitted = [], [], {}
+    while arrivals or eng.queue_depth or eng.slots_in_use:
+        while arrivals and arrivals[0][0] <= len(flights):
+            eng.submit(*arrivals.pop(0)[1:])
+        eng.step()
+        flights.append(eng.decode_in_flight)
+        live, done = eng.live_tokens(), eng.results()
+        for rid in (*live, *done):
+            admitted.setdefault(rid, eng.cache_stats()["decode_steps"])
+        readable.append(sum(map(len, live.values()))
+                        + sum(len(r.tokens) for r in done.values()))
+    return flights, readable, admitted
+
+
+def _went_ahead(flights):
+    """The steps that dispatched ahead: they began with a step in flight
+    and left one."""
+    return sum(a and b for a, b in zip(flights, flights[1:]))
+
+
+def _books(eng):
+    """The engine's host counters but the one that says how a step went
+    out."""
+    stats = eng.cache_stats()
+    return {k: v for k, v in stats.items() if k != "decode_steps_ahead"
+            }, eng.goodput()
+
+
+def test_decode_runs_one_step_ahead_while_every_slot_decodes(ahead,
+                                                             monkeypatch):
+    """With all four slots decoding and nobody about to end, step() returns
+    with the next decode step dispatched and unread; a step that ends a
+    request is never run ahead of; the run's first step delivers no decode
+    token. Tokens, finish reasons, admissions and the cache's books are
+    those of the loop that reads every step before it dispatches the
+    next."""
+    rng = np.random.default_rng(17)
+    asked = [(rng.integers(1, ahead.cfg.vocab, size=n).astype(np.int32), new)
+             for n, new in ASKED]
+
+    def serve():
+        eng = ahead.engine()
+        rids = [eng.submit(p, new) for p, new in asked]
+        flights, readable, admitted = _step_through(eng)
+        return (eng, [eng.results()[r] for r in rids], flights, readable,
+                [admitted[r] for r in rids])
+
+    eng, got, flights, readable, admitted = serve()
+    monkeypatch.setattr(ahead.programs, "decode_ahead", False)
+    eng_sync, sync, flights_sync, _, admitted_sync = serve()
+    assert not any(flights_sync) and any(flights)
+    assert [(r.tokens, r.finish_reason) for r in got] == [
+        (r.tokens, r.finish_reason) for r in sync]
+    assert admitted == admitted_sync
+    assert _books(eng) == _books(eng_sync)
+    # a run starts with a step that dispatches and reads nothing: one more
+    # step() per run and no other, so no token is more than one step late
+    starts = sum(b and not a for a, b in zip([False] + flights, flights))
+    assert len(flights) == len(flights_sync) + starts and starts >= 2
+    assert readable[-1] == sum(new for _, new in ASKED)
+    # the step that ended the last request left nothing in flight
+    assert not flights[-1]
+    # the counter says what the flights show, here and at /debug/engine
+    assert eng.cache_stats()["decode_steps_ahead"] == _went_ahead(flights) > 0
+    assert eng_sync.cache_stats()["decode_steps_ahead"] == 0
+    snap = eng.debug_snapshot()
+    assert snap["decode_in_flight"] is False
+    assert snap["cache"]["decode_steps"] == eng.cache_stats()["decode_steps"]
+    assert snap["cache"]["decode_steps_ahead"] == _went_ahead(flights)
+
+
+def test_decode_ahead_keeps_to_the_loop_with_a_free_slot(ahead):
+    """A request could be admitted into the free slot between two steps, so
+    nothing runs ahead."""
+    eng = ahead.engine()
+    rids = [eng.submit(p, 20) for p in ahead.prompts[:3]]
+    flights, _, _ = _step_through(eng)
+    assert not any(flights)
+    assert eng.cache_stats()["decode_steps_ahead"] == 0
+    assert [eng.results()[r].tokens for r in rids] == ahead.want[:3]
+
+
+def test_decode_ahead_keeps_to_the_loop_for_an_eos_id(ahead):
+    """An eos_id can end a request on any token: while one is live every
+    step is read before the next goes out."""
+    want = ahead.want
+    eng = ahead.engine()
+    rids = [eng.submit(p, 20, eos_id=want[i][7] if i == 2 else None)
+            for i, p in enumerate(ahead.prompts)]
+    flights, _, _ = _step_through(eng)
+    assert not any(flights[:8])
+    got = [eng.results()[r] for r in rids]
+    assert got[2].finish_reason == "eos"
+    assert got[2].tokens == want[2][: want[2].index(want[2][7]) + 1]
+    assert [g.tokens for i, g in enumerate(got) if i != 2] == [
+        w for i, w in enumerate(want) if i != 2]
+
+
+def test_a_cancel_reads_the_step_in_flight_first(ahead):
+    """A cancel with a step in flight: that step's tokens are read first,
+    the neighbours go on as if nothing had happened."""
+    want = ahead.want
+    eng = ahead.engine()
+    rids = [eng.submit(p, 20) for p in ahead.prompts]
+    for _ in range(6):
+        eng.step()
+    assert eng.decode_in_flight and eng.debug_snapshot()["decode_in_flight"]
+    assert eng.cancel(rids[1]) and not eng.decode_in_flight
+    eng.run()
+    got = [eng.results()[r] for r in rids]
+    assert got[1].finish_reason == "evicted"
+    assert got[1].tokens == want[1][: len(got[1].tokens)]
+    assert 5 <= len(got[1].tokens) < 20
+    assert [g.tokens for i, g in enumerate(got) if i != 1] == [
+        w for i, w in enumerate(want) if i != 1]
+
+
+def _transformer_engine(slots=4, **kw):
+    cfg = _small_cfg()
+    return cfg, ServingEngine(tfm.init_params(cfg, seed=3), cfg, slots=slots,
+                              page_size=8, max_len=64,
+                              **{**LEVERS_OFF, **kw})
+
+
+def _staggered(cfg, seed, n):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(1, cfg.vocab, size=int(rng.integers(2, 24))
+                          ).astype(np.int32), int(rng.integers(2, 30)))
+            for _ in range(n)]
+
+
+def test_a_cancel_of_a_request_whose_last_step_is_in_flight():
+    """The step in flight makes the request's last token: read first, it
+    ends the request as the loop would have a step() earlier, and the
+    cancel finds nothing left to cancel."""
+    cfg, eng = _transformer_engine(slots=2)
+    rng = np.random.default_rng(5)
+    short, long = (eng.submit(rng.integers(1, cfg.vocab, size=5), new)
+                   for new in (6, 15))
+    while len(eng.live_tokens().get(short, ())) < 5:
+        eng.step()
+    assert eng.decode_in_flight and len(eng.live_tokens()[short]) == 5
+    assert not eng.cancel(short) and not eng.decode_in_flight
+    done = eng.results()[short]
+    assert done.finish_reason == "length" and len(done.tokens) == 6
+    assert len(eng.live_tokens()[long]) == 6
+    assert eng.goodput()["wasted_evicted"] == 0
+    assert len(eng.run()[long].tokens) == 15
+
+
+def test_staggered_requests_are_admitted_at_the_synchronous_loops_steps(
+        monkeypatch):
+    """Sixteen requests of staggered lengths through four slots, half of
+    them arriving on the way: they end inside runs with a queue behind
+    them, and each is admitted after as many decode steps, gets the tokens
+    and ends for the reason that the synchronous loop gives it."""
+    def serve():
+        cfg, eng = _transformer_engine()
+        asked = _staggered(cfg, 23, 16)
+        rids = [eng.submit(*a) for a in asked[:8]]
+        flights, _, admitted = _step_through(
+            eng, [(3 * i, *a) for i, a in enumerate(asked[8:], start=1)])
+        done = eng.results()
+        assert sorted(done) == sorted(rids) + list(range(8, 16))
+        return (eng, flights, admitted,
+                {r: (done[r].tokens, done[r].finish_reason) for r in done})
+
+    eng, flights, admitted, got = serve()
+    monkeypatch.setattr(tfm.TransformerPrograms, "decode_ahead", False)
+    eng_sync, flights_sync, admitted_sync, want = serve()
+    assert got == want
+    # arrivals are keyed on step() calls, which a run's first step adds to:
+    # the ones that came on the way found the engine at most that far on
+    assert {r: admitted[r] for r in range(8)} == {
+        r: admitted_sync[r] for r in range(8)}
+    assert _went_ahead(flights) >= 8 and not any(flights_sync)
+    stats, stats_sync = eng.cache_stats(), eng_sync.cache_stats()
+    assert stats["decode_steps_ahead"] == _went_ahead(flights)
+    assert stats["decode_steps"] == stats_sync["decode_steps"]
+    assert eng.allocator.num_in_use == 0
+
+
+@pytest.fixture(scope="module")
+def plain_run():
+    """Nine staggered requests through four slots with every lever off:
+    what was asked, and the tokens each got a step ahead."""
+    cfg, plain = _transformer_engine()
+    asked = _staggered(cfg, 29, 9)
+    rids = [plain.submit(*a) for a in asked]
+    flights, _, _ = _step_through(plain)
+    assert _went_ahead(flights) > 0
+    return asked, [plain.results()[r].tokens for r in rids]
+
+
+@pytest.mark.parametrize("lever", [
+    {"prefix_cache": 1}, {"prefill_chunk": 4},
+    {"spec_ngram": 2, "spec_lookahead": 3}], ids=lambda kw: next(iter(kw)))
+def test_a_lever_keeps_the_synchronous_loop(lever, plain_run):
+    """With every slot decoding and a lever on, every step is read before
+    the next goes out, through that lever's programs."""
+    asked, want = plain_run
+    _, eng = _transformer_engine(**lever)
+    rids = [eng.submit(*a) for a in asked]
+    flights, _, _ = _step_through(eng)
+    assert not any(flights)
+    assert eng.cache_stats()["decode_steps_ahead"] == 0
+    assert [eng.results()[r].tokens for r in rids] == want
+
+
+@pytest.mark.parametrize("weights", ["uncommitted", "committed",
+                                     "compile_cache"])
+def test_the_decode_program_is_one_executable_over_both_dispatches(
+        weights, tmp_path, monkeypatch):
+    """Tokens uploaded from the host's books and tokens handed on from the
+    step in flight reach the decode program under ONE signature: nothing
+    is traced, lowered or compiled at the first step that goes ahead,
+    wherever the caller put the weights."""
+    import jax
+
+    if weights == "compile_cache":
+        monkeypatch.setenv("MXTPU_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    cfg = _small_cfg()
+    params = tfm.init_params(cfg, seed=3)
+    if weights == "committed":
+        params = jax.device_put(params, jax.devices()[0])
+        assert all(leaf.committed for leaf in jax.tree_util.tree_leaves(
+            params))
+    eng = ServingEngine(params, cfg, slots=2, page_size=8, max_len=64,
+                        **LEVERS_OFF)
+    rng = np.random.default_rng(31)
+    for new in (9, 14, 6):
+        eng.submit(rng.integers(1, cfg.vocab, size=7), new)
+    flights, _, _ = _step_through(eng)
+    stats = eng.cache_stats()
+    assert 0 < stats["decode_steps_ahead"] < stats["decode_steps"]
+    assert stats["decode_steps_ahead"] == _went_ahead(flights)
+    if weights == "compile_cache":
+        assert eng._decode.is_cached and len(eng._decode._compiled) == 1
+    else:
+        assert eng._decode._cache_size() == 1
+    # the pool a committed program leaves is committed, and so then are
+    # the tokens the host uploads
+    pool = jax.tree_util.tree_leaves(eng.paged)[0]
+    assert pool.committed == (weights == "committed")
+    assert eng._as_a_step_leaves(eng._next_tok).committed == pool.committed
+
+
+def test_decode_steps_reach_telemetry_by_how_they_went_out(monkeypatch):
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.serving import engine as engine_mod
+
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    telemetry.refresh_from_env()
+    try:
+        telemetry.REGISTRY.reset()
+        cfg, eng = _transformer_engine(slots=2)
+        for prompt, new in _staggered(cfg, 37, 5):
+            eng.submit(prompt, new)
+        flights, _, _ = _step_through(eng)
+        stats = eng.cache_stats()
+        by = {dict(labels)["dispatch"]: ch.value for labels, ch in
+              telemetry.REGISTRY.get(engine_mod.DECODE_STEPS).series()}
+        assert by["ahead"] == stats["decode_steps_ahead"] == _went_ahead(
+            flights) > 0
+        assert by["ahead"] + by["sync"] == stats["decode_steps"]
+    finally:
+        monkeypatch.delenv("MXNET_TELEMETRY", raising=False)
+        telemetry.refresh_from_env()
+        telemetry.REGISTRY.reset()

@@ -385,8 +385,12 @@ def test_serving_top_render(compile_table):
     assert "decoding" in text and "queued" in text
     assert "serving_decode_step" in text
     assert "goodput" in text
+    # its one slot decodes: the first decode step is dispatched and unread
+    assert "decode steps 1  ahead 0 (0.00)  one in flight" in text
     eng.run()
-    assert "idle" in top.render(eng.debug_snapshot())
+    text = top.render(eng.debug_snapshot())
+    assert "idle" in text and "in flight" not in text
+    assert "decode steps 10  ahead 8 (0.80)" in text
     assert top.snapshot_url("localhost:9090") == \
         "http://localhost:9090/debug/engine"
 

@@ -73,6 +73,12 @@ def render(snap):
         f"decode {tokens.get('decode', 0)}  pad {tokens.get('pad', 0)}  "
         f"evicted {tokens.get('wasted_evicted', 0)}  "
         f"goodput {tokens.get('fraction', 1.0):.3f}")
+    cache = snap.get("cache") or {}
+    if cache.get("decode_steps"):
+        n, ahead = cache["decode_steps"], cache.get("decode_steps_ahead", 0)
+        lines.append(
+            f"decode steps {n}  ahead {ahead} ({ahead / n:.2f})"
+            + ("  one in flight" if snap.get("decode_in_flight") else ""))
     prefix = snap.get("prefix_cache")
     if prefix:
         hist = prefix.get("refcount_histogram") or {}
