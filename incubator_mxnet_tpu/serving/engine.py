@@ -129,6 +129,14 @@ REQ_STEP_KIND = "req_step"  # batched decode-progress record, one per STEP
 # serving.decode) and the short names they go by in a step's phase tally
 PHASES = {"serving.h2d": "h2d", "serving.dispatch": "dispatch",
           "serving.fetch": "fetch", "serving.bookkeep": "bookkeep"}
+# what a step() counts of itself, beside the phase tally: decode programs
+# dispatched (0 or 1), those of them sent before the step in flight was
+# read, flights read, requests whose prefill it ran to the first token, and
+# requests that ended in it. They close `serving.step` as attributes and go
+# with a `serving_step_slow` event; a reader classes a step from them (a
+# plain one lands one flight, dispatches one program, admits nobody and
+# ends nobody, in either loop), the engine computes no kind
+STEP_COUNTS = ("dispatched", "ahead", "landed", "prefills", "finished")
 # a step is slow when it took more than this many rolling medians (the
 # rule of the benchmark's stall_share.sat), judged against the last
 # _STEP_WINDOW steps once _STEP_MIN of them are in; the median is worked
@@ -204,6 +212,7 @@ class _Phase:
         self._span = telemetry.span(self._name)
         self._span.__enter__()
         self._t0 = self._clock()
+        return self._span
 
     def __exit__(self, *exc):
         self._tally[self._key] += self._clock() - self._t0
@@ -312,9 +321,11 @@ class ServingEngine:
         self._results: dict[int, RequestResult] = {}
         self._ids = itertools.count()
         self.steps = 0
-        # the running step's seconds by phase, and one reusable context
-        # manager per phase that adds to it
+        # the running step's seconds by phase and what it did
+        # (STEP_COUNTS), and one reusable context manager per phase that
+        # adds to the first
         self._tally = dict.fromkeys(PHASES.values(), 0.0)
+        self._did = dict.fromkeys(STEP_COUNTS, 0)
         self._h2d, self._dispatch, self._fetch, self._bookkeep = (
             _Phase(name, self._tally, clock) for name in PHASES)
         # durations of the last steps that ran a program (the slow-step
@@ -322,6 +333,9 @@ class ServingEngine:
         # running step made, with the finish records that wait for it
         self._step_s: deque = deque(maxlen=_STEP_WINDOW)
         self._median_s = None
+        # the slow steps so far: how many, what they took beyond the
+        # median in all, and the last one's event (/debug/engine)
+        self._slow_steps = {"count": 0, "excess_s": 0.0, "last": None}
         self._first_tokens: list = []
         self._held_finishes: list = []
 
@@ -490,7 +504,7 @@ class ServingEngine:
             t_step = self._clock()
             with telemetry.span("serving.step", step=self.steps,
                                 live=self.slots_in_use,
-                                queued=len(self._queue)):
+                                queued=len(self._queue)) as step_span:
                 with telemetry.span("serving.admit") as sp:
                     admitted, blocked = self._admit()
                     sp.set_metadata(admitted=admitted, blocked=blocked)
@@ -500,6 +514,7 @@ class ServingEngine:
                     live = self._decode_spec_once()
                 else:
                     live = self._decode_once()
+                step_span.set_metadata(**self._did)
             self.steps += 1
             self._export_gauges()
             self._close_step(t_step)
@@ -518,10 +533,21 @@ class ServingEngine:
         for args in self._held_finishes:
             self._log_finish(*args)
         self._held_finishes.clear()
-        tally = self._tally
-        if not any(tally.values()):
-            return  # nothing ran: an idle poll is no sample of a step
-        dur = now - t_step
+        tally, did = self._tally, self._did
+        if any(tally.values()):  # an idle poll is no sample of a step
+            self._judge_step(t_step, now - t_step)
+            for k in tally:
+                tally[k] = 0.0
+        for k in did:
+            did[k] = 0
+
+    def _judge_step(self, t_step, dur):
+        """Logs a step that ran a program as slow when it took more than
+        SLOW_STEP_FACTOR rolling medians: the always-on record of a stall,
+        with where the step's time went (the phase tally), what the step
+        did (STEP_COUNTS: one that carried a prefill is slow by its work,
+        one that did not stalled) and `at`, its start on the engine's
+        clock as `serving_request_finish` carries `submitted`."""
         recent = self._step_s
         if len(recent) >= _STEP_MIN:
             if self._median_s is None or self.steps % _STEP_MIN == 0:
@@ -529,14 +555,18 @@ class ServingEngine:
                 self._median_s = sorted(recent)[len(recent) // 2]
             median = self._median_s
             if dur > SLOW_STEP_FACTOR * median > 0:
-                _recorder.log_event(
-                    "serving_step_slow", step=self.steps - 1,
+                tally, slow = self._tally, self._slow_steps
+                event = dict(
+                    step=self.steps - 1, at=t_step,
                     step_s=round(dur, 6), median_s=round(median, 6),
                     phases={k: round(v, 6) for k, v in tally.items()},
-                    other_s=round(dur - sum(tally.values()), 6))
+                    other_s=round(dur - sum(tally.values()), 6),
+                    **self._did)
+                slow["count"] += 1
+                slow["excess_s"] += dur - median
+                slow["last"] = event
+                _recorder.log_event("serving_step_slow", **event)
         recent.append(dur)
-        for k in tally:
-            tally[k] = 0.0
 
     def run(self, max_steps=100_000):
         """Drive step() until the queue and every slot drain; returns
@@ -722,6 +752,7 @@ class ServingEngine:
         req.first_token_at = clk_first
         req.ttft_s = clk_first - req.submitted_at
         self._first_tokens.append(req)
+        self._did["prefills"] += 1
         telemetry.observe(TTFT_SECONDS, req.ttft_s,
                           buckets=_LATENCY_BUCKETS)
         if req.trace is not None:
@@ -917,6 +948,7 @@ class ServingEngine:
         req.first_token_at = clk_first
         req.ttft_s = clk_first - req.submitted_at
         self._first_tokens.append(req)
+        self._did["prefills"] += 1
         telemetry.observe(TTFT_SECONDS, req.ttft_s,
                           buckets=_LATENCY_BUCKETS)
         if req.trace is not None:
@@ -1050,8 +1082,11 @@ class ServingEngine:
         with self._h2d:
             args = (jnp.asarray(toks), jnp.asarray(start),
                     jnp.asarray(n_real), jnp.asarray(self._tables))
+        did = self._did
+        did["dispatched"] += 1
         with self._dispatch:
             tok, self.paged = self._wide(Q)(self.params, self.paged, *args)
+        did["landed"] += 1
         with self._fetch:
             tok = np.asarray(tok)
         with self._bookkeep:
@@ -1181,12 +1216,16 @@ class ServingEngine:
             self._fetched[kind] += n
         self._decode_steps += 1
         self._decode_steps_ahead += ahead
+        did = self._did
+        did["dispatched"] += 1
+        did["ahead"] += ahead
         telemetry.inc(DECODE_STEPS, dispatch="ahead" if ahead else "sync")
         with self._h2d:
             args = (flown if ahead
                     else self._as_a_step_leaves(self._next_tok),
                     jnp.asarray(positions), jnp.asarray(self._tables))
-        with self._dispatch:
+        with self._dispatch as sp:
+            sp.set_metadata(ahead=int(ahead))
             tok, self.paged = self._decode(self.params, self.paged, *args)
         return live_slots, tok
 
@@ -1209,6 +1248,7 @@ class ServingEngine:
         return jnp.asarray(tokens)
 
     def _land(self, live_slots, tok):
+        self._did["landed"] += 1
         with self._fetch:
             tok = np.asarray(tok)
         with self._bookkeep:
@@ -1258,6 +1298,7 @@ class ServingEngine:
         req = self._slot_req[slot]
         if req is None:
             return
+        self._did["finished"] += 1
         out = self._slot_out[slot]
         if reason is None:
             reason = ("eos" if req.eos_id is not None and out
@@ -1545,6 +1586,9 @@ class ServingEngine:
             "compile": compile_rows,
             "slo": self.slo.snapshot() if self.slo is not None else None,
             "requests_finished": len(self._results),
+            # steps over SLOW_STEP_FACTOR rolling medians so far, what
+            # they took beyond the median, and the last one's event
+            "slow_steps": dict(self._slow_steps),
         }
 
     def cancel(self, request_id):

@@ -34,8 +34,8 @@ from .metrics import (  # noqa: F401
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .names import (  # noqa: F401
-    METRIC_NAMES, SPAN_NAMES, SPAN_LABEL_KEYS, is_registered_metric,
-    is_registered_span,
+    METRIC_NAMES, SPAN_NAMES, SPAN_LABEL_KEYS, SPANS_OFF_THE_RING,
+    is_registered_metric, is_registered_span,
 )
 from . import distributed  # noqa: F401
 from . import recorder  # noqa: F401
@@ -63,8 +63,8 @@ __all__ = [
     "stepstats", "ledger", "compilereg", "slo",
     "enabled", "enable", "disable", "refresh_from_env",
     "counter", "gauge", "histogram", "inc", "observe", "set_gauge",
-    "METRIC_NAMES", "SPAN_NAMES", "SPAN_LABEL_KEYS", "is_registered_metric",
-    "is_registered_span",
+    "METRIC_NAMES", "SPAN_NAMES", "SPAN_LABEL_KEYS", "SPANS_OFF_THE_RING",
+    "is_registered_metric", "is_registered_span",
 ]
 
 _state_lock = threading.Lock()

@@ -16,6 +16,7 @@ instrumentation, not application metrics.
 from __future__ import annotations
 
 __all__ = ["METRIC_NAMES", "SPAN_NAMES", "SPAN_LABEL_KEYS",
+           "SPANS_OFF_THE_RING",
            "is_registered_metric", "is_registered_span"]
 
 # name -> (kind, one-line description). Kind is documentation (the
@@ -362,7 +363,14 @@ SPAN_NAMES = frozenset({
     # the serving engine, from the outside in: submit() on the caller's
     # thread; step() and, nested by the thread, admission, each prefill
     # (to the first token on the host), the decode, and under the last two
-    # the upload / dispatch / blocking fetch / per-slot bookkeeping
+    # the upload / dispatch / blocking fetch / per-slot bookkeeping.
+    # `serving.step` closes with what the step did (serving.engine
+    # STEP_COUNTS: dispatched, ahead, landed, prefills, finished) and the
+    # decode's `serving.dispatch` with `ahead`: event stats that
+    # benchmark/readers/step_record.py reads (engine_ahead_dispatch_share.*,
+    # engine_exposed_idle_ms_per_finish.sat); the same five and `at` go
+    # with the always-on `serving_step_slow` flight event
+    # (engine_stall_share.sat, engine_stall_fetch_share.sat)
     "serving.submit",
     "serving.step",
     "serving.admit",
@@ -395,6 +403,17 @@ SPAN_NAMES = frozenset({
 # a label — a server must not grow a series per step or per request.
 # (`error`, which a span sets itself when its body raises, is the one other.)
 SPAN_LABEL_KEYS = frozenset({"train", "command", "sync"})
+
+
+# spans whose ends are no `span_end` event in the flight recorder's ring
+# (unless their body raised):
+# the serving engine's four phases close some nine times a step between
+# them, which with telemetry on pushed the always-on records
+# (`serving_request_finish`, `serving_step_slow`) out of a ring of 4096
+# within ~450 steps. Their time is in the running step's phase tally, in
+# `mxtpu_span_seconds` and, when a step was slow, in its event
+SPANS_OFF_THE_RING = frozenset({
+    "serving.h2d", "serving.dispatch", "serving.fetch", "serving.bookkeep"})
 
 
 def is_registered_metric(name):

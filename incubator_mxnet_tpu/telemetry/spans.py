@@ -21,7 +21,10 @@ appended to this process's trace file on exit. A root span adopts the
 remote parent shipped by a peer (see `telemetry.distributed`), which is
 what links a worker's `trainer.step` to the server-side `merge` it caused.
 Completed spans additionally drop a boundary event into the flight
-recorder ring, so a post-mortem dump shows what the process was doing.
+recorder ring, so a post-mortem dump shows what the process was doing
+(all but those of `names.SPANS_OFF_THE_RING`, which close several times a
+step and would push the ring's always-on records out; one whose body
+raised is an event all the same).
 
 A span whose body raises keeps its timing but is tagged
 `error=<ExcType>` (visible in traces and the `mxtpu_span_seconds` series)
@@ -42,7 +45,7 @@ from .. import profiler as _profiler
 from . import distributed as _distributed
 from . import recorder as _recorder
 from .metrics import REGISTRY
-from .names import SPAN_LABEL_KEYS
+from .names import SPAN_LABEL_KEYS, SPANS_OFF_THE_RING
 
 __all__ = ["Span", "current_span", "SPAN_HISTOGRAM", "SPAN_ERRORS"]
 
@@ -179,7 +182,9 @@ class Span:
             if self.extra:
                 record["extra"] = self.extra
             _distributed.record_span(record)
-        _recorder.log_event(
-            "span_end", name=self.name, dur_ns=int(dur * 1e9),
-            **({"error": self.tags["error"]} if exc_type is not None else {}))
+        if exc_type is not None or self.name not in SPANS_OFF_THE_RING:
+            _recorder.log_event(
+                "span_end", name=self.name, dur_ns=int(dur * 1e9),
+                **({"error": self.tags["error"]}
+                   if exc_type is not None else {}))
         return False

@@ -14,7 +14,7 @@ from incubator_mxnet_tpu import telemetry
 from incubator_mxnet_tpu.models import transformer as tfm
 from incubator_mxnet_tpu.serving import PageAllocator, ServingEngine
 from incubator_mxnet_tpu.serving.engine import (
-    ADMISSION_BLOCKED, GOODPUT, OLDEST_QUEUED, REQUESTS_TOTAL,
+    ADMISSION_BLOCKED, GOODPUT, OLDEST_QUEUED, REQUESTS_TOTAL, STEP_COUNTS,
     TOKENS_TOTAL, WASTED_TOKENS)
 from incubator_mxnet_tpu.telemetry import distributed as _distributed
 from incubator_mxnet_tpu.telemetry import exporters as _exporters
@@ -552,17 +552,95 @@ def test_engine_span_tree_in_a_directly_started_session(
     assert stats[0][0] == "serving.submit"
     assert stats[0][1]["request"] == rid and stats[0][1]["lock_wait_us"] >= 0
     step0 = eng.steps - 2
+    # what the step did closes it: one synchronous dispatch, its flight
+    # read, one prefill run to its first token, nobody ended
     assert stats[1] == ("serving.step",
-                        {"step": step0, "live": 0, "queued": 1})
+                        {"step": step0, "live": 0, "queued": 1,
+                         "dispatched": 1, "ahead": 0, "landed": 1,
+                         "prefills": 1, "finished": 0})
     assert stats[2] == ("serving.admit", {"admitted": 1, "blocked": "none"})
     name, prefill = stats[3]
     assert name == "serving.prefill" and prefill["request"] == rid
     assert prefill["bucket"] == 16 and prefill["prompt_len"] == 5
     assert prefill["queue_wait_us"] >= 0
     assert stats[4] == ("serving.decode", {"live": 1})
-    assert stats[5] == ("serving.step",
-                        {"step": step0 + 1, "live": 1, "queued": 0})
-    assert stats[6] == ("serving.admit", {"admitted": 0, "blocked": "none"})
+    assert stats[5] == ("serving.dispatch", {"ahead": 0})
+    assert stats[6] == ("serving.step",
+                        {"step": step0 + 1, "live": 1, "queued": 0,
+                         "dispatched": 1, "ahead": 0, "landed": 1,
+                         "prefills": 0, "finished": 1})
+    assert stats[7] == ("serving.admit", {"admitted": 0, "blocked": "none"})
+    assert stats[9] == ("serving.dispatch", {"ahead": 0})
+
+
+def _step_records(events):
+    """[(dispatched, ahead, landed, prefills, finished)] of a line's
+    `serving.step` spans, and the `ahead` of its decode dispatches."""
+    steps = [tuple(st[k] for k in STEP_COUNTS)
+             for n, _, _, st in events if n == "serving.step"]
+    ahead = [st["ahead"] for n, _, _, st in events
+             if n == "serving.dispatch" and "ahead" in st]
+    return steps, ahead
+
+
+def _three_requests(eng):
+    """Two slots, requests of 4 and 6 tokens and a third of 5 queued
+    behind them, stepped until an idle poll."""
+    for n, new in ((5, 4), (6, 6), (4, 5)):
+        eng.submit(_prompt(n, seed=n), new)
+    while eng.queue_depth or eng.slots_in_use:
+        eng.step()
+    eng.step()
+
+
+def test_step_says_what_it_did_while_it_runs_ahead(profiled_spans):
+    """The five counts on `serving.step` and `ahead` on the decode's
+    `serving.dispatch`, step by step, for an engine that decodes one step
+    ahead: the run's start, plain steps, the step that lands a request's
+    last flight, the restart that carries a prefill, the synchronous tail
+    with a slot free, an idle poll."""
+    telemetry.disable()
+    eng = _tiny_engine()
+    assert eng.model.decode_ahead
+    _three_requests(eng)  # compile outside the trace
+    (events,) = profiled_spans(lambda: _three_requests(eng))
+    steps, ahead = _step_records(events)
+    plain = (1, 1, 1, 0, 0)
+    assert steps == [
+        (1, 0, 0, 2, 0),   # admits two, dispatches, reads nothing yet
+        plain, plain,
+        (0, 0, 1, 0, 1),   # lands the first one's last flight
+        (1, 0, 0, 1, 0),   # the restart: a prefill and a new run
+        plain,
+        (0, 0, 1, 0, 1),   # the second one's last flight
+        (1, 0, 1, 0, 0),   # a slot is free: synchronous, and plain
+        (1, 0, 1, 0, 1),   # the third ends
+        (0, 0, 0, 0, 0)]   # an idle poll
+    # one entry per decode dispatch, a prefill's dispatch has none
+    assert ahead == [s[1] for s in steps if s[0]]
+    stats = eng.cache_stats()
+    assert stats["decode_steps"] == 2 * sum(s[0] for s in steps)
+    assert stats["decode_steps_ahead"] == 2 * sum(s[1] for s in steps)
+
+
+def test_step_says_what_it_did_in_the_synchronous_loop(profiled_spans,
+                                                       monkeypatch):
+    telemetry.disable()
+    monkeypatch.setattr(tfm.TransformerPrograms, "decode_ahead", False)
+    eng = _tiny_engine()
+    _three_requests(eng)
+    (events,) = profiled_spans(lambda: _three_requests(eng))
+    steps, ahead = _step_records(events)
+    plain = (1, 0, 1, 0, 0)
+    assert steps == [
+        (1, 0, 1, 2, 0), plain,
+        (1, 0, 1, 0, 1),   # the step that ends a request dispatched too
+        (1, 0, 1, 1, 0),   # the admission's step carries the prefill
+        (1, 0, 1, 0, 1),
+        plain,
+        (1, 0, 1, 0, 1),
+        (0, 0, 0, 0, 0)]
+    assert ahead == [0] * 7
 
 
 class _SlowToHost:
@@ -765,6 +843,90 @@ def test_slowed_step_logs_one_serving_step_slow():
     assert e["phases"] == {"h2d": 0.0, "dispatch": 0.0, "fetch": 1.0,
                            "bookkeep": 0.0}
     assert e["other_s"] == 0.0
+    # what the step did, and when it began on the ENGINE's clock (the
+    # injected one stood at 100 s and moved by the fetches alone: ten of
+    # 0.25 s before this step)
+    assert [e[k] for k in STEP_COUNTS] == [1, 0, 1, 0, 0]
+    assert e["at"] == 100.0 + 10 * 0.25
+    assert eng.debug_snapshot()["slow_steps"] == {
+        "count": 1, "excess_s": 0.75, "last": {
+            k: v for k, v in e.items() if k not in ("ts", "kind", "lane")}}
+
+
+def test_a_slow_step_with_a_prefill_is_told_from_a_stall():
+    """Two steps over three medians: one carried a prefill of 2 s (slow by
+    its work), one stalled 2 s in a plain decode fetch. `prefills` tells
+    them apart, which no duration does."""
+    clk = _Clock()
+    eng = _tiny_engine(clock=clk, slots=3)  # one stays free: synchronous
+    real_prefill, real_decode = eng._prefills[16], eng._decode
+    cost = {"prefill": 2.0, "decode": 0.25}
+
+    def prefill(*args):
+        tok, paged = real_prefill(*args)
+        return _SlowToHost(tok, cost["prefill"], clk), paged
+
+    def decode(*args):
+        tok, paged = real_decode(*args)
+        return _SlowToHost(tok, cost["decode"], clk), paged
+    eng._prefills[16], eng._decode = prefill, decode
+    clk.t = _EPOCH
+    eng.submit(_prompt(5), 26)
+    for _ in range(10):
+        eng.step()
+    assert eng.debug_snapshot()["slow_steps"]["count"] == 0
+    eng.submit(_prompt(6, seed=1), 4)
+    eng.step()              # admits: 2 s of prefill + the decode
+    cost["decode"] = 2.25
+    eng.step()              # a stall of the same length, no prefill
+    cost["decode"] = 0.25
+    eng.step()
+    a, b = [e for e in _recorder.snapshot()
+            if e["kind"] == "serving_step_slow" and e.get("at", 0) >= _EPOCH]
+    assert a["step_s"] == b["step_s"] == 2.25 and a["median_s"] == 0.25
+    assert (a["prefills"], b["prefills"]) == (1, 0)
+    assert [a[k] for k in STEP_COUNTS] == [1, 0, 1, 1, 0]
+    assert [b[k] for k in STEP_COUNTS] == [1, 0, 1, 0, 0]
+    assert a["phases"]["fetch"] == b["phases"]["fetch"] == 2.25
+    # ten plain steps after the first's prefill (2 s, no sample yet: under
+    # eight were in) and the slow one before it
+    assert a["at"] == _EPOCH + 2.0 + 10 * 0.25
+    assert b["at"] == a["at"] + 2.25
+    slow = eng.debug_snapshot()["slow_steps"]
+    assert slow["count"] == 2 and slow["excess_s"] == 4.0
+    assert slow["last"]["step"] == b["step"] == 11
+    assert ("slow steps 2  +4.000 s over the median  last: step 11 2.250 s "
+            "(fetch 2.250, prefills 0)") in _serving_top().render(
+                eng.debug_snapshot())
+
+
+def test_the_ring_keeps_its_finish_records_through_1000_steps(metrics_on):
+    """With telemetry on every span's end is a flight event; the engine's
+    four phase spans, some nine ends a step, are not (their time is in the
+    step's tally and in mxtpu_span_seconds), so 1000 steps leave the
+    always-on records of a ring of 4096 in place."""
+    _recorder.refresh_from_env()   # an empty ring of the default size
+    eng = _tiny_engine()
+    first = eng.submit(_prompt(5), 2)
+    eng.run()
+    start = eng.steps
+    while eng.steps < start + 1000:
+        if not eng.queue_depth:
+            eng.submit(_prompt(4), 28)
+        eng.step()
+    ring = _recorder.snapshot()
+    kinds = {}
+    for e in ring:
+        name = e["kind"] + ":" + e.get("name", "")
+        kinds[name] = kinds.get(name, 0) + 1
+    assert not any(k.startswith("span_end:serving." + p) for k in kinds
+                   for p in ("h2d", "dispatch", "fetch", "bookkeep"))
+    assert kinds["span_end:serving.step"] >= 1000
+    assert [e["request"] for e in ring
+            if e["kind"] == "serving_request_finish"][0] == first
+    hist = telemetry.REGISTRY.get(telemetry.SPAN_HISTOGRAM)
+    by_span = {l["span"]: c for l, c in hist.series()}
+    assert by_span["serving.fetch"].count >= 1000   # still timed
 
 
 def test_dense_fallback_counts_with_telemetry_off():

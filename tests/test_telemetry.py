@@ -93,6 +93,30 @@ def test_nested_spans_accumulate_into_registry(telem):
     assert series[outer_key].sum >= series[inner_key].sum
 
 
+def test_spans_off_the_ring_are_timed_and_leave_no_span_end(telem):
+    """The spans of names.SPANS_OFF_THE_RING (the serving engine's phases,
+    several a step) keep every sink but the flight event, and one whose
+    body raised is an event all the same."""
+    def ends():
+        return [(e["name"], e.get("error")) for e in recorder.snapshot()
+                if e["kind"] == "span_end"
+                and e["name"] in ("serving.fetch", "serving.step")]
+    before = ends()
+    with telem.span("serving.step"):
+        with telem.span("serving.fetch"):
+            pass
+        with pytest.raises(ValueError):
+            with telem.span("serving.fetch"):
+                raise ValueError("no tokens")
+    assert ends()[len(before):] == [("serving.fetch", "ValueError"),
+                                    ("serving.step", None)]
+    hist = telemetry.REGISTRY.get(telemetry.SPAN_HISTOGRAM)
+    counts = {tuple(sorted(l.items())): c.count for l, c in hist.series()}
+    assert counts[(("span", "serving.fetch"),)] == 1
+    assert counts[(("error", "ValueError"), ("span", "serving.fetch"))] == 1
+    assert telemetry.SPANS_OFF_THE_RING <= telemetry.SPAN_NAMES
+
+
 def test_spans_unify_with_profiler_aggregate_table(telem, monkeypatch):
     from incubator_mxnet_tpu import profiler
 

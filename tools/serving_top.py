@@ -79,6 +79,15 @@ def render(snap):
         lines.append(
             f"decode steps {n}  ahead {ahead} ({ahead / n:.2f})"
             + ("  one in flight" if snap.get("decode_in_flight") else ""))
+    slow = snap.get("slow_steps") or {}
+    if slow.get("count"):
+        last = slow["last"]
+        lines.append(
+            f"slow steps {slow['count']}  "
+            f"+{slow['excess_s']:.3f} s over the median  "
+            f"last: step {last['step']} {last['step_s']:.3f} s "
+            f"(fetch {last['phases']['fetch']:.3f}, "
+            f"prefills {last['prefills']})")
     prefix = snap.get("prefix_cache")
     if prefix:
         hist = prefix.get("refcount_histogram") or {}
