@@ -58,10 +58,12 @@ def summary(samples):
 
 def spread(values):
     """Distance between the quartiles over the median — the run-to-run
-    spread the bounds in BENCHMARK.json are set from. Quartiles are
-    interpolated (a handful of runs has no measured quartile)."""
+    spread the bounds in BENCHMARK.json are set from and checked against.
+    Quartiles are `statistics.quantiles(values, n=4)`'s (its default,
+    exclusive method, as the bounds are read; a handful of runs has no
+    measured quartile, so they are interpolated)."""
     med = statistics.median(values)
     if not med:
         raise ValueError("spread is undefined at a median of 0")
-    q1, _, q3 = statistics.quantiles(values, n=4, method="inclusive")
+    q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / abs(med)

@@ -31,6 +31,7 @@ weights the largest logit changes on rounding. In the window, every
 finished request must have delivered exactly its max_new_tokens.
 """
 import dataclasses
+import gc
 import queue
 import threading
 import time
@@ -123,6 +124,20 @@ def warm_and_check(ctx, eng, params, cfg, reqs):
     return worst <= float(mix["logit_tol_std"]), {
         "check_buckets": buckets, "check_tokens": new * len(prompts),
         "worst_logit_margin_std": worst}
+
+
+def logit_checks(check, name, mix, fallbacks):
+    """The numbers the verdict compares, each with its limit: the check's
+    worst reading against the mix's `logit_tol_std` (absent where the
+    check stopped before it had one), the tokens that were not the largest
+    of the row they came from, and the dense fallbacks taken."""
+    out = {"dense_fallbacks": [fallbacks, 0]}
+    if name in check:
+        out[name] = [check[name], float(mix["logit_tol_std"])]
+    if "tokens_are_rows_argmax" in check:
+        out["tokens_not_rows_argmax"] = [
+            int(not check["tokens_are_rows_argmax"]), 0]
+    return out
 
 
 class Pump:
@@ -332,9 +347,15 @@ def run(ctx):
     pump = Pump(eng, time.perf_counter)
     window = Window(ctx, pump, TailTrace(ctx, mix["trace_seconds"]))
     backlog = mix["arrivals"]["process"] == "backlog"
+    # what set-up left behind (compiled programs, the reference's graph)
+    # is no garbage: out of the collector's way, as in serve_hybrid, so
+    # that no full collection walks it inside the window
+    gc.collect()
+    gc.freeze()
     t0, t_end, judged = (run_backlog if backlog else run_open_loop)(
         ctx, pump, window, reqs)
     closed = window.close()
+    gc.unfreeze()
 
     def since(start, key):
         return closed[key] - start[key]
@@ -375,6 +396,8 @@ def run(ctx):
                for k in ("prefill", "decode", "pad", "processed")}
     facts.update({
         "correct": bool(ok and not bad and fallbacks == 0),
+        "checks": logit_checks(check, "worst_logit_margin_std", mix,
+                               fallbacks),
         "attempted": len(judged), "failed": len(bad),
         "window_start": t0, "window_s": t_end - t0,
         "compiles_in_window": since(window.opened, "compiles"),

@@ -246,6 +246,8 @@ def run(ctx):
         "delivery_span_s": instants[-1] - instants[0],
         "delivery_steps_s": steps_s,
         "correct": bool(ok and not bad and fallbacks == 0),
+        "checks": serve_engine.logit_checks(check, "worst_logit_err_std",
+                                            mix, fallbacks),
         "attempted": len(judged), "failed": len(bad),
         "window_start": t0, "window_s": t_end - t0,
         "compiles_in_window": since(window.opened, "compiles"),

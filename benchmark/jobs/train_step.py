@@ -160,6 +160,7 @@ def run(ctx):
     correct = finite and rel <= float(mix["loss_rtol"])
     return {
         "correct": correct, "attempted": steps,
+        "checks": {"first_loss_rel_diff": [rel, float(mix["loss_rtol"])]},
         "failed": sum(not math.isfinite(v) for v in losses),
         "window_start": t0, "window_s": window_s,
         "compiles_in_window": compiles_in_window,

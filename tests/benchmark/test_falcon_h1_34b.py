@@ -31,14 +31,14 @@ def test_the_cell_loads_from_files_alone():
     assert callable(loader.load_reference(cell.config))
     assert {m.name for m in cell.end_to_end} == {"serve_out_tok_per_s",
                                                  "setup_s"}
-    assert {m.name for m in cell.per_layer} == NEW_METRICS | APPENDED
+    assert NEW_METRICS | APPENDED <= {m.name for m in cell.per_layer}
     for m in cell.per_layer:       # every cost file resolves too
         if "cost" in m.args:
             assert callable(loader.load_callable("kernel_costs",
                                                  m.args["cost"]))
-    bj = loader.benchmark_json()
-    assert len(bj["workloads"]) == 7
-    assert sum(w["chips"] == 4 for w in bj["workloads"]) == 1
+    # four chips for at most a quarter of the cells, rounded down, or one
+    cells = loader.benchmark_json()["workloads"]
+    assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
 
 
 def test_the_traffic_is_the_issues_table():
@@ -68,10 +68,10 @@ def test_the_file_keeps_every_number_of_the_catalogs_entry_but_the_depth():
     config = loader.load_json("configs", "falcon_h1_34b")
     assert config["source"] == entry["source_url"]
     differs = {k for k, v in entry["config"].items() if config.get(k) != v}
-    # the contract's list is BENCHMARK.json's (the file's own stays []:
-    # its `reduced_why`)
+    # the file's list is BENCHMARK.json's
     declared = next(c for c in loader.benchmark_json()["configs"]
                     if c["name"] == "falcon_h1_34b")
+    assert config["reduced"] == declared["reduced"]
     assert differs == set(declared["reduced"]) == {"num_hidden_layers"}
     assert declared["source"] == config["source"]
     assert config["num_hidden_layers"] == 6

@@ -33,7 +33,10 @@ def test_loader_resolves_every_part_of_a_cell(name):
     assert cell.per_layer
     for m in cell.per_layer:  # reported only where the metric it moves is
         assert m.entry["moves"] in e2e, (m.name, m.entry["moves"])
-    assert cell.config["reduced"] == []
+    # the configuration file lists every key it changed from its source,
+    # as its entry in BENCHMARK.json does
+    entry = next(c for c in BENCH["configs"] if c["name"] == cell.config_name)
+    assert cell.config["reduced"] == entry["reduced"]
     rehearsal = loader.load_cell(name, rehearse=True)
     assert "rehearse" not in rehearsal.config
     assert rehearsal.config != cell.config
@@ -237,7 +240,16 @@ def test_result_line_has_exactly_the_contracts_keys(grown):
     assert p.returncode == 0, p.stderr[-2000:]
     lines = p.stdout.strip().splitlines()
     result = json.loads(lines[-1])
-    assert set(result) == CONTRACT_KEYS | {"breakdown"}
+    assert set(result) == CONTRACT_KEYS | {"breakdown", "checks"}
+    # each number the verdict compared, with its limit: the line's last key
+    # and the last lines on standard error
+    assert list(result)[-1] == "checks"
+    checks = result["checks"]
+    assert {"failed", "compiles_in_window"} <= set(checks)
+    assert all(set(c) == {"value", "limit"} for c in checks.values())
+    assert p.stderr.strip().splitlines()[-len(checks):] == [
+        f"check {name} {c['value']} limit {c['limit']}"
+        for name, c in checks.items()]
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     dev = result["device"]
@@ -262,6 +274,112 @@ def test_no_accelerator_or_no_program_is_an_error_not_a_result(grown):
     p = _run(grown, "--workload", "fake_cell", "--seed", "3", "--seconds",
              "1", "--trace", "0", "--rehearse", pythonpath="")
     assert p.returncode != 0 and not p.stdout.strip()
+
+
+def test_a_rehearsal_times_setup_without_the_chip(grown):
+    """`setup_s` is the window's opening less the first `jax.devices()`
+    (the runtime's start and the chip's hand-over); the detail line gives
+    every stage in the order it ran, with `chip_s` beside them."""
+    p = _run(grown, "--workload", "fake_cell", "--seed", "2900000001",
+             "--seconds", "1", "--trace", "0", "--rehearse")
+    assert p.returncode == 0, p.stderr[-2000:]
+    lines = p.stdout.strip().splitlines()
+    result, detail = json.loads(lines[-1]), json.loads(lines[-2])["detail"]
+    marks = detail["setup_reached_s"]
+    assert list(marks)[:4] == ["imports", "chip", "compile_cache", "peaks"]
+    assert list(marks.values()) == sorted(marks.values())
+    assert detail["window_opened_s"] >= marks["peaks"]
+    # the marks are rounded to 0.1 ms
+    assert 0 < detail["chip_s"] <= marks["chip"] - marks["imports"] + 2e-4
+    assert result["metrics"]["setup_s"]["value"] == pytest.approx(
+        detail["window_opened_s"] - detail["chip_s"])
+    assert detail["backend_started_by_imports"] is False
+
+
+# ---------------------------------------------------------------------------
+# a cell added as files and entries alone passes tests/benchmark unedited
+# ---------------------------------------------------------------------------
+
+ROOM_CELL = "gpt2xl_serve_unseen_trace"
+
+
+@pytest.fixture(scope="module")
+def room(tmp_path_factory):
+    """A copy of the benchmark and of its tests with one more configuration
+    (a copy cut in depth: the cut in its file and in its entry), one more
+    mix (a copy under another name, replaying another trace), one more cell
+    in every entry that lists its model's saturated cell, and a per-layer
+    entry of its own. Not a file that was there is edited."""
+    root = str(tmp_path_factory.mktemp("room"))
+    for rel in ("BENCHMARK.json", "conftest.py", "pytest.ini"):
+        shutil.copy(os.path.join(loader.ROOT, rel), root)
+    os.makedirs(os.path.join(root, "tests"))
+    shutil.copy(os.path.join(loader.ROOT, "tests", "conftest.py"),
+                os.path.join(root, "tests"))
+    for rel in ("benchmark", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(loader.ROOT, rel),
+                        os.path.join(root, rel),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+
+    def write(rel, obj):
+        with open(os.path.join(root, "benchmark", rel), "w") as f:
+            json.dump(obj, f)
+
+    config = loader.load_json("configs", "gpt2_xl")
+    config.update(name="gpt2_xl_half", n_layer=24, reduced=["n_layer"])
+    write("configs/gpt2_xl_half.json", config)
+    mix = loader.load_json("traffic", "backlog_short_in_long_out")
+    mix["trace_seed"] += 1
+    write("traffic/backlog_unseen_trace.json", mix)
+    write("layer_metrics/requests_ended_share.json",
+          {"what": "requests that ended over those attempted",
+           "reader": "host.py:ratio",
+           "args": {"num": "finished", "den": ["attempted"],
+                    "percent": True}})
+    bj = loader.benchmark_json()
+    bj["configs"].append({
+        "name": "gpt2_xl_half", "source": config["source"],
+        "file": "benchmark/configs/gpt2_xl_half.json",
+        "reduced": ["n_layer"], "why": "half the depth"})
+    bj["workloads"].append({
+        "name": ROOM_CELL, "config": "gpt2_xl_half",
+        "traffic": "backlog_unseen_trace", "chips": 1,
+        "why": "the saturated mix replaying a trace nobody tuned against"})
+    for e in bj["end_to_end"] + bj["per_layer"]:
+        if "gpt2xl_serve_decode_sat" in e.get("workloads", ()):
+            e["workloads"].append(ROOM_CELL)
+    bj["per_layer"].append({
+        "name": "requests_ended_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "serving.engine",
+        "moves": "serve_out_tok_per_s", "workloads": [ROOM_CELL]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bj, f, indent=2)
+    return root
+
+
+def test_a_cell_of_files_and_entries_alone_passes_these_tests(room):
+    """tests/benchmark, as they are, on the copy: every check of the
+    loader, the entries and the configurations that runs no cell holds
+    with the new cell in it (the walks through cells are left out: they
+    are each cell's own)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([room, loader.ROOT]))
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/benchmark", "-v",
+         "-p", "no:cacheprovider", "-p", "no:randomly", "-k",
+         "not rehearsal and not refuses_a_fault and not result_line and "
+         "not no_accelerator and not entries_alone"],
+        env=env, cwd=room, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout[-3000:]
+    for case in (f"test_loader_resolves_every_part_of_a_cell[{ROOM_CELL}]",
+                 "test_every_metric_has_a_file_and_a_reader[per_layer]",
+                 "test_the_entries_are_the_last_five_and_every_older_one",
+                 "test_new_entry_loads_in_its_cells[engine_stall_share.sat]",
+                 "test_new_entry_loads_in_its_cell[engine_host_ms_per_step",
+                 "test_falcon_h1_34b.py::test_the_cell_loads_from_files_alone"):
+        assert any(case in line and "PASSED" in line
+                   for line in p.stdout.splitlines()), case
 
 
 # ---------------------------------------------------------------------------
@@ -397,8 +515,9 @@ def test_percentiles_are_measured_values_and_come_with_their_count():
 
 def test_spread_is_interquartile_range_over_median():
     assert stats.spread([10, 10, 10, 10]) == 0
-    # quartiles of 1..5 (interpolated) are 2 and 4, the median 3
-    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx(2 / 3)
+    # quartiles of 1..5 (interpolated, exclusive of the ends) are 1.5 and
+    # 4.5, the median 3
+    assert stats.spread([1, 2, 3, 4, 5]) == pytest.approx(1.0)
     with pytest.raises(ValueError):
         stats.spread([0, 0, 0])
 
