@@ -24,8 +24,8 @@ __all__ = ["flash_attention", "softmax_xent", "flash_decode",
            "paged_decode_attention_wide", "paged_kv_write",
            "paged_write_plan", "paged_diff_attention",
            "paged_ring_write_plan", "selective_scan", "ssd_state_update",
-           "ssd_chunk_scan",
-           "bn_act_epilogue",
+           "ssd_chunk_scan", "power_retention_step",
+           "power_retention_chunk", "bn_act_epilogue",
            "DECODE_BLOCK", "DENSE_FALLBACKS_TOTAL"]
 
 _NEG_INF = -1e30
@@ -1389,6 +1389,19 @@ def selective_scan(dt, a, B, C, A, s0, interpret=None):
 SSD_HEAD_BLOCK = 8
 
 
+def _dead_slot_source(live):
+    """(live as int32, src): the slot whose block each dead slot's steps
+    point at, the LAST block of the live slot before it (the step before:
+    the same block twice in a row is neither fetched nor stored again),
+    else the FIRST block of the first live slot (the step after); a live
+    slot is its own."""
+    slot = jnp.arange(live.shape[0], dtype=jnp.int32)
+    live = live.astype(jnp.int32)
+    before = jax.lax.cummax(jnp.where(live != 0, slot, -1))
+    return live, jnp.where(before >= 0, before,
+                           jnp.argmax(live).astype(jnp.int32))
+
+
 def _ssd_state_kernel(l_ref, src_ref, live_ref, cols_ref, b_ref, c_ref,
                       s_ref, y_ref, out_ref):
     """One (slot, head block) grid step. s_ref, out_ref (hb, P, N): the
@@ -1460,14 +1473,7 @@ def ssd_state_update(state, layer, live, x, dt, A, B, C, interpret=None):
     cols = jnp.stack([jnp.broadcast_to(decay[:, None, :], (S, P, H)),
                       (dt[..., None] * x.astype(f32)).transpose(0, 2, 1)],
                      axis=1)                                        # (S,2,P,H)
-    # the one block a dead slot's steps point at: the LAST block of the
-    # live slot before it (the step before: the same block twice in a row
-    # is neither fetched nor stored again), else the FIRST block of the
-    # first live slot (the step after)
-    slot = jnp.arange(S, dtype=jnp.int32)
-    live = live.astype(jnp.int32)
-    before = jax.lax.cummax(jnp.where(live != 0, slot, -1))
-    src = jnp.where(before >= 0, before, jnp.argmax(live).astype(jnp.int32))
+    live, src = _dead_slot_source(live)
     n_blocks = H // hb
 
     def state_index(s, j, l, src, live):
@@ -1556,3 +1562,333 @@ def ssd_chunk_scan(x, dt, A, B, C, chunk):
     y = y + (einsum("scign,scghpn->scighp", Cc, found)
              * jnp.exp(cs).transpose(0, 1, 4, 2, 3)[..., None])
     return y.reshape(S, T, H, P), last.reshape(S, H, P, N)
+
+
+# ---------------------------------------------------------------------------
+# Power retention of degree 2 (models.brumby): gated, normalised power
+# attention, one scalar gate a K/V head and token, G query heads a K/V head.
+# With phi(u) the symmetric square of a head (phi(q).phi(k) = (q.k)^2, F =
+# D (D + 1) / 2 features), every K/V head carries a matrix state and a
+# normaliser, float32:
+#     S_t = gamma_t S_{t-1} + v_t (outer) phi(k_t)     (D, F): the value on
+#     z_t = gamma_t z_{t-1} + phi(k_t)                 the sublanes, the
+#     o_t = S_t phi(q_t) / (z_t . phi(q_t) + eps)      features on the lanes
+#
+# The features come in D / 2 + 1 blocks of D lanes: block d is c_d u *
+# roll(u, d) (lane a pairs u_a with u_{a - d mod D}; c_0 = 1, else sqrt 2),
+# which meets every unordered pair of the head once at cyclic distance d,
+# and the last block (d = D / 2) keeps its first D / 2 lanes, where each pair
+# at distance D / 2 would otherwise come twice. So phi is D rolls and
+# products, no gather: F = 8256 at D = 128 in 65 blocks of 128 lanes, the
+# last half zero (F_pad = 8320). A decode step is every live slot's state in
+# and out (power_retention_step, in place in the cache's one array for every
+# layer); a prompt goes through it in chunks as matrix products
+# (power_retention_chunk).
+# ---------------------------------------------------------------------------
+
+RETENTION_EPS = 1e-6
+_SQRT2 = float(np.sqrt(2.0))
+
+
+def retention_width(head_dim):
+    """Lanes of phi(u) for a head of `head_dim`: D / 2 + 1 blocks of D."""
+    if head_dim % 2:
+        raise ValueError("the features pair a head's lanes: an even width")
+    return (head_dim // 2 + 1) * head_dim
+
+
+def _feature_block(u, d, roll):
+    """Block d of phi(u) over u's last axis (lanes): c_d u * roll(u, d),
+    the last block's second half zero."""
+    D = u.shape[-1]
+    if d == 0:
+        return u * u
+    out = _SQRT2 * u * roll(u, d)
+    if d == D // 2:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, out.ndim - 1)
+        out = jnp.where(lane < D // 2, out, 0.0)
+    return out
+
+
+def power_features(u):
+    """phi(u) (..., retention_width(D)) of u (..., D), in the layout the
+    kernels keep: phi(q) . phi(k) = (q . k)^2."""
+    D = u.shape[-1]
+    return jnp.concatenate(
+        [_feature_block(u, d, lambda x, s: jnp.roll(x, s, -1))
+         for d in range(D // 2 + 1)], axis=-1)
+
+
+def _lane_roll(x, shift):
+    return pltpu.roll(x, shift, x.ndim - 1)
+
+
+def _dot(a, b, dtype, b_transposed=False):
+    """a (M, K) . b (K, N), or . b^T for b (N, K), in `dtype` operands,
+    float32 sums."""
+    return jax.lax.dot_general(
+        a.astype(dtype), b.astype(dtype),
+        (((1,), (1 if b_transposed else 0,)), ((), ())),
+        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                   else None),
+        preferred_element_type=jnp.float32)
+
+
+def _normalised(num, den):
+    """o = S phi(q) / (z . phi(q) + eps): a row's read-out over its
+    normaliser's."""
+    return num / (den + RETENTION_EPS)
+
+
+# a head's state is 4.26 MB at D = 128: in and out, double-buffered
+RETENTION_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _retention_step_kernel(l_ref, src_ref, live_ref, q_ref, kg_ref, v_ref,
+                           s_ref, z_ref, o_ref, s_out, z_out, *, dtype):
+    """One (slot, K/V head) grid step. q_ref, o_ref (Gp, D): the head's
+    query rows (G real, the rest zero); kg_ref (2, D): k, and log gamma on
+    every lane; v_ref (D, 1): v as a column, to broadcast over the state's
+    lanes; s_ref, s_out (D, F_pad): the head's state in and out through one
+    buffer; z_ref, z_out (Hkv, F_pad): the normalisers of every K/V head of
+    the slot, revisited by its heads (row g is this head's).
+
+    A dead slot moves nothing, as in ssd_state_update: all its steps point
+    at ONE block of a live slot (src_ref), the block of the step before or
+    after them, so the pipeline neither fetches nor stores for them; only
+    before the first live slot do its blocks go out as they came in."""
+    s, g = pl.program_id(0), pl.program_id(1)
+    D = v_ref.shape[0]
+    live = live_ref[s] != 0
+
+    @pl.when(jnp.logical_not(live) & (src_ref[s] >= s))
+    def _():
+        s_out[...] = s_ref[...]
+        z_out[...] = z_ref[...]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live & (g == 0))
+    def _():
+        z_out[...] = z_ref[...]
+
+    @pl.when(live)
+    def _():
+        k, gamma = kg_ref[0:1, :], jnp.exp(kg_ref[1:2, :])        # (1, D)
+        q = q_ref[...]
+        v = jnp.broadcast_to(v_ref[...], (D, D))
+        mine = jax.lax.broadcasted_iota(jnp.int32, (z_ref.shape[0], D), 0) == g
+        num = jnp.zeros(o_ref.shape, jnp.float32)
+        den = jnp.zeros(o_ref.shape, jnp.float32)
+        for d in range(D // 2 + 1):
+            cols = slice(d * D, (d + 1) * D)
+            kd = _feature_block(k, d, _lane_roll)                 # (1, D)
+            qd = _feature_block(q, d, _lane_roll)                 # (Gp, D)
+            sd = gamma * s_ref[:, cols] + v * kd                  # (D, D)
+            s_out[:, cols] = sd
+            zs = z_out[:, cols]                                   # (Hkv, D)
+            zs = jnp.where(mine, gamma * zs + kd, zs)
+            z_out[:, cols] = zs
+            num = num + _dot(qd, sd, dtype, b_transposed=True)
+            den = den + qd * jnp.sum(jnp.where(mine, zs, 0.0), axis=0,
+                                     keepdims=True)
+        o_ref[...] = _normalised(num, jnp.sum(den, axis=1, keepdims=True))
+
+
+def power_retention_step(state, norm, layer, live, q, k, v, log_gamma,
+                         dtype=jnp.float32, interpret=None):
+    """One token of every live sequence through one layer's retention.
+
+    state: (L, S, Hkv, D, F_pad) float32 and norm: (L, S, Hkv, F_pad)
+    float32, the cache's states and normalisers of every layer, read and
+    written in layer `layer` (int32 scalar, traced in the layer loop) in
+    place (input_output_aliases: donate them and carry them); live: (S,)
+    bool — a dead slot's state moves neither in nor out, and its o is
+    zeros; q: (S, H, D), query head j reading K/V head j // (H / Hkv);
+    k, v: (S, Hkv, D); log_gamma: (S, Hkv), at most 0. `dtype` is the
+    operands' of the read-out's product (the state stays float32).
+
+    Returns (o (S, H, D) float32, state, norm)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    _, S, Hkv, D, F = state.shape
+    H = q.shape[1]
+    G = H // Hkv
+    if H % Hkv or F != retention_width(D):
+        raise ValueError(f"{H} query heads over {Hkv} K/V heads of {D}, "
+                         f"{F} feature lanes")
+    Gp = -(-G // 8) * 8
+    f32 = jnp.float32
+    qp = jnp.pad(q.astype(f32).reshape(S, Hkv, G, D),
+                 ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    kg = jnp.stack([k.astype(f32), jnp.broadcast_to(
+        log_gamma.astype(f32)[..., None], k.shape)], axis=2)  # (S,Hkv,2,D)
+    live, src = _dead_slot_source(live)
+
+    def state_index(s, g, l, src, live):
+        g = jnp.where(live[s] != 0, g, jnp.where(src[s] < s, Hkv - 1, 0))
+        return l[0], src[s], g, 0, 0
+
+    state_spec = pl.BlockSpec((None, None, None, D, F), state_index)
+    norm_spec = pl.BlockSpec((None, None, Hkv, F),
+                             lambda s, g, l, src, live: (l[0], src[s], 0, 0))
+
+    def rows(n):
+        return pl.BlockSpec((None, None, n, D),
+                            lambda s, g, *refs: (s, g, 0, 0))
+
+    o, state, norm = pl.pallas_call(
+        functools.partial(_retention_step_kernel, dtype=jnp.dtype(dtype)),
+        name="power_retention_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, Hkv),
+            in_specs=[rows(Gp), rows(2),
+                      pl.BlockSpec((None, None, D, 1),
+                                   lambda s, g, *refs: (s, g, 0, 0)),
+                      state_spec, norm_spec],
+            out_specs=[rows(Gp), state_spec, norm_spec]),
+        out_shape=[jax.ShapeDtypeStruct((S, Hkv, Gp, D), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct(norm.shape, f32)],
+        # operands 6 and 7 (after the three scalar-prefetch arrays, q, k|g
+        # and v) are the state and the normaliser: outputs 1 and 2
+        input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=RETENTION_VMEM_BYTES),
+        interpret=interpret,
+    )(_layer_index(layer), src, live, qp, kg,
+      v.astype(f32)[..., None], state, norm)
+    return o[:, :, :G].reshape(S, H, D), state, norm
+
+
+def _retention_chunk_kernel(q_ref, k_ref, vt_ref, cs_ref, csr_ref, o_ref,
+                            s_out, z_out, s_scr, z_scr, *, dtype):
+    """One (sequence, K/V head, chunk) grid step. q_ref, o_ref (G, Q, D):
+    the head's query heads over the chunk's rows; k_ref (Q, D); vt_ref
+    (D, Q): v with the value on the sublanes; cs_ref (Q, 1) and csr_ref
+    (1, Q): the running sum of log gamma within the chunk as a column and
+    as a row; s_scr (D, F_pad), z_scr (1, F_pad): the state the chunk
+    finds, float32, handed to s_out, z_out after the last chunk."""
+    c = pl.program_id(2)
+    G, Q, D = q_ref.shape
+
+    @pl.when(c == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+        z_scr[...] = jnp.zeros_like(z_scr)
+
+    k, vt = k_ref[...], vt_ref[...]
+    cs, csr = cs_ref[...], csr_ref[...]
+    # log gamma <= 0: the running sum falls, and its last is its least
+    end = jnp.min(csr, axis=1, keepdims=True)                      # (1, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    decay = jnp.exp(jnp.where(row >= col, cs - csr, -jnp.inf))     # (Q, Q)
+    into = jnp.exp(cs)                     # (Q, 1): from the chunk's start
+
+    # what the state the chunk found gives each row
+    qa = q_ref[...].reshape(G * Q, D)
+    num = jnp.zeros((G * Q, D), jnp.float32)
+    dz = jnp.zeros((G * Q, D), jnp.float32)
+    for d in range(D // 2 + 1):
+        cols = slice(d * D, (d + 1) * D)
+        qd = _feature_block(qa, d, _lane_roll)
+        num = num + _dot(qd, s_scr[:, cols], dtype, b_transposed=True)
+        dz = dz + qd * z_scr[:, cols]
+    found = jnp.sum(dz, axis=1, keepdims=True)                     # (GQ, 1)
+
+    # and the chunk's own rows, j <= i: (q_i . k_j)^2 exp(cs_i - cs_j)
+    for j in range(G):
+        qj = qa[j * Q:(j + 1) * Q]
+        sc = _dot(qj, k, dtype, b_transposed=True)
+        w = sc * sc * decay
+        top = (into * num[j * Q:(j + 1) * Q]
+               + _dot(w, vt, dtype, b_transposed=True))
+        bottom = (into * found[j * Q:(j + 1) * Q]
+                  + jnp.sum(w, axis=1, keepdims=True))
+        o_ref[j] = _normalised(top, bottom)
+
+    # the state the next chunk finds: the value rows and, under them, the
+    # normaliser's, weighed by exp(cs_end - cs_j)
+    to_end = jnp.exp(end - csr)                                    # (1, Q)
+    weights = jnp.concatenate(
+        [vt * to_end,
+         jnp.where(jax.lax.broadcasted_iota(jnp.int32, (8, Q), 0) == 0,
+                   to_end, 0.0)], axis=0)                          # (D+8, Q)
+    through = jnp.exp(end)
+    for d in range(D // 2 + 1):
+        cols = slice(d * D, (d + 1) * D)
+        add = _dot(weights, _feature_block(k, d, _lane_roll), dtype)
+        s_scr[:, cols] = through * s_scr[:, cols] + add[:D]
+        z_scr[:, cols] = through * z_scr[:, cols] + add[D:D + 1]
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        s_out[...] = s_scr[...]
+        z_out[...] = z_scr[...]
+
+
+def power_retention_chunk(q, k, v, log_gamma, chunk, dtype=jnp.float32,
+                          interpret=None):
+    """A prompt through the retention from an empty state, in chunks of
+    `chunk` rows as matrix products. Within a chunk row i reads rows
+    j <= i through (q_i . k_j)^2 exp(cs_i - cs_j), cs the running sum of
+    log gamma; across chunks through the state by phi. The state is
+    scanned over the chunks in VMEM: no chunk's added state is ever
+    written out.
+
+    q: (S, T, H, D); k, v: (S, T, Hkv, D); log_gamma: (S, T, Hkv), at most
+    0. A padding row carries k = 0 and log gamma = 0: it leaves the state
+    as it is, so the state returned is the one after the last REAL row. T
+    is a multiple of `chunk`, or one chunk. `dtype` is the operands' of the
+    products (sums float32).
+
+    Returns (o (S, T, H, D) float32, the state (S, Hkv, D, F_pad) and the
+    normaliser (S, Hkv, F_pad) after row T - 1)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    S, T, H, D = q.shape
+    Hkv = k.shape[2]
+    G, F = H // Hkv, retention_width(D)
+    Q = chunk if T % chunk == 0 else T
+    nc = T // Q
+    f32 = jnp.float32
+    qh = q.astype(f32).reshape(S, T, Hkv, G, D).transpose(0, 2, 3, 1, 4)
+    kh = k.astype(f32).transpose(0, 2, 1, 3)                  # (S, Hkv, T, D)
+    vt = v.astype(f32).transpose(0, 2, 3, 1)                  # (S, Hkv, D, T)
+    cs = jnp.cumsum(log_gamma.astype(f32).transpose(0, 2, 1).reshape(
+        S, Hkv, nc, Q), axis=-1).reshape(S, Hkv, T)
+    heads_spec = pl.BlockSpec((None, None, G, Q, D),
+                              lambda s, g, c: (s, g, 0, c, 0))
+    o, state, norm = pl.pallas_call(
+        functools.partial(_retention_chunk_kernel, dtype=jnp.dtype(dtype)),
+        name="power_retention_chunk",
+        grid=(S, Hkv, nc),
+        in_specs=[heads_spec,
+                  pl.BlockSpec((None, None, Q, D),
+                               lambda s, g, c: (s, g, c, 0)),
+                  pl.BlockSpec((None, None, D, Q),
+                               lambda s, g, c: (s, g, 0, c)),
+                  pl.BlockSpec((None, None, Q, 1),
+                               lambda s, g, c: (s, g, c, 0)),
+                  pl.BlockSpec((None, None, 1, Q),
+                               lambda s, g, c: (s, g, 0, c))],
+        out_specs=[heads_spec,
+                   pl.BlockSpec((None, None, D, F),
+                                lambda s, g, c: (s, g, 0, 0)),
+                   pl.BlockSpec((None, None, 1, F),
+                                lambda s, g, c: (s, g, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((S, Hkv, G, T, D), f32),
+                   jax.ShapeDtypeStruct((S, Hkv, D, F), f32),
+                   jax.ShapeDtypeStruct((S, Hkv, 1, F), f32)],
+        scratch_shapes=[pltpu.VMEM((D, F), f32), pltpu.VMEM((1, F), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=RETENTION_VMEM_BYTES),
+        interpret=interpret,
+    )(qh, kh, vt, cs[..., None], cs[:, :, None, :])
+    return (o.transpose(0, 3, 1, 2, 4).reshape(S, T, H, D), state,
+            norm[:, :, 0])

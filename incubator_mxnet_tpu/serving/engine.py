@@ -24,9 +24,14 @@ Greedy decoding (temperature 0) — token-for-token identical to
 sequential `models.transformer.generate()` per request, which is the
 equivalence CI asserts.
 
-A model whose programs say `decode_ahead` (models.transformer and
-models.falcon_h1 do) is decoded ONE STEP AHEAD while every slot decodes, no
-lever is on and no live request has an `eos_id`: the step after the one in
+A model whose cache has no kind that grows with the context
+(models.brumby: every layer carries a fixed-size state) gets no page
+pool: the allocator holds the null page alone, page tables are empty
+(width 0) and slots alone bound admission.
+
+A model whose programs say `decode_ahead` (models.transformer,
+models.falcon_h1 and models.brumby do) is decoded ONE STEP AHEAD while
+every slot decodes, no lever is on and no live request has an `eos_id`: the step after the one in
 flight is dispatched (its tokens still on the device, its positions one
 further) before the one in flight is read, so the host's turn (the fetch's
 latency, the bookkeeping, the caller's own reading, the next upload and
@@ -243,18 +248,28 @@ class ServingEngine:
         if self.max_len > cfg.max_len:
             raise ValueError(f"max_len {self.max_len} exceeds the "
                              f"model's positional table ({cfg.max_len})")
-        self.table_width = -(-self.max_len // self.page_size)
-        if num_pages is None:
+        # the seam: the model brings its cache (pools by layer kind,
+        # per-slot state) and its programs; the engine owns no shape
+        self.model = cfg.paged_programs()
+        # the pool's pages hold the kinds of cache that grow with the
+        # context; a model with none (every layer recurrent) gets no pool:
+        # its allocator holds the null page alone, its page tables are
+        # empty, admission reserves nothing and slots alone bound it
+        self.has_pool = any(kind.get("grows") for kind in
+                            self.model.cache_kinds(self.page_size).values())
+        self.table_width = (-(-self.max_len // self.page_size)
+                            if self.has_pool else 0)
+        if not self.has_pool:
+            num_pages = 1
+        elif num_pages is None:
             num_pages = int(config.get("MXTPU_SERVING_PAGES"))
         if not num_pages:  # auto: every slot can hold a full sequence
             num_pages = self.slots * self.table_width + 1
         self.allocator = PageAllocator(num_pages, self.page_size)
         # shadow-state refcount checker (None unless MXTPU_SANITIZERS
         # lists "pages"); run() proves quiescence at drain through it
-        self._page_san = _sanitizers.attach_page_sanitizer(self.allocator)
-        # the seam: the model brings its cache (pools by layer kind,
-        # per-slot state) and its programs; the engine owns no shape
-        self.model = cfg.paged_programs()
+        self._page_san = (_sanitizers.attach_page_sanitizer(self.allocator)
+                          if self.has_pool else None)
         self.paged = self.model.init_cache(self.slots, num_pages,
                                            self.page_size)
         self.prefill_buckets = _default_buckets(self.max_len)
@@ -460,7 +475,7 @@ class ServingEngine:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds max_len ({self.max_len})")
-        need = self.allocator.pages_needed(total)
+        need = self._pages_for(total)
         if need > self.allocator.capacity:
             raise ValueError(
                 f"request needs {need} pages but the pool only has "
@@ -667,6 +682,11 @@ class ServingEngine:
                 return s
         return None
 
+    def _pages_for(self, n_tokens):
+        """Pages of the pool `n_tokens` cache entries take: none where the
+        model keeps no cache that grows."""
+        return self.allocator.pages_needed(n_tokens) if self.has_pool else 0
+
     def _bucket_for(self, n):
         for b in self.prefill_buckets:
             if b >= n:
@@ -694,7 +714,7 @@ class ServingEngine:
                 admitted += 1
                 continue
             total = req.prompt.size + req.max_new_tokens
-            pages = self.allocator.alloc(self.allocator.pages_needed(total),
+            pages = self.allocator.alloc(self._pages_for(total),
                                          owner=req.request_id)
             if pages is None:
                 telemetry.inc(ADMISSION_BLOCKED, reason="pages")
@@ -1447,7 +1467,7 @@ class ServingEngine:
         masked: attended / fetched is the fill share); the decode steps
         dispatched that attended them, and how many of those went out one
         step ahead (`_runs_ahead`), before the step in flight was read."""
-        live = [self.allocator.pages_needed(int(self._positions[s]))
+        live = [self._pages_for(int(self._positions[s]))
                 for s in self._decoding_slots()]
         return {
             "pool": {"pages_live": sum(live),

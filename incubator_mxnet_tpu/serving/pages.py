@@ -69,12 +69,13 @@ def _log_page_event(op, pages, owner, free):
 
 class PageAllocator:
     """Refcounting free-list allocator over a pool of `num_pages` KV
-    pages of `page_size` tokens each (page 0 reserved)."""
+    pages of `page_size` tokens each (page 0 reserved; a pool of page 0
+    alone has nothing to hand out)."""
 
     def __init__(self, num_pages: int, page_size: int):
-        if num_pages < 2:
-            raise ValueError("need at least 2 pages (page 0 is the "
-                             f"reserved null page), got {num_pages}")
+        if num_pages < 1:
+            raise ValueError("need at least the reserved null page "
+                             f"(page 0), got {num_pages} pages")
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.num_pages = int(num_pages)
@@ -103,8 +104,9 @@ class PageAllocator:
         return self.num_pages - 1
 
     def occupancy(self) -> float:
-        """Fraction of allocatable pages currently owned, in [0, 1]."""
-        return self.num_in_use / self.capacity
+        """Fraction of allocatable pages currently owned, in [0, 1] (0
+        for a pool of the null page alone: a model with no paged cache)."""
+        return self.num_in_use / max(1, self.capacity)
 
     def fragmentation(self) -> float:
         """Free-list fragmentation in [0, 1]: 1 minus the largest run of

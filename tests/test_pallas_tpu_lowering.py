@@ -150,6 +150,26 @@ def _cases():
         [((6, 32, 32, 128, 256), jnp.float32), ((), I32), ((32,), jnp.bool_),
          ((32, 32, 128), jnp.float32), ((32, 32), jnp.float32),
          ((32,), jnp.float32)] + [((32, 2, 256), jnp.float32)] * 2))
+    # power retention (models.brumby) at the benchmark's geometry
+    # (brumby_14b: 16 slots, 40 query heads over 8 K/V heads of 128, a
+    # (128, 8320) float32 state a K/V head, 8 layers, the layer index
+    # traced; bfloat16 products), and a prompt's chunked form
+    F = pk.retention_width(128)
+    out.append((
+        "power_retention_step-brumby",
+        lambda st, z, l, live, q, k, v, g: pk.power_retention_step(
+            st, z, l, live, q, k, v, g, dtype=jnp.bfloat16,
+            interpret=False),
+        [((8, 16, 8, 128, F), jnp.float32), ((8, 16, 8, F), jnp.float32),
+         ((), I32), ((16,), jnp.bool_), ((16, 40, 128), jnp.float32)]
+        + [((16, 8, 128), jnp.float32)] * 2 + [((16, 8), jnp.float32)]))
+    out.append((
+        "power_retention_chunk-brumby-T1024",
+        functools.partial(pk.power_retention_chunk, chunk=128,
+                          dtype=jnp.bfloat16, interpret=False),
+        [((1, 1024, 40, 128), jnp.float32)]
+        + [((1, 1024, 8, 128), jnp.float32)] * 2
+        + [((1, 1024, 8), jnp.float32)]))
     epi = functools.partial(pk.bn_act_epilogue, interpret=False)
     for r, c in SZ.epilogue_shapes:
         plain = [((r, c), jnp.bfloat16), ((c,), jnp.float32),
